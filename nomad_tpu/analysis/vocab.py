@@ -120,14 +120,14 @@ PROM_REQUIRED = frozenset({
     "nomad_hbm_peak_bytes_total", "nomad_hbm_leases",
     "nomad_hbm_allocs", "nomad_hbm_releases",
     # drain cadence (ISSUE 12): mega-batch width/grouping/hold window —
-    # the BENCH_r07 e2e_drain tail aggregates from these
+    # the bench e2e_drain tail aggregates from these
     "nomad_drain_drains", "nomad_drain_batch_width",
     "nomad_drain_groups", "nomad_drain_hold_ms", "nomad_drain_window_ms",
     # wave dispatch (ISSUE 12): lane structure of fused mega-batches
     "nomad_wave_dispatches", "nomad_wave_programs", "nomad_wave_lanes",
     # speculative wave dispatch (ISSUE 15): launch/certify/rollback
     # outcomes, exact re-dispatch counts, wasted device time — the
-    # BENCH_r08 e2e_spec tail and the adaptive gate read these
+    # the bench e2e_spec tail and the adaptive gate read these
     "nomad_spec_launches", "nomad_spec_certified",
     "nomad_spec_rolled_back", "nomad_spec_redispatch_programs",
     "nomad_spec_wasted_kernel_ms",

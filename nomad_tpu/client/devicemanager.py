@@ -19,10 +19,11 @@ manager's poll loops:
   — stats are ephemeral telemetry, like the reference's client stats
   endpoint).
 
-The TPU plugin reuses the bounded subprocess probe from
-`fingerprint.py` (a wedged accelerator tunnel must never hang the
-agent); a probe failure AFTER devices were seen flips the instances
-unhealthy instead of silently dropping the group.
+The TPU plugin reads devices the way `fingerprint.py` does (in-process
+where this agent schedules and so holds the chip, a bounded child probe
+in a client-only agent); a probe failure AFTER devices were seen flips
+the instances unhealthy, with the reason, instead of silently dropping
+the group.
 """
 from __future__ import annotations
 
@@ -93,9 +94,9 @@ class DevicePlugin:
 
 class TpuDevicePlugin(DevicePlugin):
     """TPU chips via the JAX runtime (the nvidia/NVML plugin analog,
-    devices/gpu/nvidia/). Detection delegates to the bounded subprocess
-    probe in fingerprint.py; stats report health + probe latency (the
-    runtime exposes no per-chip utilization counters off-device)."""
+    devices/gpu/nvidia/). Detection is `fingerprint.accelerator_devices`;
+    stats report health + probe latency (the runtime exposes no per-chip
+    utilization counters off-device)."""
 
     name = "tpu"
 
@@ -105,23 +106,19 @@ class TpuDevicePlugin(DevicePlugin):
         self._seen: List[NodeDeviceResource] = []
 
     def fingerprint(self) -> List[NodeDeviceResource]:
-        from ..structs.node import Node
-        from .fingerprint import tpu_fingerprint
+        from .fingerprint import accelerator_devices, tpu_device_group
 
-        scratch = Node(id="probe")
         t0 = time.time()
-        tpu_fingerprint(scratch)
-        probed = [d for d in scratch.node_resources.devices
-                  if d.vendor == "google" and d.type == "tpu"]
+        devs, why = accelerator_devices()
         self._last_probe_ms = (time.time() - t0) * 1e3
-        if probed:
+        if devs:
             self._last_ok = time.time()
-            self._seen = probed
-            return probed
+            self._seen = [tpu_device_group(devs)]
+            return self._seen
         if self._seen:
-            # devices were here and the probe now fails/hangs: report
-            # them unhealthy (wedged tunnel / lost grant), don't vanish.
-            # Stored back into _seen so the stats stream agrees with the
+            # devices were here and the probe now finds none: report
+            # them unhealthy with the reason, don't vanish. Stored back
+            # into _seen so the stats stream agrees with the
             # fingerprinted health instead of advertising stale healthy.
             sick = []
             for g in self._seen:
@@ -130,7 +127,8 @@ class TpuDevicePlugin(DevicePlugin):
                     instances=[NodeDeviceInstance(id=i.id, healthy=False)
                                for i in g.instances],
                     attributes={**g.attributes,
-                                "health_description": "probe failed"},
+                                "health_description":
+                                    f"probe failed: {why}"},
                 ))
             self._seen = sick
             return sick
@@ -179,8 +177,7 @@ class RemoteDevicePlugin(DevicePlugin):
     """Proxy running a device plugin in its own process
     (plugins/device_host.py over the plugins/base.py transport — the
     `plugins/device/device.go` per-process model). Supervised: any RPC
-    failure relaunches the host; a crashing probe (e.g. a wedged
-    accelerator tunnel taking the process down) costs a plugin restart,
+    failure relaunches the host; a crashing probe costs a plugin restart,
     never the agent. While the host is down, fingerprint() degrades the
     same way TpuDevicePlugin does on probe failure: last-seen devices
     flip unhealthy instead of vanishing."""
@@ -282,7 +279,8 @@ class DeviceManager:
         self.stats_interval = stats_interval
         #: where out-of-process device-host logs live
         self.state_dir = state_dir
-        self.plugins = plugins if plugins is not None else self._builtin()
+        #: None → the builtin set, chosen at first use (see `plugins`)
+        self._plugins = plugins
         self._lock = threading.Lock()
         #: {"vendor/type/name": {instance_id: {..stats..}}}
         self._stats: Dict[str, Dict[str, dict]] = {}
@@ -293,7 +291,18 @@ class DeviceManager:
         #: (a wedged manager loop must leave a visible trace)
         self._errs = ErrorStreak("client.devicemanager")
 
+    @property
+    def plugins(self) -> List[DevicePlugin]:
+        """Built at first use, not at construction: the client is
+        constructed before `Server.start` takes the device, and whether
+        this process holds it decides where the tpu plugin may run."""
+        with self._lock:
+            if self._plugins is None:
+                self._plugins = self._builtin()
+            return self._plugins
+
     def _builtin(self) -> List[DevicePlugin]:
+        from ..lib import backend
         from ..plugins.base import oop_requested
 
         def mk(name: str, cls) -> DevicePlugin:
@@ -306,7 +315,11 @@ class DeviceManager:
 
         plugins: List[DevicePlugin] = [mk("env", EnvDevicePlugin)]
         if not os.environ.get("NOMAD_TPU_SKIP_TPU_FINGERPRINT"):
-            plugins.append(mk("tpu", TpuDevicePlugin))
+            # one process per chip: where this process holds the device
+            # a plugin host would only find it taken — read in-process
+            plugins.append(mk("tpu", TpuDevicePlugin)
+                           if backend.resolved() is None
+                           else TpuDevicePlugin())
         return plugins
 
     def seed(self, groups: List[NodeDeviceResource]) -> None:
@@ -416,7 +429,7 @@ class DeviceManager:
 
     def shutdown(self) -> None:
         self._stop.set()
-        for p in self.plugins:
+        for p in self._plugins or []:
             close = getattr(p, "close", None)
             if close is not None:
                 try:

@@ -31,6 +31,7 @@ from ..structs.funcs import PREEMPTION_SCORE_ORIGIN, PREEMPTION_SCORE_RATE
 from .placement import (
     ClusterArrays,
     TGParams,
+    _EXACT,
     _dp_feasible,
     _lut_gather,
     _onehot_tokens,
@@ -90,7 +91,8 @@ def preempt_rank(cluster: ClusterArrays, p: TGParams,
         # −1 pads match no row — same idiom as the placement kernel)
         eq = (p.delta_idx[:, None] == jnp.arange(n)[None, :]
               ).astype(jnp.float32)
-        used = used - jnp.einsum("dn,dr->nr", eq, p.delta_res)
+        used = used - jnp.einsum("dn,dr->nr", eq, p.delta_res,
+                                 precision=_EXACT)
 
     # Sort each node's candidates by priority ascending (victims cheapest
     # first — reference filterAndGroupPreemptibleAllocs order).
@@ -116,12 +118,14 @@ def preempt_rank(cluster: ClusterArrays, p: TGParams,
     # net priority of the minimal prefix (rank.go:747 netPriority).
     # Per-row prefix selection as one-hot einsums, not [rows, k_idx]
     # advanced indexing — TPU gathers serialize; every slot is finite
-    # (INF_PRIO = 1e9) so masked products stay exact.
+    # (INF_PRIO = 1e9) and the selector is 0/1, so under `_EXACT` the
+    # selected value comes back bit for bit.
     psum = jnp.cumsum(jnp.where(eligible, prio_s, 0.0), axis=1)  # [N, A]
     k_oh = (jnp.arange(a)[None, :] == k_idx[:, None]
             ).astype(jnp.float32)                               # [N, A]
-    max_p = jnp.einsum("na,na->n", prio_s, k_oh)  # sorted ⇒ last = max
-    sum_p = jnp.einsum("na,na->n", psum, k_oh)
+    max_p = jnp.einsum("na,na->n", prio_s, k_oh,
+                       precision=_EXACT)           # sorted ⇒ last = max
+    sum_p = jnp.einsum("na,na->n", psum, k_oh, precision=_EXACT)
     net_prio = jnp.where(max_p > 0, max_p + sum_p / jnp.maximum(max_p, 1.0),
                          0.0)
     pre_score = 1.0 / (
@@ -130,7 +134,8 @@ def preempt_rank(cluster: ClusterArrays, p: TGParams,
     )
 
     # Bin-pack score at the post-eviction utilization (funcs.go:175).
-    util_sel = jnp.einsum("nar,na->nr", util_k, k_oh)           # [N, R]
+    util_sel = jnp.einsum("nar,na->nr", util_k, k_oh,
+                          precision=_EXACT)                     # [N, R]
     binpack, _ = fit_scores(util_sel, cap)
 
     combined = (binpack + pre_score) / 2.0

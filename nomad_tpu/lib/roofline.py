@@ -59,22 +59,12 @@ def device_peaks(device) -> Tuple[Optional[float], Optional[float], str]:
 
 def kernel_cost(compiled) -> Dict[str, float]:
     """{"flops": .., "bytes_accessed": ..} from a jax.stages.Compiled
-    (or anything exposing cost_analysis()). Missing counters come back
-    as 0.0 — older backends omit them rather than erroring."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend without cost model
-        return {"flops": 0.0, "bytes_accessed": 0.0}
-    # older jax returns [dict] per computation, newer returns dict
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return {"flops": 0.0, "bytes_accessed": 0.0}
+    (or anything exposing cost_analysis()). A counter the backend's
+    cost model does not report comes back as 0.0."""
+    ca = compiled.cost_analysis()
     return {
         "flops": float(ca.get("flops", 0.0) or 0.0),
-        "bytes_accessed": float(ca.get("bytes accessed",
-                                       ca.get("bytes_accessed", 0.0))
-                                or 0.0),
+        "bytes_accessed": float(ca.get("bytes accessed", 0.0) or 0.0),
     }
 
 

@@ -1,9 +1,12 @@
 """ctypes bindings for the C++ host-runtime core (`native/core.cpp`).
 
-Builds `libnomad_core.so` with g++ on first use (cached by source mtime)
-and exposes zero-copy wrappers over numpy buffers. Every entry point has
-a pure-Python fallback so the framework runs where no compiler exists;
-`available()` reports which path is active.
+Builds the library with g++ on first use and exposes zero-copy wrappers
+over numpy buffers. The library file is keyed on the CONTENT of
+`core.cpp` (`libnomad_core.<sha256[:12]>.so`, git-ignored): a copy or a
+checkout does not preserve mtimes, and a stale binary that still loads
+is worse than none. Every entry point has a pure-Python fallback so the
+framework runs where no compiler exists; `status()` reports which path
+is active and, when it is the fallback, why.
 
 Consumers: `structs/network.py` (dynamic-port first-fit) and any host
 loop needing batch fit/score/scatter primitives.
@@ -11,63 +14,110 @@ loop needing batch fit/score/scatter primitives.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+log = logging.getLogger("nomad_tpu.native")
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "core.cpp")
-_LIB = os.path.join(_REPO_ROOT, "native", "libnomad_core.so")
+_DIR = os.path.join(_REPO_ROOT, "native")
+_SRC = os.path.join(_DIR, "core.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_path = ""
+_reason = "not loaded yet"
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libnomad_core.{digest}.so")
+
+
+def _build(path: str) -> str:
+    """Compile core.cpp to `path`; returns "" or the reason it failed.
+    Built under a per-process name and renamed into place, so agents
+    starting together never load a half-written file."""
+    tmp = f"{path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
-             "-o", _LIB, _SRC],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+        os.replace(tmp, path)
+    except FileNotFoundError:
+        return "g++ not found"
+    except subprocess.CalledProcessError as e:
+        err = e.stderr.decode(errors="replace").strip().splitlines()
+        return f"g++ exited rc={e.returncode}: {err[-1] if err else ''}"
+    except (OSError, subprocess.SubprocessError) as e:
+        return f"build failed: {e}"
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for old in glob.glob(os.path.join(_DIR, "libnomad_core*.so")):
+        if old != path:  # binaries of other core.cpp contents
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return ""
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _path, _reason
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         if os.environ.get("NOMAD_TPU_NO_NATIVE"):
+            _reason = "NOMAD_TPU_NO_NATIVE is set"
             return None
         if not os.path.exists(_SRC):
+            _reason = f"{_SRC} is missing"
             return None
-        stale = (not os.path.exists(_LIB)
-                 or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if stale and not _build():
-            return None
+        path = _lib_path()
+        if not os.path.exists(path):
+            _reason = _build(path)
+            if _reason:
+                log.warning("native core unavailable (%s); using the "
+                            "Python fallbacks", _reason)
+                return None
         try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
+            lib = ctypes.CDLL(path)
+        except OSError as e:
+            _reason = f"load failed: {e}"
+            log.warning("native core unavailable (%s)", _reason)
             return None
         lib.nomad_first_fit_ports.restype = ctypes.c_int
         lib.nomad_count_free_ports.restype = ctypes.c_int
         lib.nomad_core_abi_version.restype = ctypes.c_int
         if lib.nomad_core_abi_version() != 4:
+            _reason = "ABI version mismatch"
             return None
-        _lib = lib
+        _lib, _path, _reason = lib, path, ""
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> Dict[str, object]:
+    """{"loaded", "path", "reason"}: whether the compiled core is in
+    use (building it on first call), and if not, why not."""
+    loaded = _load() is not None
+    return {"loaded": loaded, "path": _path, "reason": _reason}
 
 
 def _ptr(arr: np.ndarray, ctype):

@@ -89,6 +89,10 @@ def make_mesh(n_devices: Optional[int] = None,
     """Build a ("batch", "nodes") mesh over the first `n_devices` devices."""
     devices = jax.devices()
     n = len(devices) if n_devices is None else n_devices
+    if n > len(devices):
+        raise ValueError(
+            f"mesh of {n} devices requested, {len(devices)} "
+            f"{devices[0].platform} device(s) present")
     devices = devices[:n]
     if batch is None:
         # Node-axis size must divide the cluster row bucket (a power of two ≥

@@ -8,8 +8,11 @@ instantiates ONE device plugin (builtin by name, or a third-party
 `module:Class` path) and serves the three-method contract over the
 msgpack-RPC plugin transport. The client-side proxy
 (`client/devicemanager.py` RemoteDevicePlugin) supervises it — a
-crashing device probe (e.g. a wedged accelerator tunnel taking the
-whole process down) costs a plugin relaunch, never the agent.
+crashing device probe costs a plugin relaunch, never the agent. The
+`tpu` plugin is hosted here only by a client-only agent: an agent that
+schedules holds the chip itself and reads it in-process
+(`DeviceManager._builtin`), because a host started from under it would
+find the device taken.
 
 Launch: ``python -m nomad_tpu.plugins.device_host <name>``.
 """

@@ -278,6 +278,14 @@ class Server:
     # ---- lifecycle (leader.go:222 establishLeadership) ----
 
     def start(self) -> None:
+        if self.workers:
+            # a process that schedules takes its device HERE, once and
+            # loudly, before the first eval is dequeued — not at the
+            # first dispatch inside a worker thread, where a missing
+            # accelerator is a traceback and a nack per eval
+            from ..lib.backend import resolve
+
+            resolve()
         self.broker.set_enabled(True)
         self.blocked.set_enabled(True)
         self.plan_queue.set_enabled(True)

@@ -16,27 +16,30 @@ from __future__ import annotations
 import dataclasses
 import importlib
 import pkgutil
+import threading
 from typing import Any, Dict, Type
 
 _TYPE_TAG = "__t"
 _REGISTRY: Dict[str, Type] = {}
+_REGISTRY_LOCK = threading.Lock()
 
 
-def _build_registry() -> None:
+def _build_registry() -> Dict[str, Type]:
     import nomad_tpu.structs as pkg
 
+    reg: Dict[str, Type] = {}
     for info in pkgutil.iter_modules(pkg.__path__):
         mod = importlib.import_module(f"nomad_tpu.structs.{info.name}")
         for name in dir(mod):
             obj = getattr(mod, name)
             if (isinstance(obj, type) and dataclasses.is_dataclass(obj)
                     and obj.__module__ == mod.__name__):
-                existing = _REGISTRY.get(obj.__name__)
+                existing = reg.get(obj.__name__)
                 if existing is not None and existing is not obj:
                     raise RuntimeError(
                         f"duplicate struct name {obj.__name__} in registry"
                     )
-                _REGISTRY[obj.__name__] = obj
+                reg[obj.__name__] = obj
     # Wire-visible dataclasses living outside nomad_tpu.structs
     from nomad_tpu.acl.policy import HostVolumeRule, NamespaceRule, Policy
     from nomad_tpu.acl.tokens import ACLPolicy, ACLToken
@@ -44,12 +47,19 @@ def _build_registry() -> None:
 
     for cls in (SchedulerConfiguration, ACLPolicy, ACLToken, Policy,
                 NamespaceRule, HostVolumeRule):
-        _REGISTRY[cls.__name__] = cls
+        reg[cls.__name__] = cls
+    return reg
 
 
 def registry() -> Dict[str, Type]:
+    """Built once, under a lock, and published whole: the HTTP layer
+    decodes on one thread per request, and a reader that saw the
+    half-filled dict of a concurrent first build failed with
+    "unknown struct type 'Job'" (a burst of first submits does that)."""
     if not _REGISTRY:
-        _build_registry()
+        with _REGISTRY_LOCK:
+            if not _REGISTRY:
+                _REGISTRY.update(_build_registry())
     return _REGISTRY
 
 

@@ -93,7 +93,7 @@ def synth_service_job(rng: random.Random, count: int = 8,
     stanzas (configs 2-5). `datacenter` pins the job to ONE dc — jobs
     pinned to different dcs have disjoint node footprints, the shape the
     wave-dispatch partition (ISSUE 12) parallelizes."""
-    jid = f"svc-{uuid.uuid4().hex[:12]}"
+    jid = f"svc-{rng.getrandbits(48):012x}"
     constraints = [Constraint(ltarget="${attr.kernel.name}", rtarget="linux",
                               operand="=")]
     if distinct_hosts:
@@ -153,7 +153,7 @@ def synth_system_job(rng: random.Random, priority: int = 80) -> Job:
     """One system job (BASELINE config 4): one alloc per eligible node,
     priority above the synthetic filler allocs so priority-based preemption
     (system_sched.go:268) can evict on full nodes."""
-    jid = f"sys-{uuid.uuid4().hex[:12]}"
+    jid = f"sys-{rng.getrandbits(48):012x}"
     return Job(
         id=jid,
         name=jid,
@@ -185,7 +185,7 @@ def synth_system_job(rng: random.Random, priority: int = 80) -> Job:
 def synth_alloc(rng: random.Random, node: Node, shared_job: Job) -> Allocation:
     """A pre-existing (running) alloc occupying capacity on `node`."""
     return Allocation(
-        id=uuid.uuid4().hex,
+        id=f"{rng.getrandbits(128):032x}",
         eval_id="synth",
         namespace="default",
         name=f"{shared_job.id}.web[0]",

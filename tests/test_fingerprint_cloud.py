@@ -166,9 +166,20 @@ class TestCniFingerprint:
 
 
 class TestTpuFingerprintBounded:
-    def test_wedged_probe_leaves_node_unannotated(self, monkeypatch):
+    """The child probe of a process that holds no JAX backend (a
+    client-only agent). The in-process path of a scheduling agent is in
+    tests/test_bringup.py."""
+
+    @pytest.fixture(autouse=True)
+    def _no_backend_held(self, monkeypatch):
+        from nomad_tpu.lib import backend
+
+        monkeypatch.setattr(backend, "_resolved", None)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+
+    def test_hung_probe_leaves_node_unannotated(self, monkeypatch):
         """A hanging accelerator runtime must not block fingerprinting:
-        the subprocess probe times out and the agent moves on."""
+        the subprocess probe times out, says so, and the agent moves on."""
         import subprocess
 
         from nomad_tpu.client import fingerprint as fp
@@ -176,12 +187,27 @@ class TestTpuFingerprintBounded:
         def fake_run(*a, **k):
             raise subprocess.TimeoutExpired(cmd=a[0], timeout=k["timeout"])
 
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        monkeypatch.delenv("XLA_FLAGS", raising=False)
         monkeypatch.setattr(subprocess, "run", fake_run)
         node = Node()
         fp.tpu_fingerprint(node)  # must return promptly, not raise
         assert "tpu.count" not in node.attributes
+        devs, why = fp.accelerator_devices()
+        assert devs == [] and "timed out" in why
+
+    def test_failed_probe_says_why(self, monkeypatch):
+        import subprocess
+
+        from nomad_tpu.client import fingerprint as fp
+
+        class R:
+            returncode = 1
+            stdout = b""
+            stderr = b"Traceback ...\nRuntimeError: TPU is already in use\n"
+
+        monkeypatch.setattr(subprocess, "run", lambda *a, **k: R())
+        devs, why = fp.accelerator_devices()
+        assert devs == []
+        assert "rc=1" in why and "already in use" in why
 
     def test_probe_result_annotates_devices(self, monkeypatch):
         import json
@@ -194,9 +220,8 @@ class TestTpuFingerprintBounded:
         class R:
             returncode = 0
             stdout = json.dumps(rows).encode()
+            stderr = b""
 
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        monkeypatch.delenv("XLA_FLAGS", raising=False)
         monkeypatch.setattr(subprocess, "run", lambda *a, **k: R())
         node = Node()
         fp.tpu_fingerprint(node)
@@ -205,13 +230,13 @@ class TestTpuFingerprintBounded:
         assert node.node_resources.devices[0].vendor == "google"
         assert node.node_resources.devices[0].instances[0].id == "0"
 
-    def test_cpu_pin_skips_probe(self, monkeypatch):
+    def test_cpu_request_skips_probe(self, monkeypatch):
         import subprocess
 
         from nomad_tpu.client import fingerprint as fp
 
         def boom(*a, **k):
-            raise AssertionError("probe must not run under a CPU pin")
+            raise AssertionError("no probe when the operator asked for cpu")
 
         monkeypatch.setenv("JAX_PLATFORMS", "cpu")
         monkeypatch.setattr(subprocess, "run", boom)

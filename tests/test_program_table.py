@@ -34,7 +34,8 @@ from nomad_tpu.lib.metrics import default_registry
 from nomad_tpu.lib.transfer import default_ledger
 from nomad_tpu.mock import alloc_resources
 from nomad_tpu.parallel.mesh import stack_params
-from nomad_tpu.scheduler.stack import _DEV_CACHE, TPUStack
+from nomad_tpu.scheduler.stack import (_DEV_CACHE, TPUStack,
+                                       drop_device_view)
 from nomad_tpu.server.program_table import (DIM_CEILINGS,
                                             DeviceProgramTable, table_for)
 from nomad_tpu.server.select_batch import SelectCoordinator
@@ -274,7 +275,7 @@ def _np_view(arrays):
 
 
 def _cold_view(cl):
-    _DEV_CACHE.pop(cl, None)
+    drop_device_view(cl)
     return _np_view(TPUStack(cl).device_arrays())
 
 
