@@ -191,6 +191,7 @@ class Agent:
         self.server = None
         self.client = None
         self.cluster = None
+        self._gc_watch = None
         self._started_at = time.time()
         # agent log ring for /v1/agent/monitor (hclog → monitor stream):
         # one process-wide handler fans out to the live agents' rings
@@ -268,6 +269,11 @@ class Agent:
         # scheduler could find it taken.
         if self.server is not None:
             self.server.start()
+            # collector pauses of the scheduling process: runtime.gc_*
+            from ..lib.backend import GcWatch
+
+            self._gc_watch = GcWatch()
+            self._gc_watch.install()
         if self.client is not None:
             # advertise this agent's HTTP endpoint on the node BEFORE
             # registration — remote ephemeral-disk migration dials the
@@ -304,6 +310,8 @@ class Agent:
             self.client.shutdown()
         if self.server is not None:
             self.server.shutdown()
+        if self._gc_watch is not None:
+            self._gc_watch.remove()
 
     # ---- introspection (agent_endpoint.go) ----
 

@@ -232,6 +232,11 @@ ALLOWED_PREFIXES = (
     "nomad_slo_",             # per-priority scheduling SLOs (ISSUE 17)
     "nomad_events_",          # FSM-sourced cluster event stream
                               # (ISSUE 18, server/event_broker.py)
+    "nomad_sched_",           # scheduler-thread CPU vs wall over its
+                              # non-blocking phases (ISSUE 26)
+    "nomad_runtime_",         # JAX compiles / tracing+lowering and
+                              # collector pauses of the process
+                              # (ISSUE 26, lib/backend.py)
 )
 
 #: the only label names any exposed series may carry
@@ -284,8 +289,9 @@ ALLOWED_SITES = frozenset(TRANSFER_SITES | RESIDENCY_SITES)
 #:                 http.submit on the follower)
 #:   eval          child of the span current at broker enqueue
 #:                 (rpc.forward when forwarded, http.submit when local)
-#:   eval.<phase>  child of `eval` — one per lib/trace.py PHASES entry,
-#:                 mirrored off the EvalTracer's monotonic spans
+#:   eval.<phase>  child of `eval` — one per lib/trace.py PHASES entry
+#:                 up to `ack` (the scheduler thread's own four phases
+#:                 are not mirrored), off the EvalTracer's monotonic spans
 #:   plan.apply    child of `eval` — span id LEADER-MINTED in
 #:                 plan_apply.apply (like `now=`) and stamped onto the
 #:                 plan's allocs before the raft entry is journaled

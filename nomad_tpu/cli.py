@@ -824,8 +824,11 @@ def cmd_operator_timeline(args) -> int:
     """`nomad-tpu operator timeline` — per-dispatch pipeline records
     (/v1/scheduler/timeline): pack/view/kernel intervals plus how much
     of each dispatch's pack hid under the predecessor's kernel
-    (overlap) and the device idle between kernels (bubble). The summary
-    line is the quick read; `-json` dumps raw records for tooling."""
+    (overlap) and the device idle between kernels (bubble). "Kernel" is
+    launch → first read on the host's clock, split into launch /
+    release / speculation hold / wake / fetch; the kernel's own time on
+    the device is the profiler's to give. The summary line is the quick
+    read; `-json` dumps raw records for tooling."""
     from .api import ApiError
 
     api = _client(args)
@@ -849,6 +852,12 @@ def cmd_operator_timeline(args) -> int:
     print(f"Transfer     = {summ.get('transfer_bytes_per_dispatch', 0.0):.0f}"
           f" B / {summ.get('transfer_count_per_dispatch', 0.0):.1f} "
           f"transfers per dispatch")
+    print("Kernel (ms)  = launch -> first read on the host's clock "
+          "(Launch + Release + Hold + Wake + Fetch), not device time: "
+          "that is the profiler's")
+    print(f"Bounds only  = {summ.get('kernel_end_unknown', 0)} dispatches"
+          f" follow a read that did not block ('*': overlap an upper, "
+          f"bubble a lower bound; left out of Overlap and Bubble above)")
     recs = tl.get("dispatches", [])
     if recs:
         print()
@@ -856,16 +865,25 @@ def cmd_operator_timeline(args) -> int:
         def fmt(v, nd=2):
             return "-" if v is None else f"{v:.{nd}f}"
 
+        def bound(r, key):
+            return fmt(r[key]) + ("*" if r.get("bounds_only") else "")
+
         rows = [[str(r["seq"]), str(r["programs"]),
                  "yes" if r["batched"] else "no",
                  fmt(r["pack_ms"]), fmt(r.get("upload_ms")),
                  fmt(r["view_ms"]), fmt(r["kernel_ms"]),
-                 fmt(r["overlap_ms"]), fmt(r["bubble_ms"]),
+                 fmt(r.get("launch_ms")), fmt(r.get("release_ms")),
+                 fmt(r.get("spec_hold_ms")), fmt(r.get("wake_ms")),
+                 fmt(r.get("fetch_block_ms")),
+                 {True: "yes", False: "no"}.get(r.get("was_ready"), "-"),
+                 bound(r, "overlap_ms"), bound(r, "bubble_ms"),
                  str(r["transfer_bytes"])]
                 for r in recs]
         print(_columns(rows, ["Seq", "Progs", "Fused", "Pack (ms)",
                               "Upload (ms)", "View (ms)", "Kernel (ms)",
-                              "Overlap (ms)", "Bubble (ms)", "Bytes"]))
+                              "Launch", "Release", "Hold", "Wake",
+                              "Fetch", "Ready", "Overlap (ms)",
+                              "Bubble (ms)", "Bytes"]))
     return 0
 
 

@@ -375,14 +375,15 @@ def place_task_group(cluster: ClusterArrays, p: TGParams, max_allocs: int,
     n = cap.shape[0]
 
     # ---- static (per-group) feasibility, computed once ----
-    feas_c = _lut_gather(p.lut, p.key_idx, cluster.attrs)          # [N, C] bool
-    lut_all = jnp.all(feas_c, axis=1)
-    feas = cluster.node_ok & p.extra_mask & lut_all
-    in_cand = None
-    if p.cand_idx.shape[0]:
-        in_cand = jnp.any(p.cand_idx[:, None] == jnp.arange(n)[None, :],
-                          axis=0)
-        feas = feas & (in_cand | ~p.use_cand)
+    with jax.named_scope("nomad.feasibility_mask"):
+        feas_c = _lut_gather(p.lut, p.key_idx, cluster.attrs)          # [N, C] bool
+        lut_all = jnp.all(feas_c, axis=1)
+        feas = cluster.node_ok & p.extra_mask & lut_all
+        in_cand = None
+        if p.cand_idx.shape[0]:
+            in_cand = jnp.any(p.cand_idx[:, None] == jnp.arange(n)[None, :],
+                              axis=0)
+            feas = feas & (in_cand | ~p.use_cand)
 
     if explain:
         # candidate base: every node the iterator chain would scan
@@ -447,78 +448,82 @@ def place_task_group(cluster: ClusterArrays, p: TGParams, max_allocs: int,
         # compare, don't scatter (−1 pads match no row)
         penalty = jnp.any(pen_idx[:, None] == jnp.arange(n)[None, :], axis=0)
 
-        util = used + p.ask[None, :]                       # [N, R]
-        res_over = util > cap                              # [N, R]
-        fits = ~jnp.any(res_over, axis=1)
-        dyn_ok = (dyn_free - splaced * p.n_dyn) >= p.n_dyn
-        res_ok = res_free & ~(has_res_ask & (splaced > 0))
-        ports_ok = dyn_ok & res_ok
-        fits = fits & ports_ok
-        ok = feas & fits
-        dh_collide = p.distinct_hosts & (job_cnt > 0)
-        ok = ok & ~dh_collide
+        with jax.named_scope("nomad.fit_mask"):
+            util = used + p.ask[None, :]                   # [N, R]
+            res_over = util > cap                          # [N, R]
+            fits = ~jnp.any(res_over, axis=1)
+            dyn_ok = (dyn_free - splaced * p.n_dyn) >= p.n_dyn
+            res_ok = res_free & ~(has_res_ask & (splaced > 0))
+            ports_ok = dyn_ok & res_ok
+            fits = fits & ports_ok
+            ok = feas & fits
+            dh_collide = p.distinct_hosts & (job_cnt > 0)
+            ok = ok & ~dh_collide
 
-        dp_mask = None
-        if dcounts.shape[0]:
-            dp_mask = _dp_feasible(dtok, dtok_oh, dcounts, p)
-            ok = ok & dp_mask
+            dp_mask = None
+            if dcounts.shape[0]:
+                dp_mask = _dp_feasible(dtok, dtok_oh, dcounts, p)
+                ok = ok & dp_mask
 
         # ---- fused scoring (rank.go semantics) ----
-        binpack, spreadfit = fit_scores(util, cap)
-        fit_score = jnp.where(p.algorithm == 1, spreadfit, binpack)
+        with jax.named_scope("nomad.score"):
+            binpack, spreadfit = fit_scores(util, cap)
+            fit_score = jnp.where(p.algorithm == 1, spreadfit, binpack)
 
-        ssum = fit_score
-        scnt = jnp.ones_like(fit_score)
+            ssum = fit_score
+            scnt = jnp.ones_like(fit_score)
 
-        collide = tg_cnt > 0
-        anti = -(tg_cnt + 1.0) / jnp.maximum(p.desired_count, 1.0)
-        ssum = ssum + jnp.where(collide, anti, 0.0)
-        scnt = scnt + collide
+            collide = tg_cnt > 0
+            anti = -(tg_cnt + 1.0) / jnp.maximum(p.desired_count, 1.0)
+            ssum = ssum + jnp.where(collide, anti, 0.0)
+            scnt = scnt + collide
 
-        ssum = ssum + jnp.where(penalty, -1.0, 0.0)
-        scnt = scnt + penalty
+            ssum = ssum + jnp.where(penalty, -1.0, 0.0)
+            scnt = scnt + penalty
 
-        inc_aff = aff_score != 0.0
-        ssum = ssum + jnp.where(inc_aff, aff_score, 0.0)
-        scnt = scnt + inc_aff
+            inc_aff = aff_score != 0.0
+            ssum = ssum + jnp.where(inc_aff, aff_score, 0.0)
+            scnt = scnt + inc_aff
 
-        spread_score = _spread_boost(stok, stok_oh, scounts, p)
-        inc_spread = spread_score != 0.0
-        ssum = ssum + jnp.where(inc_spread, spread_score, 0.0)
-        scnt = scnt + inc_spread
+            spread_score = _spread_boost(stok, stok_oh, scounts, p)
+            inc_spread = spread_score != 0.0
+            ssum = ssum + jnp.where(inc_spread, spread_score, 0.0)
+            scnt = scnt + inc_spread
 
-        final = ssum / scnt
-        masked = jnp.where(ok, final, NEG_INF)
+            final = ssum / scnt
+            masked = jnp.where(ok, final, NEG_INF)
 
         # Preferred node (sticky ephemeral disk / prev-node rescheduling:
         # generic_sched.go findPreferredNode + stack SelectPreferringNodes)
-        best = jnp.argmax(masked)
-        pref_ok = (pref_idx >= 0) & ok[jnp.maximum(pref_idx, 0)]
-        idx = jnp.where(pref_ok, jnp.maximum(pref_idx, 0), best)
-        found = ok[idx] & active
-        sel = jnp.where(found, idx, -1)
+        with jax.named_scope("nomad.select"):
+            best = jnp.argmax(masked)
+            pref_ok = (pref_idx >= 0) & ok[jnp.maximum(pref_idx, 0)]
+            idx = jnp.where(pref_ok, jnp.maximum(pref_idx, 0), best)
+            found = ok[idx] & active
+            sel = jnp.where(found, idx, -1)
 
-        onehot = (jnp.arange(n) == idx) & found
-        used = used + jnp.where(onehot[:, None], p.ask[None, :], 0.0)
-        job_cnt = job_cnt + onehot
-        tg_cnt = tg_cnt + onehot
-        splaced = splaced + onehot.astype(jnp.float32)
-        if scounts.shape[0]:
-            sel_tok = stok[idx]                     # [S], normalized
-            # missing values never enter the use map (spread.go:326);
-            # miss is the last slot after _select_tokens normalization
-            valid = (sel_tok != scounts.shape[1] - 1) & found
-            upd = jax.nn.one_hot(
-                sel_tok, scounts.shape[1], dtype=scounts.dtype,
-            ) * valid[:, None]
-            scounts = scounts + upd
-        if dcounts.shape[0]:
-            sel_dtok = dtok[idx]                    # [P]
-            dvalid = (sel_dtok != dcounts.shape[1] - 1) & found
-            dupd = jax.nn.one_hot(
-                sel_dtok, dcounts.shape[1], dtype=dcounts.dtype,
-            ) * dvalid[:, None]
-            dcounts = dcounts + dupd
+        with jax.named_scope("nomad.carry_update"):
+            onehot = (jnp.arange(n) == idx) & found
+            used = used + jnp.where(onehot[:, None], p.ask[None, :], 0.0)
+            job_cnt = job_cnt + onehot
+            tg_cnt = tg_cnt + onehot
+            splaced = splaced + onehot.astype(jnp.float32)
+            if scounts.shape[0]:
+                sel_tok = stok[idx]                     # [S], normalized
+                # missing values never enter the use map (spread.go:326);
+                # miss is the last slot after _select_tokens normalization
+                valid = (sel_tok != scounts.shape[1] - 1) & found
+                upd = jax.nn.one_hot(
+                    sel_tok, scounts.shape[1], dtype=scounts.dtype,
+                ) * valid[:, None]
+                scounts = scounts + upd
+            if dcounts.shape[0]:
+                sel_dtok = dtok[idx]                    # [P]
+                dvalid = (sel_dtok != dcounts.shape[1] - 1) & found
+                dupd = jax.nn.one_hot(
+                    sel_dtok, dcounts.shape[1], dtype=dcounts.dtype,
+                ) * dvalid[:, None]
+                dcounts = dcounts + dupd
 
         n_fit = jnp.sum((feas & fits).astype(jnp.int32))
         ys = (
@@ -586,7 +591,8 @@ def place_task_group(cluster: ClusterArrays, p: TGParams, max_allocs: int,
     init = (used0, job_cnt0, tg_cnt0, p.spread_counts0, p.dp_counts0,
             splaced0)
     xs = (jnp.arange(max_allocs), p.penalty_idx, p.preferred_idx)
-    (used_f, _, _, _, _, _), ys = jax.lax.scan(step, init, xs)
+    with jax.named_scope("nomad.alloc_scan"):
+        (used_f, _, _, _, _, _), ys = jax.lax.scan(step, init, xs)
     sels, scores, n_fits, finals = ys[:4]
     ex = None
     if explain:
@@ -783,13 +789,16 @@ def _chain_with_carry(cluster: ClusterArrays, batch: TGParams,
         used, dyn = carry
         cl = cluster._replace(used=used, dyn_free=dyn)
         r = place_task_group(cl, p, max_allocs, explain=explain)
-        placed = jnp.sum(
-            ((r.sel_idx[:, None] == jnp.arange(n)[None, :])
-             & (r.sel_idx >= 0)[:, None]).astype(jnp.float32), axis=0)
-        return (r.new_used, dyn - placed * p.n_dyn), r
+        with jax.named_scope("nomad.chain_carry"):
+            placed = jnp.sum(
+                ((r.sel_idx[:, None] == jnp.arange(n)[None, :])
+                 & (r.sel_idx >= 0)[:, None]).astype(jnp.float32), axis=0)
+            dyn = dyn - placed * p.n_dyn
+        return (r.new_used, dyn), r
 
-    (used_f, dyn_f), results = jax.lax.scan(
-        prog, (cluster.used, cluster.dyn_free), batch)
+    with jax.named_scope("nomad.program_chain"):
+        (used_f, dyn_f), results = jax.lax.scan(
+            prog, (cluster.used, cluster.dyn_free), batch)
     return results, (used_f, dyn_f)
 
 

@@ -16,9 +16,11 @@ from the wrong device for the life of the process.
 """
 from __future__ import annotations
 
+import gc
 import logging
 import os
-from typing import NamedTuple, Optional, Tuple
+import time
+from typing import List, NamedTuple, Optional, Tuple
 
 log = logging.getLogger("nomad_tpu.backend")
 
@@ -102,7 +104,9 @@ def setup_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory
     in use. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself
     and nothing is set in code; otherwise the cache lives in
-    `<checkout>/.xla_cache` (git-ignored). Call before the first compile."""
+    `<checkout>/.xla_cache` (git-ignored). Call before the first compile
+    (which `count_compiles` then counts)."""
+    count_compiles()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -110,3 +114,90 @@ def setup_compile_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR
+
+
+# ---- runtime counters (process registry: `runtime.*`) ----------------------
+
+#: JAX's own duration events: a backend compile or a persistent-cache
+#: load (the same event covers both), and tracing + lowering, which are
+#: paid also where the backend compile is skipped
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                       "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_counting_compiles = False
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    from .metrics import default_registry
+
+    if event == _COMPILE_EVENT:
+        reg = default_registry()
+        reg.inc("runtime.compiles")
+        reg.inc("runtime.compile_ms", float(duration) * 1e3)
+    elif event in _TRACE_LOWER_EVENTS:
+        default_registry().inc("runtime.trace_lower_ms",
+                               float(duration) * 1e3)
+
+
+def count_compiles() -> None:
+    """One `jax.monitoring` listener for the life of the process:
+    `runtime.compiles`, `runtime.compile_ms` and `runtime.trace_lower_ms`
+    (sums) in the process registry — what a warm-up cost, and whether
+    anything compiled while serving."""
+    global _counting_compiles
+    if _counting_compiles:
+        return
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _counting_compiles = True
+
+
+class GcWatch:
+    """`gc.callbacks` hook of a serving process: every collection's
+    pause into the histogram `runtime.gc_pause_ms`, full (generation 2)
+    collections into the counter `runtime.gc_full`, and a `nomad/gc`
+    span into a running profile.
+
+    A collection can start on a thread that holds an instrument's lock
+    (a reader sorting the histogram's window allocates), so the hook
+    holds its two instruments, never looks one up, and never waits for a
+    lock: what it cannot record at once waits for the next collection."""
+
+    def __init__(self, registry=None) -> None:
+        from .metrics import default_registry
+
+        reg = registry or default_registry()
+        self._pause_ms = reg.histogram("runtime.gc_pause_ms")
+        self._full = reg.counter("runtime.gc_full")
+        self._open: Optional[tuple] = None
+        self._pending: List[float] = []
+        self._pending_full = 0
+
+    def install(self) -> None:
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def remove(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        from .trace import host_span
+
+        if phase == "start":
+            span = host_span("gc")
+            span.__enter__()
+            self._open = (time.perf_counter(), span)
+            return
+        if self._open is None:
+            return
+        (t0, span), self._open = self._open, None
+        span.__exit__(None, None, None)
+        self._pending.append((time.perf_counter() - t0) * 1e3)
+        if info.get("generation") == 2:
+            self._pending_full += 1
+        while self._pending and self._pause_ms.try_add(self._pending[-1]):
+            self._pending.pop()
+        if self._pending_full and self._full.try_inc(self._pending_full):
+            self._pending_full = 0

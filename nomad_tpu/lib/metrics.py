@@ -78,6 +78,15 @@ class Counter:
         with self._lock:
             self._value += n
 
+    def try_inc(self, n: float = 1.0) -> bool:
+        """`inc` for a caller that may be running INSIDE this lock on
+        its own thread (a collector callback starts wherever an
+        allocation happens): False while the lock is held, by anyone."""
+        if self._lock.locked():
+            return False
+        self.inc(n)
+        return True
+
     @property
     def value(self) -> float:
         with self._lock:
@@ -145,6 +154,14 @@ class Histogram:
 
     # go-metrics spelling, so call sites read like the reference
     add_sample = add
+
+    def try_add(self, v: float) -> bool:
+        """`add`, as `Counter.try_inc` (a collection can start inside
+        `summary()`, which sorts the window under this lock)."""
+        if self._lock.locked():
+            return False
+        self.add(v)
+        return True
 
     def _window(self) -> List[float]:
         if self._full:

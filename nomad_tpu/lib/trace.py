@@ -30,9 +30,35 @@ Phase taxonomy (what each span bounds):
 - `kernel`      fused placement-kernel dispatch (device + transfer)
 - `plan_apply`  submit_plan → PlanResult (queue hop + verify + commit)
 - `ack`         broker ack/nack point (zero-length terminator)
+
+On the fused batch path `schedule` is also split into what the
+scheduler thread was doing (ISSUE 26), so that `schedule` = `prepare` +
+`park` + `result_wait` + `plan_build` + `plan_apply` + a small tail
+(eval status update):
+
+- `prepare`     process() start (or a refreshed plan's return) → the
+                coordinator's select() entry (reconcile, compile_tg)
+- `park`        select() entry → this request's event set (rendezvous,
+                the predecessor batch, a speculative hold)
+- `result_wait` this waiter's time inside the lazy holder's resolve()
+                (lock wait, or the blocking device→host fetch)
+- `plan_build`  select() return → the next select() / submit_plan()
+                entry (the per-allocation loop of scheduler/generic.py)
+
+`prepare` and `plan_build` are phases in which the thread never blocks
+on purpose: their `time.thread_time()` and wall deltas also sum into
+the counters `sched.phase_cpu_ms` / `sched.phase_wall_ms`, whose ratio
+says how much of "host work" is waiting for the GIL or a store lock.
+These four are in the eval's own trace and the histograms, not in the
+cross-process SpanStore: mirroring them cost 8 % of singles' rate.
+
+`host_span(name)` puts the same phases (and the coordinator's, the
+applier's and the collector's) into the JAX profiler's host plane as
+`nomad/<name>`, on the device trace's clock.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -43,7 +69,42 @@ from .tracectx import SpanStore, TraceContext, new_span_id
 
 #: canonical span order for display/aggregation
 PHASES = ("queue_wait", "claim", "snapshot", "schedule", "pack",
-          "delta_apply", "kernel", "plan_apply", "ack")
+          "delta_apply", "kernel", "plan_apply", "ack",
+          "prepare", "park", "result_wait", "plan_build")
+
+#: (`jax.profiler.TraceAnnotation`, JAX's profile state), looked up on
+#: first use so that importing this module never imports JAX; False
+#: where JAX is absent
+_profiler = None
+_NO_SPAN = contextlib.nullcontext()
+
+
+def host_span(name: str):
+    """Context manager that writes `nomad/<name>` into the host plane of
+    a `jax.profiler` trace (`start_trace` / `trace`) while one is being
+    taken; a null context otherwise, and without JAX.
+
+    `TraceAnnotation` is not free when no profile runs: the binding lets
+    go of the GIL, so under contention every span hands the interpreter
+    to another thread (23 ms a span with four busy threads; 4 % of the
+    single-allocation rate on the chip, PERF.md §6). Hence the look at
+    JAX's own session state first — a private name: where a later JAX
+    has moved it, every span is annotated again, slow but right."""
+    global _profiler
+    if _profiler is None:
+        try:
+            from jax._src import profiler as _p
+
+            _profiler = (_p.TraceAnnotation,
+                         getattr(_p, "_profile_state", None))
+        except ImportError:  # a client-only agent, a plugin host
+            _profiler = False
+    if not _profiler:
+        return _NO_SPAN
+    annotation, state = _profiler
+    if state is not None and state.profile_session is None:
+        return _NO_SPAN
+    return annotation("nomad/" + name)
 
 
 class _Trace:
@@ -75,6 +136,12 @@ class EvalTracer:
         self.source = source
         self._lock = threading.Lock()
         self._traces: "OrderedDict[str, _Trace]" = OrderedDict()
+        #: per scheduler thread: the phases of the eval it is running
+        self._tls = threading.local()
+        self._phase_hists: Dict[str, object] = {}
+        if registry is not None:
+            self._phase_cpu = registry.counter("sched.phase_cpu_ms")
+            self._phase_wall = registry.counter("sched.phase_wall_ms")
 
     # ---- recording ----
 
@@ -168,6 +235,81 @@ class EvalTracer:
 
     def span(self, trace_id: str, phase: str) -> "_SpanCtx":
         return _SpanCtx(self, trace_id, phase)
+
+    # ---- the scheduler thread's own phases (fused batch path) ----
+    #
+    # One eval's phases all run on ONE scheduler thread, so they are
+    # gathered in thread-local state without a lock and recorded in one
+    # go by `host_flush` when `schedule` ends: between a batch's release
+    # and its acks 32 such threads contend for the GIL, and every shared
+    # lock taken there lengthens the batch (PERF.md §6, PR 26). They are
+    # not mirrored into the SpanStore for the same reason.
+
+    def host_arm(self) -> None:
+        """The calling thread starts an eval that parks at a coordinator."""
+        tls = self._tls
+        tls.phases, tls.open = [], None
+        tls.cpu_s = tls.wall_s = 0.0
+
+    def host_begin(self, phase: str) -> None:
+        """Open `phase` (`prepare` / `plan_build`: the thread never
+        blocks on purpose in them) on the calling thread, closing the
+        one still open; a no-op on a thread that is not armed."""
+        tls = self._tls
+        if getattr(tls, "phases", None) is None:
+            return
+        self.host_end()
+        ann = host_span(phase)
+        ann.__enter__()
+        tls.open = (phase, time.monotonic(), time.thread_time(), ann)
+
+    def host_end(self) -> None:
+        """Close the calling thread's open phase, if any."""
+        tls = self._tls
+        cur = getattr(tls, "open", None)
+        if cur is None:
+            return
+        phase, t0, cpu0, ann = cur
+        tls.open = None
+        end = time.monotonic()
+        tls.cpu_s += time.thread_time() - cpu0
+        tls.wall_s += end - t0
+        ann.__exit__(None, None, None)
+        tls.phases.append((phase, t0, end))
+
+    def host_add(self, phase: str, start: float, end: float) -> None:
+        """A phase the caller timed itself (`park`, `result_wait`)."""
+        phases = getattr(self._tls, "phases", None)
+        if phases is not None:
+            phases.append((phase, start, end))
+
+    def host_flush(self, trace_id: str) -> None:
+        """Disarm the calling thread and record what it gathered: the
+        `eval.phase.<name>_ms` histograms, the eval's spans, and the
+        phases' CPU and wall time into `sched.phase_cpu_ms` /
+        `sched.phase_wall_ms`."""
+        tls = self._tls
+        if getattr(tls, "phases", None) is None:
+            return
+        self.host_end()
+        phases, tls.phases = tls.phases, None
+        if not phases:
+            return
+        reg = self.registry
+        if reg is not None:
+            for phase, start, end in phases:
+                h = self._phase_hists.get(phase)
+                if h is None:
+                    h = self._phase_hists[phase] = reg.histogram(
+                        f"eval.phase.{phase}_ms")
+                h.add(max(end - start, 0.0) * 1e3)
+            self._phase_cpu.inc(tls.cpu_s * 1e3)
+            self._phase_wall.inc(tls.wall_s * 1e3)
+        with self._lock:
+            tr = self._traces.get(trace_id)
+            if tr is not None:
+                tr.spans.extend({"phase": p, "start": s, "end": e}
+                                for p, s, e in phases)
 
     # ---- querying ----
 

@@ -225,14 +225,54 @@ def default_ledger() -> TransferLedger:
 # ---- dispatch-pipeline timeline --------------------------------------------
 
 
+def _ms(s: Optional[float], e: Optional[float]) -> Optional[float]:
+    return (None if s is None or e is None
+            else round(max(e - s, 0.0) * 1e3, 3))
+
+
+def _read_parts(rec: dict) -> Dict[str, Optional[float]]:
+    """A record's launch → first-read interval in its five parts (ms;
+    None where an instant was never stamped: a synthetic record, a
+    normal dispatch's `spec_hold_ms`)."""
+    stash = rec["held_at"] if rec["held_at"] is not None \
+        else rec["released_at"]
+    return {
+        "launch_ms": _ms(rec["kernel_start"], rec["launch_end"]),
+        "release_ms": _ms(rec["launch_end"], stash),
+        "spec_hold_ms": _ms(rec["held_at"], rec["released_at"]),
+        "wake_ms": _ms(rec["released_at"], rec["entered_at"]),
+        "fetch_block_ms": _ms(rec["entered_at"], rec["kernel_end"]),
+    }
+
+
 class DispatchTimeline:
     """Bounded ring of per-dispatch pipeline records + overlap math.
 
     One record per coordinator dispatch: host pack interval, device-view
-    resolve interval, kernel launch→land interval (the end arrives
-    asynchronously — whichever waiter materializes the lazy `_BatchOut`
-    first reports it), transfer bytes/count for the dispatch (host→device
-    at commit, the device→host fetch added at kernel end).
+    resolve interval, the launch → FIRST READ interval (`kernel_start` →
+    `kernel_end`; the end arrives asynchronously — whichever waiter
+    materializes the lazy `_BatchOut` first reports it), transfer
+    bytes/count for the dispatch (host→device at commit, the device→host
+    fetch added at kernel end).
+
+    `kernel_end` is the instant the first reader had the outputs on the
+    host, NOT the instant the kernel ended on the device: the device's
+    own end is the profiler's to give (the benchmark's
+    `kernel_device_ms`). Under speculation the outputs are read a whole
+    batch after they landed. What the host did between launch and first
+    read is split into five intervals that sum to `kernel_ms`:
+
+      launch_ms       inside the jitted placement call (jit-cache lookup
+                      and enqueue; a retrace shows here)
+      release_ms      that call's return → waiters released, or stashed
+                      for certification (carry note, stop rows, holder)
+      spec_hold_ms    a speculative dispatch's stash → its release by
+                      certification; absent for a normal dispatch
+      wake_ms         release → the first resolver enters `resolve()`
+      fetch_block_ms  the first resolver's time inside `np.asarray`
+                      (device not done yet + device→host copy), with
+                      `was_ready` = the first output leaf's `is_ready()`
+                      on entry
 
     Derived per record, once its PREDECESSOR's kernel interval is
     complete:
@@ -246,7 +286,13 @@ class DispatchTimeline:
                   can't hide
 
     The first record (no predecessor in the ring) carries null for both
-    and is excluded from aggregates. Records export monotonic offsets
+    and is excluded from aggregates. Where the PREDECESSOR's read did
+    not block (`was_ready` true) its `kernel_end` is only an upper bound
+    of the kernel's end: the record's overlap/bubble are then flagged
+    `bounds_only` (overlap an upper, bubble a lower bound), left out of
+    the `pipeline.overlap_ms` / `pipeline.bubble_ms` histograms and of
+    `summary()`'s aggregates, and counted in
+    `pipeline.kernel_end_unknown`. Records export monotonic offsets
     against a wall anchor exactly like lib/trace.py traces.
 
     `records_after(index, timeout)` is the event-broker long-poll shape
@@ -272,7 +318,8 @@ class DispatchTimeline:
                transfer_count: int,
                upload: Optional[Tuple[float, float]] = None,
                speculative: bool = False,
-               traces: Optional[List[str]] = None) -> int:
+               traces: Optional[List[str]] = None,
+               launch_end: Optional[float] = None) -> int:
         """Append a dispatch record at kernel launch; returns its seq.
         `pack`/`upload`/`view` are monotonic (start, end) intervals —
         `upload` is the explicit packed-buffer host→device transfer
@@ -297,6 +344,10 @@ class DispatchTimeline:
                 "upload_start": upload[0], "upload_end": upload[1],
                 "view_start": view[0], "view_end": view[1],
                 "kernel_start": kernel_start, "kernel_end": None,
+                # launch → first read, split (class docstring)
+                "launch_end": launch_end, "held_at": None,
+                "released_at": None, "entered_at": None,
+                "was_ready": None, "bounds_only": False,
                 "transfer_bytes": int(transfer_bytes),
                 "transfer_count": int(transfer_count),
                 "overlap_ms": None, "bubble_ms": None,
@@ -327,21 +378,43 @@ class DispatchTimeline:
                            max(view[1] - pack[0], 0.0) * 1e3)
         return seq
 
+    def released(self, seq: int, at: float, held: bool = False) -> None:
+        """The dispatch's waiters were released at `at` — or, `held`, its
+        outputs were stashed for certification (a speculative dispatch
+        is released later, by its verdict). No-op for evicted records."""
+        with self._cv:
+            rec = self._find_locked(seq)
+            if rec is not None:
+                rec["held_at" if held else "released_at"] = at
+
     def kernel_end(self, seq: int, end: Optional[float] = None,
-                   fetch_bytes: int = 0, fetch_count: int = 0) -> None:
-        """Close a dispatch's kernel interval (called from the first
-        `_BatchOut` resolver) and fold the device→host fetch into its
-        transfer totals. No-op for evicted records."""
+                   fetch_bytes: int = 0, fetch_count: int = 0,
+                   entered: Optional[float] = None,
+                   was_ready: Optional[bool] = None) -> None:
+        """Close a dispatch's launch → first-read interval (called from
+        the first `_BatchOut` resolver, which entered `resolve()` at
+        `entered` and found the outputs `was_ready` or not) and fold the
+        device→host fetch into its transfer totals. No-op for evicted
+        records."""
         end = time.monotonic() if end is None else end
         reg = self.registry
         kms = None
+        parts = {}
         with self._cv:
             rec = self._find_locked(seq)
             if rec is None:
                 return
             if rec["kernel_end"] is None:
                 rec["kernel_end"] = end
+                rec["entered_at"] = entered
+                rec["was_ready"] = was_ready
+                if rec["held_at"] is not None \
+                        and rec["released_at"] is None:
+                    # a fully rolled-back speculation: read by its
+                    # certifier, released to nobody
+                    rec["released_at"] = entered
                 kms = max(end - rec["kernel_start"], 0.0) * 1e3
+                parts = _read_parts(rec)
             rec["transfer_bytes"] += int(fetch_bytes)
             rec["transfer_count"] += int(fetch_count)
             self._finalize_locked(seq + 1)
@@ -349,6 +422,9 @@ class DispatchTimeline:
         if reg is not None:
             if kms is not None:
                 reg.add_sample("pipeline.kernel_ms", kms)
+            for name, v in parts.items():
+                if v is not None:
+                    reg.add_sample("pipeline." + name, v)
             if fetch_bytes or fetch_count:
                 reg.inc("pipeline.transfer_bytes", fetch_bytes)
                 reg.inc("pipeline.transfer_count", fetch_count)
@@ -425,7 +501,14 @@ class DispatchTimeline:
         rec["overlap_ms"] = round(max(overlap, 0.0) * 1e3, 3)
         rec["bubble_ms"] = round(max(
             rec["kernel_start"] - prev["kernel_end"], 0.0) * 1e3, 3)
-        if self.registry is not None:
+        # the predecessor's outputs had landed before anybody read
+        # them: its kernel ended at some unknown earlier instant
+        rec["bounds_only"] = prev["was_ready"] is True
+        if self.registry is None:
+            return
+        if rec["bounds_only"]:
+            self.registry.inc("pipeline.kernel_end_unknown")
+        else:
             self.registry.add_sample("pipeline.overlap_ms",
                                      rec["overlap_ms"])
             self.registry.add_sample("pipeline.bubble_ms",
@@ -435,11 +518,7 @@ class DispatchTimeline:
 
     def _export(self, rec: dict) -> dict:
         a = self.mono_anchor
-
-        def ms(s, e):
-            return (None if s is None or e is None
-                    else round(max(e - s, 0.0) * 1e3, 3))
-
+        ms = _ms
         return {
             "seq": rec["seq"], "programs": rec["programs"],
             "batched": rec["batched"],
@@ -453,8 +532,11 @@ class DispatchTimeline:
             "upload_ms": ms(rec["upload_start"], rec["upload_end"]),
             "view_ms": ms(rec["view_start"], rec["view_end"]),
             "kernel_ms": ms(rec["kernel_start"], rec["kernel_end"]),
+            **_read_parts(rec),
+            "was_ready": rec["was_ready"],
             "overlap_ms": rec["overlap_ms"],
             "bubble_ms": rec["bubble_ms"],
+            "bounds_only": rec["bounds_only"],
             "speculative": rec.get("speculative", False),
             "spec_outcome": rec.get("spec_outcome"),
             "spec_wasted_frac": rec.get("spec_wasted_frac"),
@@ -506,6 +588,7 @@ class DispatchTimeline:
 
         rolled = [r for r in recs if r["spec_outcome"] == "rolled_back"]
         paired = [r for r in recs if r["overlap_ms"] is not None
+                  and not r["bounds_only"]
                   and not (r["spec_outcome"] == "rolled_back"
                            and _frac(r) >= 1.0)]
         pack_ms = sum(r["host_ms"] or 0.0 for r in paired)
@@ -525,6 +608,8 @@ class DispatchTimeline:
         return {
             "last_seq": seq,
             "dispatches": n,
+            "kernel_end_unknown": sum(1 for r in recs
+                                      if r["bounds_only"]),
             "spec": spec,
             "overlap_pct": round(100.0 * overlap / pack_ms, 2)
             if pack_ms else 0.0,

@@ -37,6 +37,7 @@ import numpy as np
 from ..utils import fast_uuid
 from ..lib import DelayHeap
 from ..lib.metrics import MetricsRegistry
+from ..lib.trace import host_span
 from ..lib.tracectx import TraceContext
 from ..structs import Evaluation
 
@@ -380,16 +381,17 @@ class EvalBroker:
                 if hold_s > 0 and len(picks) >= 2:
                     hold_deadline = time.time() + hold_s
                     t_hold = time.time()
-                    while len(picks) < max_n and not self._shutdown:
-                        pick = self._pick_locked(batch_types,
-                                                 types=batch_types)
-                        if pick is not None:
-                            picks.append(self._deliver_locked(pick))
-                            continue
-                        remaining = hold_deadline - time.time()
-                        if remaining <= 0:
-                            break
-                        self._cv.wait(remaining)
+                    with host_span("drain_hold"):
+                        while len(picks) < max_n and not self._shutdown:
+                            pick = self._pick_locked(batch_types,
+                                                     types=batch_types)
+                            if pick is not None:
+                                picks.append(self._deliver_locked(pick))
+                                continue
+                            remaining = hold_deadline - time.time()
+                            if remaining <= 0:
+                                break
+                            self._cv.wait(remaining)
                     held_ms = (time.time() - t_hold) * 1e3
         # the fairness slots were ADMITTED out of order; the batch's
         # chain order is still strict priority (stable on delivery

@@ -23,6 +23,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ..lib.metrics import MetricsRegistry
+from ..lib.trace import host_span
 from ..scheduler.util import proposed_allocs
 from ..structs import Allocation, Node, Plan, PlanResult, allocs_fit
 from .state import StateStore
@@ -318,6 +319,10 @@ class PlanApplier:
 
     def apply(self, plan: Plan) -> PlanResult:
         """Verify against latest state, commit what fits (plan_apply.go:400)."""
+        with host_span("plan_apply"):
+            return self._apply(plan)
+
+    def _apply(self, plan: Plan) -> PlanResult:
         # Token check (reference: the leader validates the worker still owns
         # the eval before accepting its plan — Plan.Submit → evalBroker token
         # validation, nomad/plan_endpoint.go:31). A nack-timeout redelivery

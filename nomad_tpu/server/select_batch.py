@@ -82,6 +82,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..lib.trace import host_span
 from ..utils import bucket as _bucket
 
 #: process-unique dispatch tokens: view-lease keys AND the carry/plan
@@ -223,9 +224,13 @@ class _SelectReq:
 class _BatchOut:
     """Shared lazy holder for one dispatch's device outputs: the first
     accessor pays the single device→host fetch (blocking until the
-    kernel lands) and fires `on_first_resolve` with the numpy tuple
-    (kernel-span + timeline + fetch-ledger attribution); everyone else
-    reuses the numpy copy. Releasing waiters BEFORE materializing lets
+    kernel lands) and fires `on_first_resolve(np tuple, entered, fetched,
+    was_ready)` (kernel-span + timeline + fetch-ledger attribution);
+    everyone else reuses the numpy copy. `entered`/`fetched` bracket
+    the fetch on the monotonic clock and `was_ready` is `is_ready()` of
+    the first output leaf on entry: True means the kernel had landed
+    before anybody read it, so the read's end says nothing about the
+    kernel's. Releasing waiters BEFORE materializing lets
     their plan construction overlap the in-flight kernel — and frees
     the coordinator thread to pack the NEXT round of parked programs
     while this kernel is still running. The fetch is `np.asarray`, an
@@ -252,13 +257,17 @@ class _BatchOut:
     def resolve(self) -> Tuple:
         with self._lock:
             if self._np is None:
+                entered = time.monotonic()
+                is_ready = getattr(self._dev[0], "is_ready", None)
+                was_ready = bool(is_ready()) if is_ready else None
                 self._np = tuple(np.asarray(x) for x in self._dev)
+                fetched = time.monotonic()
                 # dropping the device refs frees the kernel outputs'
                 # HBM; the residency bookings release with them
                 self._dev = None
                 if self._on_first is not None:
                     cb, self._on_first = self._on_first, None
-                    cb(self._np)
+                    cb(self._np, entered, fetched, was_ready)
             return self._np
 
 
@@ -276,18 +285,13 @@ class SelectCoordinator:
         self._live = 0
         self._parked: List[_SelectReq] = []
         self.window_s = window_s
-        # stats: the coordinator-driving worker thread writes most keys
-        # in _dispatch; kernel_ms is attributed by whichever WAITER
-        # materializes a dispatch's outputs first (the coordinator no
-        # longer blocks on the kernel), so those increments go through
-        # _stats_lock. Readers copy after finish_batch, when every
-        # waiter has resolved. pack_bytes counts the packed-transport
-        # buffers independently of the ledger — the attribution test
-        # cross-checks the two.
-        self._stats_lock = threading.Lock()
+        # stats: counts only, written by the coordinator-driving worker
+        # thread in _dispatch (intervals live in `eval.phase.*` and
+        # `pipeline.*`). Readers copy after finish_batch. pack_bytes
+        # counts the packed-transport buffers independently of the
+        # ledger — the attribution test cross-checks the two.
         self.stats = {"dispatches": 0, "programs": 0, "batched": 0,
-                      "dispatch_ms": 0.0, "view_ms": 0.0, "pack_ms": 0.0,
-                      "kernel_ms": 0.0, "pack_bytes": 0}
+                      "pack_bytes": 0}
         #: eval-lifecycle tracer + program-order → eval-id map (worker
         #: fills trace_ids in start_batch) for per-eval pack/kernel spans
         self.tracer = tracer
@@ -343,14 +347,25 @@ class SelectCoordinator:
         coordinator releases waiters at kernel launch, so this blocks
         until the fused chain actually lands."""
         req = _SelectReq(arrays_fn, params, n_place, order, explain)
+        tracer = self.tracer
+        if tracer is not None:
+            tracer.host_end()  # prepare (or the last group's build)
+        t_in = time.monotonic()
         with self._cv:
             self._parked.append(req)
             self._cv.notify_all()
-        req.event.wait()
+        with host_span("park"):
+            req.event.wait()
+        t_set = time.monotonic()
         if req.err is not None:
             raise req.err
         holder, i, token = req.out
-        out = holder.resolve()
+        with host_span("result_wait"):
+            out = holder.resolve()
+        if tracer is not None:
+            tracer.host_add("park", t_in, t_set)
+            tracer.host_add("result_wait", t_set, time.monotonic())
+            tracer.host_begin("plan_build")
         sel, score, feas, fit = out[:4]
         # a fused dispatch runs with explain when ANY program asked —
         # but a program that opted out must not receive attribution it
@@ -403,7 +418,8 @@ class SelectCoordinator:
             spec, self._spec = self._spec, None
             first = False  # round-1 rendezvous already happened
             try:
-                self._certify_spec(spec)
+                with host_span("certify"):
+                    self._certify_spec(spec)
             except BaseException as e:  # noqa: BLE001 — fail the waiters
                 for r in spec["reqs"]:
                     if not r.event.is_set():
@@ -449,14 +465,6 @@ class SelectCoordinator:
         from ..parallel.mesh import pad_params, stack_params
 
         led = default_ledger()
-        t_start = time.perf_counter()
-        # stats use perf_counter; trace spans use the monotonic clock —
-        # bridge with a one-shot offset so both read the same instants
-        _off = time.monotonic() - t_start
-
-        def _mono(t: float) -> float:
-            return t + _off
-
         self.stats["dispatches"] += 1
         self.stats["programs"] += len(batch)
         # group by owning CLUSTER without resolving the device view yet.
@@ -482,7 +490,7 @@ class SelectCoordinator:
                 key = ("arrays", id(a.capacity))
                 resolved[key] = a
             groups.setdefault(key, []).append(r)
-        _kernel_done = self._kernel_done_factory(led, _mono)
+        _kernel_done = self._kernel_done_factory(led)
 
         for key, reqs in groups.items():
             reqs.sort(key=lambda r: r.order)
@@ -504,18 +512,20 @@ class SelectCoordinator:
                 cluster = getattr(owner, "cluster", None)
                 if cluster is not None and get_active_mesh() is None:
                     if self._dispatch_table(reqs, cluster, want_ex, led,
-                                            _mono, _kernel_done):
+                                            _kernel_done):
                         continue
             if len(reqs) == 1:
                 r = reqs[0]
-                tv = time.perf_counter()
-                with led.scope() as moved:
+                tv = time.monotonic()
+                with led.scope() as moved, host_span("view"):
                     arrays = resolved.get(key) or r.arrays_fn()
-                tk = time.perf_counter()
-                self.stats["view_ms"] += (tk - tv) * 1e3
-                self._trace([r], "delta_apply", _mono(tv), _mono(tk))
+                tk = time.monotonic()
+                self._trace([r], "delta_apply", tv, tk)
                 (p,), m = pad_params([r.params])
-                res = place_task_group_jit(arrays, p, m, explain=want_ex)
+                with host_span("launch"):
+                    res = place_task_group_jit(arrays, p, m,
+                                               explain=want_ex)
+                tl = time.monotonic()
                 seq = 0
                 if self.timeline is not None:
                     # zero-length pack: the single path has no packed
@@ -523,18 +533,16 @@ class SelectCoordinator:
                     # stack._to_device — deliberately outside the guard)
                     seq = self.timeline.commit(
                         programs=1, batched=False,
-                        pack=(_mono(tv), _mono(tv)),
-                        view=(_mono(tv), _mono(tk)),
-                        kernel_start=_mono(tk),
+                        pack=(tv, tv), view=(tv, tk),
+                        kernel_start=tk, launch_end=tl,
                         transfer_bytes=moved[0], transfer_count=moved[1],
                         traces=self._dist_traces([r]))
                 dev = (res.sel_idx, res.sel_score,
                        res.nodes_feasible, res.nodes_fit)
                 if res.explain is not None:
                     dev = dev + tuple(res.explain)
-                r.out = (_BatchOut(dev, _kernel_done([r], tk, seq)),
-                         None, None)
-                r.event.set()
+                out = _BatchOut(dev, _kernel_done([r], tk, seq))
+                self._release(out, seq, [(r, None, None)])
                 continue
             self.stats["batched"] += len(reqs)
             params_list = [r.params for r in reqs]
@@ -545,15 +553,15 @@ class SelectCoordinator:
             if b > len(reqs):
                 pad = _inert_program(params_list[0])
                 params_list = params_list + [pad] * (b - len(reqs))
-            t0 = time.perf_counter()
-            stacked, m = stack_params(params_list)
-            # packed transport: one buffer per dtype class instead of ~40
-            # per-leaf host→device transfers (per-transfer cost not
-            # measured on an attached chip)
-            ibuf, fbuf, ubuf, spec = pack_params(stacked)
-            t1 = time.perf_counter()
-            self.stats["pack_ms"] += (t1 - t0) * 1e3
-            self._trace(reqs, "pack", _mono(t0), _mono(t1))
+            t0 = time.monotonic()
+            with host_span("pack"):
+                stacked, m = stack_params(params_list)
+                # packed transport: one buffer per dtype class instead of
+                # ~40 per-leaf host→device transfers (per-transfer cost
+                # not measured on an attached chip)
+                ibuf, fbuf, ubuf, spec = pack_params(stacked)
+            t1 = time.monotonic()
+            self._trace(reqs, "pack", t0, t1)
             # Everything device-touching from here to launch runs under
             # the transfer guard (NOMAD_TPU_TRANSFER_GUARD): transfers
             # on this path are all EXPLICIT and ledger-accounted, so a
@@ -568,27 +576,27 @@ class SelectCoordinator:
                     dfbuf = jnp.asarray(fbuf)
                     dubuf = jnp.asarray(ubuf)
                 self.stats["pack_bytes"] += nb
-                t2 = time.perf_counter()
+                t2 = time.monotonic()
                 # view AFTER pack, at the last possible instant before
                 # the kernel: the predecessor batch's plans have
                 # committed by now, and the delta log makes this a
                 # row-update instead of a full re-upload (BENCH_r05's
                 # dominant e2e cost)
-                with led.scope() as moved:
+                with led.scope() as moved, host_span("view"):
                     arrays = resolved.get(key) or reqs[0].arrays_fn()
-                tv = time.perf_counter()
-                self.stats["view_ms"] += (tv - t2) * 1e3
-                self._trace(reqs, "delta_apply", _mono(t2), _mono(tv))
-                dev_out = place_packed_chain(arrays, dibuf, dfbuf, dubuf,
-                                             spec, m, explain=want_ex)
+                tv = time.monotonic()
+                self._trace(reqs, "delta_apply", t2, tv)
+                with host_span("launch"):
+                    dev_out = place_packed_chain(
+                        arrays, dibuf, dfbuf, dubuf, spec, m,
+                        explain=want_ex)
+                tl = time.monotonic()
             seq = 0
             if self.timeline is not None:
                 seq = self.timeline.commit(
                     programs=len(reqs), batched=True,
-                    pack=(_mono(t0), _mono(t1)),
-                    upload=(_mono(t1), _mono(t2)),
-                    view=(_mono(t2), _mono(tv)),
-                    kernel_start=_mono(tv),
+                    pack=(t0, t1), upload=(t1, t2), view=(t2, tv),
+                    kernel_start=tv, launch_end=tl,
                     transfer_bytes=nb + moved[0],
                     transfer_count=3 + moved[1],
                     traces=self._dist_traces(reqs))
@@ -597,12 +605,22 @@ class SelectCoordinator:
             # output as the chain lands and rolls straight into its plan
             # apply, while this thread returns to run() and can pack the
             # next round of parked programs against the in-flight kernel
-            for i, r in enumerate(reqs):
-                r.out = (out, i, None)
-                r.event.set()
-        self.stats["dispatch_ms"] += (time.perf_counter() - t_start) * 1e3
+            self._release(out, seq,
+                          [(r, i, None) for i, r in enumerate(reqs)])
 
-    def _kernel_done_factory(self, led, _mono):
+    def _release(self, holder: "_BatchOut", seq: int, outs) -> None:
+        """Hand `holder` to its waiters — `outs` is [(req, program
+        index, token)] — and stamp the instant on the dispatch's
+        timeline record: the end of `release` (of `spec_hold` for a
+        speculative dispatch, which its certification releases) and
+        the start of `wake`."""
+        if self.timeline is not None:
+            self.timeline.released(seq, time.monotonic())
+        for r, i, token in outs:
+            r.out = (holder, i, token)
+            r.event.set()
+
+    def _kernel_done_factory(self, led):
         """Resolver-callback factory shared by the normal dispatch path
         and the speculative one (`_dispatch_spec`) — ONE body, so the
         kernel-land bookkeeping (stats, trace, fetch ledger, timeline,
@@ -611,15 +629,12 @@ class SelectCoordinator:
 
         def _kernel_done(reqs, t_launch, seq, cluster=None, token=None,
                          idxs=None, wave=False, spec_state=None):
-            def cb(np_out):
-                t_end = time.perf_counter()
-                with self._stats_lock:
-                    self.stats["kernel_ms"] += (t_end - t_launch) * 1e3
+            def cb(np_out, entered, t_end, was_ready):
                 if spec_state is not None:
                     # certification reads this to account the wasted
                     # share of a rolled-back speculative kernel
                     spec_state["kernel_ms"] = (t_end - t_launch) * 1e3
-                self._trace(reqs, "kernel", _mono(t_launch), _mono(t_end))
+                self._trace(reqs, "kernel", t_launch, t_end)
                 # the device→host fetch happened HERE (np.asarray on the
                 # first-resolving waiter's thread): credit it to the
                 # dispatch's timeline record + the fetch ledger site
@@ -627,9 +642,11 @@ class SelectCoordinator:
                 led.record("select_batch.fetch", fetch,
                            count=len(np_out))
                 if self.timeline is not None:
-                    self.timeline.kernel_end(seq, _mono(t_end),
+                    self.timeline.kernel_end(seq, t_end,
                                              fetch_bytes=fetch,
-                                             fetch_count=len(np_out))
+                                             fetch_count=len(np_out),
+                                             entered=entered,
+                                             was_ready=was_ready)
                 if cluster is not None:
                     # table-path dispatch: the chain has landed — fill
                     # the carry note's predicted placement rows (per
@@ -676,7 +693,7 @@ class SelectCoordinator:
 
         return _kernel_done
 
-    def _dispatch_table(self, reqs, cluster, want_ex, led, _mono,
+    def _dispatch_table(self, reqs, cluster, want_ex, led,
                         _kernel_done, spec: bool = False) -> bool:
         """Dispatch one cluster group through the device program table.
         Returns False (nothing dispatched, no side effects on reqs) when
@@ -698,7 +715,7 @@ class SelectCoordinator:
         lanes = self._wave_lanes(reqs)
         if len(lanes) >= self._MIN_WAVE_LANES:
             return self._dispatch_table_wave(lanes, cluster, want_ex,
-                                             led, _mono, _kernel_done,
+                                             led, _kernel_done,
                                              spec=spec)
         table = table_for(cluster)
         params_list = [r.params for r in reqs]
@@ -710,11 +727,12 @@ class SelectCoordinator:
         if b > len(reqs):
             pad = _inert_program(params_list[0])
             params_list = params_list + [pad] * (b - len(reqs))
-        t0 = time.perf_counter()
-        prep = table.prepare(params_list)
+        t0 = time.monotonic()
+        with host_span("pack"):
+            prep = table.prepare(params_list)
         if prep is None:
             return False
-        t1 = time.perf_counter()
+        t1 = time.monotonic()
         with guard_scope():
             import jax.numpy as jnp
 
@@ -724,8 +742,7 @@ class SelectCoordinator:
                 # legacy fallback re-packs, so no stats/spans were
                 # recorded yet (they would double-count)
             ti, tf, tu, ins_nb, ins_count = com
-            self.stats["pack_ms"] += (t1 - t0) * 1e3
-            self._trace(reqs, "pack", _mono(t0), _mono(t1))
+            self._trace(reqs, "pack", t0, t1)
             if len(reqs) > 1:
                 self.stats["batched"] += len(reqs)
             nb = (prep.rows.nbytes + prep.dyn_i.nbytes
@@ -736,7 +753,7 @@ class SelectCoordinator:
                 df = jnp.asarray(prep.dyn_f)
                 du = jnp.asarray(prep.dyn_u)
             self.stats["pack_bytes"] += nb + ins_nb
-            t2 = time.perf_counter()
+            t2 = time.monotonic()
             # view AFTER pack, at the last possible instant before the
             # kernel (the predecessor batch's plans have committed and,
             # when its carry survived, resolve here as a zero-transfer
@@ -746,7 +763,7 @@ class SelectCoordinator:
             # launch below.
             token = next(_DISPATCH_TOKENS)
             try:
-                with led.scope() as moved:
+                with led.scope() as moved, host_span("view"):
                     if spec:
                         arrays = stack_mod.spec_chain_view(cluster, token)
                         if arrays is None:
@@ -755,12 +772,13 @@ class SelectCoordinator:
                             # normally once the predecessor commits
                     else:
                         arrays = reqs[0].arrays_fn(lease_token=token)
-                tv = time.perf_counter()
-                self.stats["view_ms"] += (tv - t2) * 1e3
-                self._trace(reqs, "delta_apply", _mono(t2), _mono(tv))
-                out, carry = place_table_chain(
-                    arrays, ti, tf, tu, drows, di, df, du,
-                    prep.sspec, prep.dspec, prep.m, explain=want_ex)
+                tv = time.monotonic()
+                self._trace(reqs, "delta_apply", t2, tv)
+                with host_span("launch"):
+                    out, carry = place_table_chain(
+                        arrays, ti, tf, tu, drows, di, df, du,
+                        prep.sspec, prep.dspec, prep.m, explain=want_ex)
+                tl = time.monotonic()
             except BaseException:
                 # the lease is normally released by the first resolver's
                 # kernel_end; a failed launch has no resolvers
@@ -771,58 +789,77 @@ class SelectCoordinator:
             spec_state = {"reqs": reqs, "idxs": None, "cluster": cluster,
                           "token": token, "lanes":
                           [list(range(len(reqs)))], "kernel_ms": 0.0}
-        seq = 0
-        if self.timeline is not None:
-            seq = self.timeline.commit(
-                programs=len(reqs), batched=len(reqs) > 1,
-                pack=(_mono(t0), _mono(t1)),
-                upload=(_mono(t1), _mono(t2)),
-                view=(_mono(t2), _mono(tv)),
-                kernel_start=_mono(tv),
-                transfer_bytes=nb + ins_nb + moved[0],
-                transfer_count=4 + ins_count + moved[1],
-                speculative=spec)
-        # carry note: once this dispatch's outputs land and its plans
-        # commit, the next refresh may adopt the chain's (used,
-        # dyn_free) carry instead of re-uploading the committed rows.
-        # The token (already leased at resolve) also rides the waiters'
-        # results onto their plans (carry_token): a commit window
-        # covers the carry only when it came from THIS dispatch.
-        # Residency: the carry arrays are held HBM until the next
-        # refresh adopts (re-sites them into the view) or rejects
-        # (drops them — the booking releases with the buffers).
-        from ..lib.hbm import default_hbm
+        return self._launched(
+            reqs, None, cluster, token, arrays, out, carry, spec_state,
+            _kernel_done,
+            dict(programs=len(reqs), batched=len(reqs) > 1,
+                 pack=(t0, t1), upload=(t1, t2), view=(t2, tv),
+                 kernel_start=tv, launch_end=tl,
+                 transfer_bytes=nb + ins_nb + moved[0],
+                 transfer_count=4 + ins_count + moved[1],
+                 speculative=spec))
 
-        hbm = default_hbm()
-        hbm.track("select_batch.carry", carry[0])
-        hbm.track("select_batch.carry", carry[1])
-        evals = [self.trace_ids.get(r.order) for r in reqs]
-        stop_rows = set()
-        for r in reqs:
-            p = r.params
-            for arr in (p.delta_idx, p.pclr_idx, p.pset_idx):
-                a = np.asarray(arr).reshape(-1)
-                stop_rows.update(int(x) for x in a[a >= 0])
-        if spec:
-            stack_mod.spec_chain_advance(cluster, token, evals,
-                                         stop_rows, carry[0], carry[1])
-        else:
-            stack_mod.note_dispatch_carry(cluster, token, arrays, evals,
-                                          stop_rows, carry[0], carry[1])
-        holder = _BatchOut(
-            tuple(out),
-            _kernel_done(reqs, tv, seq, cluster=cluster, token=token,
-                         spec_state=spec_state))
-        if spec:
-            spec_state["holder"] = holder
-            spec_state["seq"] = seq
-            self._spec = spec_state
-            if self.registry is not None:
-                self.registry.inc("spec.launches")
-            return True
-        for i, r in enumerate(reqs):
-            r.out = (holder, i, token)
-            r.event.set()
+    def _launched(self, reqs, idxs, cluster, token, arrays, out, carry,
+                  spec_state, _kernel_done, record: dict) -> bool:
+        """The `release` interval of a table dispatch, chain (`idxs`
+        None) or wave (`idxs` = each request's [lane, position] slot):
+        timeline record, carry note, lazy holder, then the waiters are
+        released — or, for a speculative launch (`spec_state`), stashed
+        for commit-time certification."""
+        from ..lib.hbm import default_hbm
+        from ..scheduler import stack as stack_mod
+
+        spec = spec_state is not None
+        with host_span("release"):
+            seq = 0
+            if self.timeline is not None:
+                seq = self.timeline.commit(**record)
+            # carry note: once this dispatch's outputs land and its
+            # plans commit, the next refresh may adopt the chain's
+            # (used, dyn_free) carry instead of re-uploading the
+            # committed rows. The token (already leased at resolve)
+            # also rides the waiters' results onto their plans
+            # (carry_token): a commit window covers the carry only when
+            # it came from THIS dispatch. Residency: the carry arrays
+            # are held HBM until the next refresh adopts (re-sites them
+            # into the view) or rejects (drops them — the booking
+            # releases with the buffers).
+            hbm = default_hbm()
+            hbm.track("select_batch.carry", carry[0])
+            hbm.track("select_batch.carry", carry[1])
+            evals = [self.trace_ids.get(r.order) for r in reqs]
+            stop_rows = set()
+            for r in reqs:
+                p = r.params
+                for arr in (p.delta_idx, p.pclr_idx, p.pset_idx):
+                    a = np.asarray(arr).reshape(-1)
+                    stop_rows.update(int(x) for x in a[a >= 0])
+            if spec:
+                stack_mod.spec_chain_advance(cluster, token, evals,
+                                             stop_rows, carry[0], carry[1])
+            else:
+                stack_mod.note_dispatch_carry(cluster, token, arrays,
+                                              evals, stop_rows, carry[0],
+                                              carry[1])
+            holder = _BatchOut(
+                tuple(out),
+                _kernel_done(reqs, record["kernel_start"], seq,
+                             cluster=cluster, token=token, idxs=idxs,
+                             wave=idxs is not None,
+                             spec_state=spec_state))
+            if spec:
+                spec_state["holder"] = holder
+                spec_state["seq"] = seq
+                self._spec = spec_state
+                if self.registry is not None:
+                    self.registry.inc("spec.launches")
+                if self.timeline is not None:
+                    self.timeline.released(seq, time.monotonic(),
+                                           held=True)
+                return True
+            self._release(holder, seq,
+                          [(r, j if idxs is None else idxs[j], token)
+                           for j, r in enumerate(reqs)])
         # the launched dispatch has a chain carry to predict from: offer
         # the NEXT batch a speculative launch against it, overlapping
         # this batch's plan commits with its successor's kernel
@@ -867,7 +904,7 @@ class SelectCoordinator:
             min(lanes, key=len).extend(g)
         return [l for l in lanes if l]
 
-    def _dispatch_table_wave(self, lanes, cluster, want_ex, led, _mono,
+    def _dispatch_table_wave(self, lanes, cluster, want_ex, led,
                              _kernel_done, spec: bool = False) -> bool:
         """Dispatch ≥2 disjoint-footprint lanes as ONE fused wave
         through the device program table (`place_table_wave`). Same
@@ -885,24 +922,25 @@ class SelectCoordinator:
 
         reqs = [r for lane in lanes for r in lane]
         table = table_for(cluster)
-        t0 = time.perf_counter()
-        lane_len = _bucket(max(len(lane) for lane in lanes), lo=2)
-        n_lanes = _bucket(len(lanes), lo=2)
-        pad = _inert_program(lanes[0][0].params)
-        params_list: List = []
-        idxs: List[int] = []
-        for li, lane in enumerate(lanes):
-            for pi, r in enumerate(lane):
-                idxs.append(li * lane_len + pi)
-            params_list.extend([r.params for r in lane])
-            params_list.extend([pad] * (lane_len - len(lane)))
-        # fully-inert pad lanes (bucketed lane count shares compiles);
-        # they share the template's table row and fold as no-ops
-        params_list.extend([pad] * ((n_lanes - len(lanes)) * lane_len))
-        prep = table.prepare(params_list)
+        t0 = time.monotonic()
+        with host_span("pack"):
+            lane_len = _bucket(max(len(lane) for lane in lanes), lo=2)
+            n_lanes = _bucket(len(lanes), lo=2)
+            pad = _inert_program(lanes[0][0].params)
+            params_list: List = []
+            idxs: List[int] = []
+            for li, lane in enumerate(lanes):
+                for pi, r in enumerate(lane):
+                    idxs.append(li * lane_len + pi)
+                params_list.extend([r.params for r in lane])
+                params_list.extend([pad] * (lane_len - len(lane)))
+            # fully-inert pad lanes (bucketed lane count shares compiles);
+            # they share the template's table row and fold as no-ops
+            params_list.extend([pad] * ((n_lanes - len(lanes)) * lane_len))
+            prep = table.prepare(params_list)
         if prep is None:
             return False
-        t1 = time.perf_counter()
+        t1 = time.monotonic()
         with guard_scope():
             import jax.numpy as jnp
 
@@ -910,8 +948,7 @@ class SelectCoordinator:
             if com is None:
                 return False  # caps flush raced this prepare
             ti, tf, tu, ins_nb, ins_count = com
-            self.stats["pack_ms"] += (t1 - t0) * 1e3
-            self._trace(reqs, "pack", _mono(t0), _mono(t1))
+            self._trace(reqs, "pack", t0, t1)
             self.stats["batched"] += len(reqs)
             rows2 = prep.rows.reshape(n_lanes, lane_len)
             di3 = prep.dyn_i.reshape(n_lanes, lane_len,
@@ -927,24 +964,25 @@ class SelectCoordinator:
                 df = jnp.asarray(df3)
                 du = jnp.asarray(du3)
             self.stats["pack_bytes"] += nb + ins_nb
-            t2 = time.perf_counter()
+            t2 = time.monotonic()
             # view AFTER pack + atomic lease, exactly like the chain
             # path (see _dispatch_table)
             token = next(_DISPATCH_TOKENS)
             try:
-                with led.scope() as moved:
+                with led.scope() as moved, host_span("view"):
                     if spec:
                         arrays = stack_mod.spec_chain_view(cluster, token)
                         if arrays is None:
                             return False
                     else:
                         arrays = reqs[0].arrays_fn(lease_token=token)
-                tv = time.perf_counter()
-                self.stats["view_ms"] += (tv - t2) * 1e3
-                self._trace(reqs, "delta_apply", _mono(t2), _mono(tv))
-                out, carry = place_table_wave(
-                    arrays, ti, tf, tu, drows, di, df, du,
-                    prep.sspec, prep.dspec, prep.m, explain=want_ex)
+                tv = time.monotonic()
+                self._trace(reqs, "delta_apply", t2, tv)
+                with host_span("launch"):
+                    out, carry = place_table_wave(
+                        arrays, ti, tf, tu, drows, di, df, du,
+                        prep.sspec, prep.dspec, prep.m, explain=want_ex)
+                tl = time.monotonic()
             except BaseException:
                 stack_mod.release_view(cluster, token)
                 raise
@@ -958,57 +996,21 @@ class SelectCoordinator:
             spec_state = {"reqs": reqs, "idxs": idxs, "cluster": cluster,
                           "token": token, "lanes": lanes_idx,
                           "kernel_ms": 0.0}
-        seq = 0
-        if self.timeline is not None:
-            seq = self.timeline.commit(
-                programs=len(reqs), batched=True,
-                pack=(_mono(t0), _mono(t1)),
-                upload=(_mono(t1), _mono(t2)),
-                view=(_mono(t2), _mono(tv)),
-                kernel_start=_mono(tv),
-                transfer_bytes=nb + ins_nb + moved[0],
-                transfer_count=4 + ins_count + moved[1],
-                speculative=spec)
         if self.registry is not None:
             self.registry.inc("wave.dispatches")
             self.registry.inc("wave.programs", len(reqs))
             self.registry.add_sample("wave.lanes", len(lanes))
             self.registry.add_sample("wave.lane_len",
                                      max(len(l) for l in lanes))
-        from ..lib.hbm import default_hbm
-
-        hbm = default_hbm()
-        hbm.track("select_batch.carry", carry[0])
-        hbm.track("select_batch.carry", carry[1])
-        evals = [self.trace_ids.get(r.order) for r in reqs]
-        stop_rows = set()
-        for r in reqs:
-            p = r.params
-            for arr in (p.delta_idx, p.pclr_idx, p.pset_idx):
-                a = np.asarray(arr).reshape(-1)
-                stop_rows.update(int(x) for x in a[a >= 0])
-        if spec:
-            stack_mod.spec_chain_advance(cluster, token, evals,
-                                         stop_rows, carry[0], carry[1])
-        else:
-            stack_mod.note_dispatch_carry(cluster, token, arrays, evals,
-                                          stop_rows, carry[0], carry[1])
-        holder = _BatchOut(
-            tuple(out),
-            _kernel_done(reqs, tv, seq, cluster=cluster, token=token,
-                         idxs=idxs, wave=True, spec_state=spec_state))
-        if spec:
-            spec_state["holder"] = holder
-            spec_state["seq"] = seq
-            self._spec = spec_state
-            if self.registry is not None:
-                self.registry.inc("spec.launches")
-            return True
-        for j, r in enumerate(reqs):
-            r.out = (holder, idxs[j], token)
-            r.event.set()
-        self._offer_spec(cluster)
-        return True
+        return self._launched(
+            reqs, idxs, cluster, token, arrays, out, carry, spec_state,
+            _kernel_done,
+            dict(programs=len(reqs), batched=True,
+                 pack=(t0, t1), upload=(t1, t2), view=(t2, tv),
+                 kernel_start=tv, launch_end=tl,
+                 transfer_bytes=nb + ins_nb + moved[0],
+                 transfer_count=4 + ins_count + moved[1],
+                 speculative=spec))
 
     # ---- speculative launch + commit-time certification (ISSUE 15) ----
 
@@ -1082,23 +1084,16 @@ class SelectCoordinator:
         from ..lib.transfer import default_ledger
 
         led = default_ledger()
-        t_start = time.perf_counter()
-        _off = time.monotonic() - t_start
-
-        def _mono(t: float) -> float:
-            return t + _off
-
         # the SAME resolver callback as the normal path (collision
         # flight events, carry-prediction fill — chain-aware — and
         # lease release included); only the dispatch entry differs
-        _kernel_done = self._kernel_done_factory(led, _mono)
+        _kernel_done = self._kernel_done_factory(led)
         want_ex = any(r.explain for r in batch)
-        if not self._dispatch_table(batch, cluster, want_ex, led, _mono,
+        if not self._dispatch_table(batch, cluster, want_ex, led,
                                     _kernel_done, spec=True):
             return False
         self.stats["dispatches"] += 1
         self.stats["programs"] += len(batch)
-        self.stats["dispatch_ms"] += (time.perf_counter() - t_start) * 1e3
         return True
 
     def _certify_spec(self, spec) -> None:
@@ -1135,11 +1130,9 @@ class SelectCoordinator:
                     if self._fp_hit(fp, stale):
                         rolled.update(lane[pos:])
                         break
-        for i in range(len(reqs)):
-            if i not in rolled:
-                r = reqs[i]
-                r.out = (holder, i if idxs is None else idxs[i], token)
-                r.event.set()
+        self._release(holder, spec["seq"],
+                      [(reqs[i], i if idxs is None else idxs[i], token)
+                       for i in range(len(reqs)) if i not in rolled])
         if not rolled:
             if reg is not None:
                 reg.inc("spec.certified")

@@ -19,6 +19,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ..lib.metrics import MetricsRegistry
+from ..lib.trace import host_span
 from ..scheduler.generic import GenericScheduler
 from ..scheduler.system import SystemScheduler
 from ..structs import Evaluation, Plan, PlanResult
@@ -71,12 +72,19 @@ class EvalContext:
             plan.trace_id = self.eval.trace_id
             plan.trace_span_id = self.eval.trace_span_id
         tracer = getattr(self.server, "tracer", None)
+        if tracer is not None:
+            tracer.host_end()  # plan_build (or prepare)
         t0 = time.monotonic()
+        refreshed = None
         try:
-            return self._submit_plan(plan)
+            result, refreshed = self._submit_plan(plan)
+            return result, refreshed
         finally:
             if tracer is not None:
                 tracer.record(self.eval.id, "plan_apply", start=t0)
+                if refreshed is not None:
+                    # partial commit: the scheduler reconciles again
+                    tracer.host_begin("prepare")
 
     def _submit_plan(self, plan: Plan
                      ) -> Tuple[PlanResult, Optional[object]]:
@@ -297,9 +305,10 @@ class Worker:
             snap = snapshot
             if snap is None:
                 t0 = time.monotonic()
-                snap = self.server.state.snapshot_min_index(
-                    max(eval.modify_index, eval.job_modify_index),
-                    timeout=5.0)
+                with host_span("snapshot"):
+                    snap = self.server.state.snapshot_min_index(
+                        max(eval.modify_index, eval.job_modify_index),
+                        timeout=5.0)
                 if tracer is not None:
                     tracer.record(eval.id, "snapshot", start=t0)
             if snap is None:
@@ -312,8 +321,19 @@ class Worker:
                                                       GenericScheduler):
                 sched.select_coordinator = coordinator
                 sched.select_order = order
+                if tracer is not None:
+                    # the eval parks at the coordinator: its schedule
+                    # span splits into prepare / park / result_wait /
+                    # plan_build on this thread
+                    tracer.host_arm()
             t0 = time.monotonic()
-            sched.process(eval)
+            if tracer is not None:
+                tracer.host_begin("prepare")
+            try:
+                sched.process(eval)
+            finally:
+                if tracer is not None:
+                    tracer.host_flush(eval.id)
             if tracer is not None:
                 tracer.record(eval.id, "schedule", start=t0)
             if eval.type == "_core":
@@ -370,7 +390,8 @@ class Worker:
         need = max(max(ev.modify_index, ev.job_modify_index)
                    for ev, _ in items)
         t0 = time.monotonic()
-        snap = self.server.state.snapshot_min_index(need, timeout=5.0)
+        with host_span("snapshot"):
+            snap = self.server.state.snapshot_min_index(need, timeout=5.0)
         if self.tracer is not None:
             t1 = time.monotonic()
             for ev, _ in items:  # one resolution serves the whole batch
