@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import select
 import signal as _signal
 import subprocess
 import sys
@@ -42,6 +43,9 @@ class ExecutorService:
     #: on a busy dev box). Generous enough that an agent restart's
     #: recover window (seconds–minutes) never races it.
     IDLE_GRACE_S = 900.0
+    #: launch() waits this long for the bootstrap to exec the command
+    #: (interpreter start, cgroup/namespace/chroot set-up: well under 1 s)
+    BOOTSTRAP_WAIT_S = 10.0
 
     def __init__(self) -> None:
         self._proc: Optional[subprocess.Popen] = None
@@ -174,18 +178,35 @@ class ExecutorService:
 
         out = self._rotator(spec, "stdout")
         err = self._rotator(spec, "stderr")
+        # launch() returns when the bootstrap has exec'd the command (or
+        # died trying): taskinit holds this pipe's write end close-on-exec,
+        # so end-of-file here IS the exec. Returning at once let a stop()
+        # in the bootstrap's first ~0.4 s (interpreter start + imports)
+        # TERM the bootstrap itself — a task that traps TERM then "died by
+        # TERM" without ever having run.
+        ready_r, ready_w = os.pipe()
+        init_spec["ready_fd"] = ready_w
         # taskinit must import nomad_tpu regardless of the task's env;
         # the spec rides in an env var (no tempfile lifetime races)
         boot_env = {**os.environ,
                     "PYTHONPATH": os.pathsep.join(p for p in sys.path if p),
                     "NOMAD_TASKINIT_SPEC": json.dumps(init_spec)}
-        self._proc = subprocess.Popen(
-            [sys.executable, "-m", "nomad_tpu.plugins.taskinit"],
-            stdout=subprocess.PIPE if out else subprocess.DEVNULL,
-            stderr=subprocess.PIPE if err else subprocess.DEVNULL,
-            stdin=subprocess.DEVNULL,
-            env=boot_env,
-        )
+        try:
+            self._proc = subprocess.Popen(
+                [sys.executable, "-m", "nomad_tpu.plugins.taskinit"],
+                stdout=subprocess.PIPE if out else subprocess.DEVNULL,
+                stderr=subprocess.PIPE if err else subprocess.DEVNULL,
+                stdin=subprocess.DEVNULL,
+                env=boot_env,
+                pass_fds=(ready_w,),
+            )
+        except BaseException:
+            os.close(ready_r)
+            raise
+        finally:
+            os.close(ready_w)
+        with os.fdopen(ready_r, "rb") as ready:  # nothing is ever written
+            select.select([ready], [], [], self.BOOTSTRAP_WAIT_S)
         for stream, rot in ((self._proc.stdout, out),
                             (self._proc.stderr, err)):
             if stream is None or rot is None:

@@ -61,6 +61,12 @@ def main() -> None:
         with open(sys.argv[1]) as fh:
             spec = json.load(fh)
 
+    # the executor's launch() returns at end-of-file on this pipe: the
+    # exec closes it (and so does dying before it)
+    ready_fd = spec.get("ready_fd")
+    if ready_fd is not None:
+        os.set_inheritable(ready_fd, False)
+
     os.setsid()
 
     cg = spec.get("cgroup")
@@ -129,6 +135,8 @@ def main() -> None:
 
         signal.signal(signal.SIGTERM, forward)
         signal.signal(signal.SIGINT, forward)
+        if ready_fd is not None:
+            os.close(ready_fd)  # ours; the child's goes with its exec
         while True:
             try:
                 done, status = os.waitpid(pid, 0)

@@ -37,6 +37,8 @@ class _Future:
 
     def set(self, result: Optional[PlanResult], error: Optional[Exception] = None
             ) -> None:
+        if self._ev.is_set():
+            return  # the first answer stands (shutdown beat the applier)
         self.result = result
         self.error = error
         self._ev.set()
@@ -62,8 +64,8 @@ class PlanQueue:
         # plans popped by dequeue() but not yet committed (the applier
         # thread pops BEFORE taking the apply mutex) — idle() must count
         # them or the inline fast path could commit ahead of an
-        # already-dequeued higher-priority plan
-        self._in_flight = 0
+        # already-dequeued higher-priority plan; shutdown() fails them
+        self._in_flight: List[_Future] = []
         #: queued + in-flight plans awaiting the serialized leader apply
         #: (ISSUE 13): the contention read on the commit-point mutex —
         #: eagerly created so the series is always exposed
@@ -72,7 +74,7 @@ class PlanQueue:
 
     def _gauge_locked(self) -> None:
         if self._g_depth is not None:
-            self._g_depth.set(len(self._heap) + self._in_flight)
+            self._g_depth.set(len(self._heap) + len(self._in_flight))
 
     def set_enabled(self, enabled: bool) -> None:
         with self._cv:
@@ -87,6 +89,11 @@ class PlanQueue:
     def enqueue(self, plan: Plan) -> _Future:
         fut = _Future()
         with self._cv:
+            if self._shutdown:
+                # nobody dequeues any more: answer at once, or the
+                # submitting worker sleeps out its whole apply timeout
+                fut.set(None, RuntimeError("plan queue shutdown"))
+                return fut
             if not self._enabled:
                 fut.set(None, RuntimeError("plan queue disabled"))
                 return fut
@@ -108,7 +115,7 @@ class PlanQueue:
                     return None
                 if self._heap:
                     _, _, plan, fut = heapq.heappop(self._heap)
-                    self._in_flight += 1
+                    self._in_flight.append(fut)
                     self._gauge_locked()
                     return plan, fut
                 remaining = 1.0
@@ -119,9 +126,10 @@ class PlanQueue:
                 self._cv.wait(min(remaining, 1.0))
 
     def task_done(self) -> None:
-        """Applier thread: the plan returned by dequeue() is committed."""
+        """Applier thread: the plan returned by dequeue() is committed
+        (one applier, so the oldest in flight)."""
         with self._cv:
-            self._in_flight -= 1
+            self._in_flight.pop(0)
             self._gauge_locked()
 
     def idle(self) -> bool:
@@ -129,12 +137,15 @@ class PlanQueue:
         path's gate."""
         with self._cv:
             return (self._enabled and not self._heap
-                    and self._in_flight == 0 and not self._shutdown)
+                    and not self._in_flight and not self._shutdown)
 
     def shutdown(self) -> None:
         with self._cv:
             self._shutdown = True
-            for _, _, _, fut in self._heap:
+            # queued AND dequeued-but-uncommitted: a submitting worker
+            # must not sleep out its apply timeout against a stopped
+            # applier (as RpcClient.close() fails its in-flight waiters)
+            for fut in [f for _, _, _, f in self._heap] + self._in_flight:
                 fut.set(None, RuntimeError("plan queue shutdown"))
             self._heap.clear()
             self._gauge_locked()
@@ -287,6 +298,9 @@ class PlanApplier:
             plan, fut = item
             try:
                 with self._apply_lock:
+                    if self._stop.is_set():
+                        # its worker was already told so by shutdown()
+                        raise RuntimeError("plan queue shutdown")
                     result = self.apply(plan)
                 fut.set(result)
             except Exception as e:  # noqa: BLE001 — fail the waiting worker

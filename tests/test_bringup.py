@@ -16,7 +16,7 @@ from nomad_tpu.lib import backend
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(argv, cwd=REPO, env=None, timeout=300):
+def _run(argv, cwd=REPO, env=None, timeout=120):
     return subprocess.run([sys.executable] + argv, cwd=cwd, env=env,
                           capture_output=True, timeout=timeout)
 
@@ -230,7 +230,7 @@ class TestBench:
         exits non-zero with the reason and prints no metric line."""
         r = _run(["bench.py"],
                  env=_env(JAX_PLATFORMS=None, NOMAD_TPU_BENCH_LINT="0"),
-                 timeout=180)
+                 timeout=120)
         assert r.returncode != 0
         assert r.stdout.decode().strip() == ""
         assert "no accelerator" in \
@@ -366,3 +366,33 @@ class TestHeartbeatTracker:
             assert seen.wait(10.0)
         finally:
             hb.shutdown()
+
+
+class TestPerTestLimit:
+    """tests/conftest.py: a test that never returns fails alone."""
+
+    def test_a_stuck_test_fails_with_its_stacks_and_the_next_one_runs(
+            self, tmp_path):
+        conftest = os.path.join(REPO, "tests", "conftest.py")
+        (tmp_path / "conftest.py").write_text(
+            "import importlib.util\n"
+            f"spec = importlib.util.spec_from_file_location('c', {conftest!r})\n"
+            "c = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(c)\n"
+            "c.TEST_LIMIT_S = 2.0\n"
+            "pytest_runtest_protocol = c.pytest_runtest_protocol\n")
+        (tmp_path / "test_stuck.py").write_text(
+            "import threading\n"
+            "def test_waits_forever(): threading.Event().wait()\n"
+            "def test_after_it(): pass\n")
+        r = _run(["-m", "pytest", "-q", "-p", "no:cacheprovider", "-p",
+                  "no:xdist", "-p", "no:randomly", str(tmp_path)],
+                 cwd=str(tmp_path), env=_env(JAX_PLATFORMS="cpu"),
+                 timeout=60)
+        out = r.stdout.decode() + r.stderr.decode()
+        assert r.returncode == 1, out[-3000:]
+        assert "1 failed, 1 passed" in out, out[-3000:]
+        assert "test_waits_forever exceeded the per-test limit of 2 s" in out
+        # every thread's stack, the stuck frame among them
+        assert "Current thread 0x" in out
+        assert 'test_stuck.py", line 2 in test_waits_forever' in out

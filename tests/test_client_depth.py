@@ -146,7 +146,7 @@ class TestStickyDiskMigration:
         # budget still flaked (round-5), so it carries real headroom now
         ok = _wait(lambda: any(
             al.client_status == "complete" and al.job_version == 1
-            for al in api.job_allocations(job.id)), timeout=240.0)
+            for al in api.job_allocations(job.id)), timeout=120.0)
         if not ok:
             import json as _json
             diag = {

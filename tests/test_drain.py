@@ -461,7 +461,7 @@ def _run_feed(n_nodes, jobs_fn, eval_batch, monkeypatch, seed=17):
         for ev in evs:
             got = s.wait_for_eval(
                 ev.id, statuses=("complete", "failed", "blocked",
-                                 "cancelled"), timeout=300.0)
+                                 "cancelled"), timeout=120.0)
             assert got is not None and got.status == "complete", got
         node_names = {nid: nd.name for nid, nd in s.state._nodes.items()}
         placements = {}
@@ -568,7 +568,7 @@ class TestLoadedWindowCounters:
                 for ev in evs:
                     got = s.wait_for_eval(
                         ev.id, statuses=("complete", "failed", "blocked",
-                                         "cancelled"), timeout=300.0)
+                                         "cancelled"), timeout=120.0)
                     assert got is not None and got.status == "complete",\
                         got
                 if w == 0:

@@ -76,7 +76,7 @@ def feed(tmp_path_factory):
         for ev in evs:
             got = s.wait_for_eval(
                 ev.id, statuses=("complete", "failed", "blocked",
-                                 "cancelled"), timeout=300.0)
+                                 "cancelled"), timeout=120.0)
             assert got is not None and got.status == "complete", got
         gc.collect()
         out = {"traces": [s.tracer.get(ev.id) for ev in evs],

@@ -1,12 +1,14 @@
 """Control-plane end-to-end tests (reference test strategy SURVEY §4.3:
 in-process server, real broker/planner/workers, mock fixtures)."""
+import threading
 import time
 
 import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.server import Server, ServerConfig
-from nomad_tpu.structs import Evaluation
+from nomad_tpu.server.worker import EvalContext
+from nomad_tpu.structs import Evaluation, Plan
 from nomad_tpu.structs.node import NODE_STATUS_DOWN
 
 
@@ -168,3 +170,50 @@ def test_broker_serializes_per_job(server):
     d2 = server.wait_for_eval(ev2.id)
     assert d1 is not None and d1.status == "complete"
     assert d2 is not None and d2.status == "complete"
+
+
+@pytest.mark.parametrize("where", ["queued", "in_flight", "after_shutdown"])
+def test_shutdown_answers_a_plan_that_is_never_applied(where):
+    """A worker's `_submit_plan` waits up to 30 s for its plan's future:
+    when the applier stops, every future still queued, dequeued but not
+    committed, or enqueued afterwards is failed at once (no scheduler
+    thread sleeps out the time-out after `Server.shutdown()`)."""
+    s = Server(ServerConfig(num_schedulers=0, heartbeat_ttl=60.0))
+    s.start()
+    stopper = threading.Thread(target=s.shutdown)
+    errs = []
+
+    def submit():  # what a scheduler thread does with its plan
+        ctx = EvalContext(s, Evaluation(id="e-late"), "tok", None)
+        try:
+            ctx._submit_plan(Plan(eval_id="e-late"))
+        except Exception as e:  # noqa: BLE001
+            errs.append(e)
+
+    # the commit-point mutex held: the applier dequeues the first plan
+    # and can apply neither it nor the one queued behind it
+    with s.planner._apply_lock:
+        futs = [s.plan_queue.enqueue(Plan(eval_id=f"e{i}")) for i in (0, 1)]
+        dl = time.monotonic() + 5.0
+        while not s.plan_queue._in_flight and time.monotonic() < dl:
+            time.sleep(0.01)
+        assert s.plan_queue._in_flight == futs[:1]
+        t0 = time.monotonic()
+        stopper.start()  # joins the blocked applier for 2 s
+        if where == "after_shutdown":
+            while not s.plan_queue._shutdown:
+                time.sleep(0.001)
+            t = threading.Thread(target=submit)
+            t.start()
+            t.join(5.0)
+            assert not t.is_alive()
+        else:
+            with pytest.raises(RuntimeError, match="plan queue shutdown"):
+                futs[where == "queued"].wait(5.0)
+        assert time.monotonic() - t0 < 1.0
+    stopper.join(10.0)
+    assert not stopper.is_alive()
+    if where == "after_shutdown":
+        assert "plan queue shutdown" in str(errs[0])
+    # the plan that was in flight is not committed behind its worker's back
+    assert int(s.planner.stats["applied"]) == 0

@@ -953,7 +953,7 @@ def _spec_feed(monkeypatch, speculate, n_jobs=24, eval_batch=8,
         for ev in evs:
             got = s.wait_for_eval(
                 ev.id, statuses=("complete", "failed", "blocked",
-                                 "cancelled"), timeout=300.0)
+                                 "cancelled"), timeout=120.0)
             assert got is not None and got.status == "complete", got
         node_names = {nid: nd.name for nid, nd in s.state._nodes.items()}
         placements = {}

@@ -451,7 +451,8 @@ class TestLabeledExposition:
         includes the process ledger's labeled family."""
         from nomad_tpu.lib.transfer import default_ledger
 
-        default_ledger().record("test.exposition_site", 11)
+        led = default_ledger()
+        led.record("test.exposition_site", 11)
         from nomad_tpu.agent import Agent, AgentConfig
 
         a = Agent(AgentConfig(client=False, heartbeat_ttl=60.0))
@@ -460,5 +461,10 @@ class TestLabeledExposition:
             text = a.metrics_prometheus()
         finally:
             a.shutdown()
+            # the process ledger outlives this test: a made-up site left
+            # in it fails test_metrics_names' site pin whenever both
+            # files land on one xdist worker
+            with led._lock:
+                led._sites.pop("test.exposition_site", None)
         assert ('nomad_transfer_bytes_total{site="test.exposition_site"}'
                 in text)

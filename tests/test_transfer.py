@@ -425,7 +425,7 @@ class TestLedgerAttributionE2E:
             for ev in evs:
                 assert s.wait_for_eval(
                     ev.id, statuses=("complete", "failed", "blocked",
-                                     "cancelled"), timeout=600.0)
+                                     "cancelled"), timeout=120.0)
             led = default_ledger()
             reg = default_registry()
             led0 = led.snapshot()
@@ -437,7 +437,7 @@ class TestLedgerAttributionE2E:
             for ev in evs:
                 got = s.wait_for_eval(
                     ev.id, statuses=("complete", "failed", "blocked",
-                                     "cancelled"), timeout=600.0)
+                                     "cancelled"), timeout=120.0)
                 if got is not None:
                     done += 1
             assert done == n_evals
