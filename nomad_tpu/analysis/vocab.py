@@ -81,6 +81,11 @@ PROM_REQUIRED = frozenset({
     "nomad_plan_apply_applied", "nomad_plan_apply_partial",
     "nomad_plan_apply_rejected_nodes", "nomad_plan_apply_stale_token",
     "nomad_plan_apply_inline", "nomad_plan_apply_apply_ms",
+    # device instance ids verified at the commit point (ISSUE 28): nodes
+    # rejected with reason `devices`; the scheduler's device offers and
+    # those made again (refreshed plan, reselected node)
+    "nomad_plan_apply_rejected_devices",
+    "nomad_sched_device_offers", "nomad_sched_device_offer_retries",
     # eval-lifecycle phase histograms (lib/trace.py taxonomy)
     "nomad_eval_phase_schedule_ms", "nomad_eval_phase_plan_apply_ms",
     # device-view delta refresh (scheduler/stack.py)

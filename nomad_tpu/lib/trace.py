@@ -44,6 +44,10 @@ scheduler thread was doing (ISSUE 26), so that `schedule` = `prepare` +
                 (lock wait, or the blocking device→host fetch)
 - `plan_build`  select() return → the next select() / submit_plan()
                 entry (the per-allocation loop of scheduler/generic.py)
+- `device_offer` inside `plan_build`, only for an eval whose group asks
+                for a device: the time its offers spent drawing instance
+                ids (DeviceAllocator + assign_task_devices), summed into
+                one span per eval (ISSUE 28)
 
 `prepare` and `plan_build` are phases in which the thread never blocks
 on purpose: their `time.thread_time()` and wall deltas also sum into
@@ -52,9 +56,9 @@ says how much of "host work" is waiting for the GIL or a store lock.
 These four are in the eval's own trace and the histograms, not in the
 cross-process SpanStore: mirroring them cost 8 % of singles' rate.
 
-`host_span(name)` puts the same phases (and the coordinator's, the
-applier's and the collector's) into the JAX profiler's host plane as
-`nomad/<name>`, on the device trace's clock.
+`host_span(name)` puts the same phases (each `device_offer` by itself;
+and the coordinator's, the applier's and the collector's) into the JAX
+profiler's host plane as `nomad/<name>`, on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -70,7 +74,7 @@ from .tracectx import SpanStore, TraceContext, new_span_id
 #: canonical span order for display/aggregation
 PHASES = ("queue_wait", "claim", "snapshot", "schedule", "pack",
           "delta_apply", "kernel", "plan_apply", "ack",
-          "prepare", "park", "result_wait", "plan_build")
+          "prepare", "park", "result_wait", "plan_build", "device_offer")
 
 #: (`jax.profiler.TraceAnnotation`, JAX's profile state), looked up on
 #: first use so that importing this module never imports JAX; False
@@ -142,6 +146,10 @@ class EvalTracer:
         if registry is not None:
             self._phase_cpu = registry.counter("sched.phase_cpu_ms")
             self._phase_wall = registry.counter("sched.phase_wall_ms")
+            # device offers (scheduler/generic.py): made, and made again
+            # after a rejection at commit or on a reselected node
+            registry.counter("sched.device_offers")
+            registry.counter("sched.device_offer_retries")
 
     # ---- recording ----
 

@@ -14,14 +14,27 @@ same two-tier design as ports. Documented deviation: device *affinities*
 influence which device group's instances are picked on the chosen node, not
 the node choice itself (the reference folds the affinity score into the node
 score, rank.go:301-320); the oracle mirrors the kernel so parity holds.
+
+Fused batches: the kernel's chain counts devices and is right, but each
+eval draws its instance IDs from its OWN snapshot plus its own plan, which
+holds nothing of what its batch-mates drew or committed meanwhile. The plan
+applier verifies instance IDs at the commit point like ports
+(`server/plan_apply.py`, reason `devices`) and the rejected node is offered
+again on a refreshed snapshot — the reference's way. `DeviceHolds` keeps
+batch-mates from colliding in the first place: the offer also excludes what
+the cluster's live ledger (`tensor/cluster.py device_refs`) shows committed
+and what other evals still building their plans were handed.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+import threading
+import weakref
+from typing import Dict, List, Optional, Tuple
 
 from ..structs.devices import DeviceAccounter
 from ..structs.resources import (AllocatedDeviceResource, NodeDeviceResource,
                                  RequestedDevice)
+from ..tensor.cluster import device_keys
 
 
 def _device_value(dev: NodeDeviceResource, target: str) -> Tuple[Optional[str], bool]:
@@ -152,3 +165,74 @@ def assign_task_devices(allocator: DeviceAllocator, tg):
                 return None, f"task {t.name}: {err}"
             out.setdefault(t.name, []).append(offer)
     return out, ""
+
+
+def asks_devices(tg) -> bool:
+    return any(t.resources.devices for t in tg.tasks)
+
+
+class DeviceHolds:
+    """Instance IDs handed to evals whose plans are still being built or
+    applied, per node: {node id: {(group id, instance id): eval id}}.
+    Leader-side scratch, never state: an eval's holds go when its attempt
+    ends (committed IDs are in the cluster's ledger by then, rejected ones
+    are free again). The lock is taken only by groups that ask for a
+    device."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._by_node: Dict[str, Dict[Tuple[str, str], str]] = {}
+        self._by_owner: Dict[str, List[Tuple[str, Tuple[str, str]]]] = {}
+
+    def assign(self, cluster, node, allocator: DeviceAllocator, tg, plan):
+        """`assign_task_devices` with the IDs excluded that a live alloc
+        holds in `cluster`'s ledger (unless `plan` releases it) or that
+        another eval was handed; what is assigned is held for
+        `plan.eval_id`."""
+        released = {a.id for a in plan.node_update.get(node.id, ())}
+        released.update(a.id for a in plan.node_preemptions.get(node.id, ()))
+        released.update(a.id for a in plan.node_allocation.get(node.id, ()))
+        row = cluster.row_of.get(node.id)
+        owner = plan.eval_id
+        with self._lock:
+            held = self._by_node.get(node.id, {})
+            taken = [k for k, o in held.items() if o != owner]
+            if row is not None:
+                taken += [k for k, holders in
+                          tuple(cluster.device_refs[row].items())
+                          if any(h not in released for h in holders)]
+            for group, inst in taken:
+                acct = allocator.accounter.devices.get(group)
+                if acct is not None and acct.instances.get(inst) == 0:
+                    acct.instances[inst] = 1
+            offers, err = assign_task_devices(allocator, tg)
+            if offers is not None:
+                held = self._by_node.setdefault(node.id, held)
+                mine = self._by_owner.setdefault(owner, [])
+                for task_offers in offers.values():
+                    for key in device_keys(task_offers):
+                        held[key] = owner
+                        mine.append((node.id, key))
+        return offers, err
+
+    def release(self, owner: str) -> None:
+        with self._lock:
+            for node_id, key in self._by_owner.pop(owner, ()):
+                held = self._by_node.get(node_id)
+                if held is not None and held.get(key) == owner:
+                    del held[key]
+                    if not held:
+                        del self._by_node[node_id]
+
+
+#: cluster -> DeviceHolds (weak: the holds die with their cluster)
+_HOLDS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_HOLDS_LOCK = threading.Lock()
+
+
+def holds_for(cluster) -> DeviceHolds:
+    with _HOLDS_LOCK:
+        h = _HOLDS.get(cluster)
+        if h is None:
+            h = _HOLDS[cluster] = DeviceHolds()
+        return h

@@ -48,6 +48,9 @@ from .reconcile import (
     ReconcileResults,
     ALLOC_UPDATING,
 )
+from ..lib.trace import host_span
+from .device import (DeviceAllocator, asks_devices, assign_task_devices,
+                     holds_for)
 from .stack import PlanContext, TPUStack
 from .util import (
     Planner,
@@ -98,6 +101,13 @@ class GenericScheduler:
         #: set by the worker's batch path (server/select_batch.py) to
         #: fuse this eval's placement dispatches with its batch-mates'
         self.select_coordinator = None
+        #: attempts begun (a plan refreshed after a rejection is another)
+        self._attempts = 0
+        #: device offers of this eval: the holds they drew past (None until
+        #: the first: an eval that asks for no device never touches them),
+        #: the first one's start and the seconds they took together
+        self._holds = None
+        self._offer_t0 = self._offer_s = 0.0
 
     # ---- entry point ----
 
@@ -105,9 +115,12 @@ class GenericScheduler:
         """Reference Process (generic_sched.go:125)."""
         self.eval = eval
         limit = MAX_BATCH_ATTEMPTS if self.batch else MAX_SERVICE_ATTEMPTS
-        err = retry_max(
-            limit, self._process, lambda: progress_made(self.plan_result)
-        )
+        try:
+            err = retry_max(
+                limit, self._process, lambda: progress_made(self.plan_result)
+            )
+        finally:
+            self._device_offers_end()
         if err is not None:
             if isinstance(err, SetStatusError):
                 self._create_blocked_eval(plan_failure=True)
@@ -163,6 +176,11 @@ class GenericScheduler:
         self.job = self.state.job_by_id(ev.namespace, ev.job_id)
         self.queued_allocs = {}
         self.follow_up_evals = []
+        if self._holds is not None:
+            # what the last attempt was handed is committed (and in the
+            # cluster's ledger) or was rejected (and is free again)
+            self._holds.release(ev.id)
+        self._attempts += 1
         self.plan = ev.make_plan(self.job)
         # optimistic carry-exact certification (device-resident plan
         # deltas): only fused-coordinator dispatches produce a device
@@ -604,8 +622,47 @@ class GenericScheduler:
             ctx.preferred_node_ids.append(preferred)
         return ctx
 
-    def _allocated_resources(self, tg: TaskGroup, node):
-        return allocated_resources(self.state, self.plan, tg, node)
+    def _allocated_resources(self, tg: TaskGroup, node, again: bool = False):
+        """`allocated_resources`; for a group that asks for a device the
+        instance ids are drawn past what batch-mates hold (`DeviceHolds`),
+        and the offer is counted and timed. `again`: the offer repeats one
+        that failed or was rejected (a reselected node, a refreshed plan)."""
+        if node is None or not asks_devices(tg):
+            return allocated_resources(self.state, self.plan, tg, node)
+        reg = self._registry()
+        reg.inc("sched.device_offers")
+        if again or self._attempts > 1:
+            reg.inc("sched.device_offer_retries")
+
+        if self._holds is None:
+            self._holds = holds_for(self.cluster)
+
+        def offer_devices(proposed):
+            t0 = time.monotonic()
+            with host_span("device_offer"):
+                out = self._holds.assign(
+                    self.cluster, node, DeviceAllocator(node, proposed), tg,
+                    self.plan)
+            if not self._offer_s:
+                self._offer_t0 = t0
+            self._offer_s += time.monotonic() - t0
+            return out
+
+        return allocated_resources(self.state, self.plan, tg, node,
+                                   offer_devices=offer_devices)
+
+    def _device_offers_end(self) -> None:
+        """The eval ends: its holds go, and the time its device offers
+        took is one `device_offer` phase of its trace (gathered on this
+        thread, recorded when `schedule` ends: lib/trace.py)."""
+        if self._holds is None:
+            return
+        self._holds.release(self.eval.id)
+        tracer = getattr(getattr(self.planner, "server", None), "tracer",
+                         None)
+        if tracer is not None:
+            tracer.host_add("device_offer", self._offer_t0,
+                            self._offer_t0 + self._offer_s)
 
     def _reselect_excluding(self, tg: TaskGroup, entry, excluded: set,
                             first_err: str):
@@ -633,14 +690,15 @@ class GenericScheduler:
             if node_id is None:
                 break
             node = self.state.node_by_id(node_id)
-            alloc_res, err = self._allocated_resources(tg, node)
+            alloc_res, err = self._allocated_resources(tg, node, again=True)
             if err is None:
                 return node_id, node, sel.scores[0], alloc_res, None
             excluded.add(node_id)
         return None, None, 0.0, None, err
 
 
-def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node):
+def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node,
+                        offer_devices=None):
     """Grant resources + assign ports for a placement (reference:
     BinPackIterator's per-task network/port assignment, rank.go:231-320).
     Port assignment happens host-side against the node's NetworkIndex built
@@ -650,8 +708,11 @@ def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node):
     Returns (resources, error): a non-None error means the node cannot
     satisfy the group's port asks and the placement MUST fail (the reference
     ranks such nodes out, rank.go:256-267 — an alloc is never placed with
-    its ports silently dropped)."""
-    from .device import DeviceAllocator, assign_task_devices
+    its ports silently dropped).
+
+    Device instance ids come from the same proposed allocs;
+    `offer_devices(proposed)` stands in for that step where the caller
+    knows more (GenericScheduler: what batch-mates hold)."""
 
     tasks: Dict[str, AllocatedTaskResources] = {}
     shared = AllocatedSharedResources(disk_mb=tg.ephemeral_disk.size_mb)
@@ -663,7 +724,11 @@ def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node):
         net_idx = NetworkIndex()
         net_idx.set_node(node)
         net_idx.add_allocs(proposed)
-        offers, derr = assign_task_devices(DeviceAllocator(node, proposed), tg)
+        if offer_devices is not None:
+            offers, derr = offer_devices(proposed)
+        else:
+            offers, derr = assign_task_devices(
+                DeviceAllocator(node, proposed), tg)
         if offers is None:
             return None, derr
         dev_offers = offers

@@ -1617,8 +1617,14 @@ class TPUStack:
         # Match the ask against the registered vendor/type device pools
         # (structs.RequestedDevice.ID, structs.go:2552-2554: <type>,
         # <vendor>/<type>, <vendor>/<type>/<name>). Model-specific 3-part
-        # asks charge their pool's column; the exact group is resolved
-        # host-side (DeviceAllocator) with offer-retry on mismatch.
+        # asks charge their pool's column; the exact group and the
+        # instance ids are resolved host-side at offer time
+        # (scheduler/device.py DeviceAllocator). Where the column said
+        # yes and the offer finds no instance, the placement is offered
+        # again on the next-best nodes (generic.py _reselect_excluding,
+        # three deep) and then fails; an id that a batch-mate took
+        # meanwhile is caught at the commit point (plan_apply, reason
+        # `devices`) and offered again on the refreshed snapshot.
         for pool, col in self.cluster.device_cols.items():
             vendor, dtype = pool.split("/")
             parts = name.split("/")

@@ -153,6 +153,10 @@ class PlanQueue:
 
 
 _DIM_NAMES = {0: "cpu", 1: "memory", 2: "disk", 3: "network"}
+#: rejection reason of a node whose plan oversubscribes a device: an
+#: instance id held by a live alloc or assigned twice, or (the kernel's
+#: count columns) more instances than the node has
+REASON_DEVICES = "devices"
 
 
 def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
@@ -167,8 +171,10 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
 
     freed = np.zeros(R_TOTAL, dtype=np.float32)
     freed_ports: Dict[int, int] = {}
+    released: List[str] = []
 
     def release(alloc_id: str) -> None:
+        released.append(alloc_id)
         u = cl.alloc_usage.get(alloc_id)
         if u is not None and u[0] == row:
             np.add(freed, u[1], out=freed)
@@ -184,6 +190,7 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
 
     placed = None
     placed_ports: List[int] = []
+    placed_devs: List[Tuple[str, str]] = []
     for a in plan.node_allocation.get(node_id, ()):
         release(a.id)  # in-place update: the plan's copy replaces it
         if a.terminal_status():
@@ -191,10 +198,13 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
         try:
             v = cl.usage_row(a)
             ports = cl._alloc_port_list(a)
+            devs = cl._alloc_device_list(a)
         except Exception:  # noqa: BLE001 — odd shape: object path decides
             return None
         placed = v if placed is None else placed + v
         placed_ports.extend(ports)
+        if devs:
+            placed_devs.extend(devs)
 
     if placed is None:
         return True, ""
@@ -203,7 +213,7 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
     over = total > cl.capacity[row] + 1e-3
     if over.any():
         col = int(np.argmax(over))
-        return False, _DIM_NAMES.get(col, "devices")
+        return False, _DIM_NAMES.get(col, REASON_DEVICES)
     seen: set = set()
     for p in placed_ports:
         if p in seen:
@@ -213,6 +223,19 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
         if refs > 0 or (p in cl.base_ports[row]
                         and p not in freed_ports):
             return False, f"port {p} already in use"
+    if placed_devs:
+        # instance ids, like ports: the kernel's columns count them, the
+        # ids are drawn on the host at offer time from the eval's own
+        # snapshot, so two evals of one fused batch can draw the same one
+        # (reference AllocsFit with checkDevices, plan_apply.go:642)
+        held = cl.device_refs[row]
+        seen_devs: set = set()
+        for key in placed_devs:
+            if key in seen_devs:
+                return False, REASON_DEVICES
+            seen_devs.add(key)
+            if any(h not in released for h in held.get(key, ())):
+                return False, REASON_DEVICES
     return True, ""
 
 
@@ -239,7 +262,9 @@ def evaluate_node_plan(state, plan: Plan, node_id: str) -> Tuple[bool, str]:
             return verdict
 
     proposed = proposed_allocs(state, plan, node_id)
-    fit, dim, _util = allocs_fit(node, proposed)
+    fit, dim, _util = allocs_fit(node, proposed, check_devices=True)
+    if dim == "device oversubscribed":
+        dim = REASON_DEVICES
     return fit, dim
 
 
@@ -247,8 +272,8 @@ class PlanApplier:
     """Single-threaded plan verification + commit loop (plan_apply.go:71)."""
 
     #: counter names mirrored by the legacy `stats` view
-    STAT_KEYS = ("applied", "partial", "rejected_nodes", "stale_token",
-                 "inline")
+    STAT_KEYS = ("applied", "partial", "rejected_nodes", "rejected_devices",
+                 "stale_token", "inline")
 
     def __init__(self, state: StateStore, queue: PlanQueue,
                  broker=None,
@@ -390,6 +415,8 @@ class PlanApplier:
                     partial = True
                     rejected.append(node_id)
                     self._ctr["rejected_nodes"].inc()
+                    if reason == REASON_DEVICES:
+                        self._ctr["rejected_devices"].inc()
         if partial and plan.all_at_once:
             # all-at-once plans commit nothing on any failure — including the
             # stops, or destructive updates would halt services with no

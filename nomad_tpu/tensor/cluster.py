@@ -66,6 +66,13 @@ def _delta_log_len() -> int:
     return max(8, val)
 
 
+def device_keys(offers) -> List[Tuple[str, str]]:
+    """(device group id, instance id) of every instance in a list of
+    AllocatedDeviceResource: the key of the device instance ledger."""
+    return [(f"{ad.vendor}/{ad.type}/{ad.name}", inst)
+            for ad in offers for inst in ad.device_ids]
+
+
 def _bucket(n: int, lo: int = 64) -> int:
     b = lo
     while b < n:
@@ -114,6 +121,14 @@ class ClusterTensors:
         self.base_ports: List[frozenset] = [frozenset()] * n_cap
         # alloc_id -> (row, port list) for release on update/removal
         self.alloc_ports: Dict[str, Tuple[int, List[int]]] = {}
+        # device instances in use, the ports' twin: per row (device group
+        # id, instance id) -> the alloc ids that hold it (one, unless state
+        # was restored already doubled), and alloc_id -> (row, keys). Kept
+        # by the same upsert/remove paths as the port ledger and rebuilt
+        # by them on restore: derived state, never a second source.
+        self.device_refs: List[Dict[Tuple[str, str], Tuple[str, ...]]] = [
+            dict() for _ in range(n_cap)]
+        self.alloc_devices: Dict[str, Tuple[int, List[Tuple[str, str]]]] = {}
         self.row_of: Dict[str, int] = {}
         self.node_of_row: List[Optional[str]] = [None] * n_cap
         self.nodes: Dict[str, Node] = {}
@@ -356,6 +371,7 @@ class ClusterTensors:
         df[: self.n_cap] = self.dyn_free
         self.dyn_free = df
         self.port_refs.extend(dict() for _ in range(new_cap - self.n_cap))
+        self.device_refs.extend(dict() for _ in range(new_cap - self.n_cap))
         self.base_ports.extend([frozenset()] * (new_cap - self.n_cap))
         at = np.full((new_cap, self.k_cap), MISSING, dtype=np.int32)
         at[: self.n_cap] = self.attrs
@@ -435,6 +451,38 @@ class ClusterTensors:
                 if 0 <= p.value < PORT_WORDS * 32:
                     out.append(p.value)
         return out
+
+    # ---- device instance ledger ----
+
+    @staticmethod
+    def _alloc_device_list(alloc: Allocation) -> List[Tuple[str, str]]:
+        """(device group id, instance id) of every instance an alloc's
+        offers hold (reference DeviceAccounter.AddAllocs, devices.go:69)."""
+        ar = alloc.allocated_resources
+        if ar is None:
+            return []
+        return [key for tr in ar.tasks.values()
+                for key in device_keys(tr.devices)]
+
+    def _add_alloc_devices(self, alloc_id: str, row: int,
+                           keys: List[Tuple[str, str]]) -> None:
+        refs = self.device_refs[row]
+        for key in keys:
+            refs[key] = refs.get(key, ()) + (alloc_id,)
+        self.alloc_devices[alloc_id] = (row, keys)
+
+    def _release_alloc_devices(self, alloc_id: str) -> None:
+        entry = self.alloc_devices.pop(alloc_id, None)
+        if entry is None:
+            return
+        row, keys = entry
+        refs = self.device_refs[row]
+        for key in keys:
+            rest = tuple(h for h in refs.get(key, ()) if h != alloc_id)
+            if rest:
+                refs[key] = rest
+            else:
+                refs.pop(key, None)
 
     def device_col(self, device_id: str) -> Optional[int]:
         """Column for a device *pool*, keyed by vendor/type (groups of the
@@ -558,11 +606,15 @@ class ClusterTensors:
         self.dyn_free[row] = 0.0
         self.base_ports[row] = frozenset()
         self.port_refs[row] = {}
+        self.device_refs[row] = {}
         # Drop alloc accounting pointing at the freed row — otherwise a
         # later release would mutate whatever node reuses the row, and the
         # upsert_node rebuild would resurrect stale ports/usage.
         for aid in [a for a, (r, _p) in self.alloc_ports.items() if r == row]:
             del self.alloc_ports[aid]
+        for aid in [a for a, (r, _d) in self.alloc_devices.items()
+                    if r == row]:
+            del self.alloc_devices[aid]
         for aid in [a for a, (r, _u) in self.alloc_usage.items() if r == row]:
             del self.alloc_usage[aid]
         for japs in self.job_allocs.values():
@@ -606,6 +658,8 @@ class ClusterTensors:
         if pp is not None:
             touched.append(pp[0])  # release flips that row's dyn_free
         self._release_alloc_ports(alloc.id)
+        if self.alloc_devices:
+            self._release_alloc_devices(alloc.id)
         japs = self.job_allocs.setdefault(alloc.job_id, {})
         japs.pop(alloc.id, None)
 
@@ -625,6 +679,9 @@ class ClusterTensors:
         self.used[row] += usage
         self.alloc_usage[alloc.id] = (row, usage)
         self._add_alloc_ports(alloc.id, row, self._alloc_port_list(alloc))
+        devs = self._alloc_device_list(alloc)
+        if devs:
+            self._add_alloc_devices(alloc.id, row, devs)
         japs[alloc.id] = (row, alloc.task_group)
         touched.append(row)
         self._log_hot(*touched)
@@ -641,6 +698,8 @@ class ClusterTensors:
         if pp is not None:
             touched.append(pp[0])
         self._release_alloc_ports(alloc_id)
+        if self.alloc_devices:
+            self._release_alloc_devices(alloc_id)
         if job_id and job_id in self.job_allocs:
             self.job_allocs[job_id].pop(alloc_id, None)
         else:
