@@ -86,6 +86,10 @@ PROM_REQUIRED = frozenset({
     # those made again (refreshed plan, reselected node)
     "nomad_plan_apply_rejected_devices",
     "nomad_sched_device_offers", "nomad_sched_device_offer_retries",
+    # every offer a scheduler made (resources granted to one placement)
+    # and those that built no NetworkIndex / DeviceAllocator because the
+    # group asks for no port and no device (ISSUE 29)
+    "nomad_sched_offers", "nomad_sched_offers_skipped",
     # eval-lifecycle phase histograms (lib/trace.py taxonomy)
     "nomad_eval_phase_schedule_ms", "nomad_eval_phase_plan_apply_ms",
     # device-view delta refresh (scheduler/stack.py)

@@ -150,6 +150,10 @@ class EvalTracer:
             # after a rejection at commit or on a reselected node
             registry.counter("sched.device_offers")
             registry.counter("sched.device_offer_retries")
+            # every offer, and those that built no per-node index (the
+            # group asks for no port and no device): added once an eval
+            registry.counter("sched.offers")
+            registry.counter("sched.offers_skipped")
 
     # ---- recording ----
 

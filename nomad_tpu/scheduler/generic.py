@@ -108,6 +108,9 @@ class GenericScheduler:
         #: the first one's start and the seconds they took together
         self._holds = None
         self._offer_t0 = self._offer_s = 0.0
+        #: offers of this eval and those that built no per-node index,
+        #: counted on this thread and added to the registry when it ends
+        self._offers = self._offers_skipped = 0
 
     # ---- entry point ----
 
@@ -120,7 +123,7 @@ class GenericScheduler:
                 limit, self._process, lambda: progress_made(self.plan_result)
             )
         finally:
-            self._device_offers_end()
+            self._offers_end()
         if err is not None:
             if isinstance(err, SetStatusError):
                 self._create_blocked_eval(plan_failure=True)
@@ -314,25 +317,13 @@ class GenericScheduler:
             results.destructive_update, results.place
         )
 
-    def _registry(self):
-        """Metrics registry for scheduler.* counters: the owning server's
-        when scheduling for a real server (EvalContext planner), else the
-        process-global one (harness / tests / bare stacks)."""
-        srv = getattr(self.planner, "server", None)
-        reg = getattr(srv, "metrics", None)
-        if reg is None:
-            from ..lib.metrics import default_registry
-
-            reg = default_registry()
-        return reg
-
     def _record_explain_metrics(self, ex: dict) -> None:
         """Fold one select's attribution into the `scheduler.filter.*` /
         `scheduler.exhausted.*` counter families (go-metrics
         `nomad.nomad.blocked_evals`-style rollups; Prometheus exposition
         rides the registry). Dimension keys keep their display names —
         the exposition layer mangles to [a-z0-9_]."""
-        reg = self._registry()
+        reg = planner_registry(self.planner)
         if ex.get("filtered_constraint"):
             reg.inc("scheduler.filter.constraint", ex["filtered_constraint"])
         if ex.get("filtered_device_plugin"):
@@ -444,6 +435,12 @@ class GenericScheduler:
             self.plan.carry_token = result.carry_token
             if result.explain is not None:
                 self._record_explain_metrics(result.explain)
+            # what is the same for every allocation of the group, once:
+            # whether an offer consults the node at all, and (where it
+            # does not, so that every allocation is granted the same)
+            # whether what commits is what the kernel added
+            plain = not offer_needs_node(tg)
+            certified = False
 
             for i, (p, prev, _dest) in enumerate(entries):
                 node_id = result.node_ids[i]
@@ -503,7 +500,8 @@ class GenericScheduler:
                     # generic_sched.go:742).
                     for v in victims:
                         self.plan.append_preempted_alloc(v, alloc_id)
-                alloc_res, net_err = self._allocated_resources(tg, node)
+                alloc_res, net_err = self._allocated_resources(
+                    tg, node, plain=plain)
                 if net_err is not None:
                     # Offer-time assignment (ports/devices) failed on the
                     # selected node: the reference would have ranked it out
@@ -557,8 +555,9 @@ class GenericScheduler:
                     ds = self.deployment.task_groups.get(tg.name)
                     if ds is not None:
                         ds.placed_canaries.append(alloc.id)
-                if self.plan.carry_exact:
+                if self.plan.carry_exact and not (plain and certified):
                     self._certify_carry_exact(alloc, result.ask)
+                    certified = True
                 self.plan.append_alloc(alloc)
         return None
 
@@ -622,14 +621,21 @@ class GenericScheduler:
             ctx.preferred_node_ids.append(preferred)
         return ctx
 
-    def _allocated_resources(self, tg: TaskGroup, node, again: bool = False):
-        """`allocated_resources`; for a group that asks for a device the
-        instance ids are drawn past what batch-mates hold (`DeviceHolds`),
-        and the offer is counted and timed. `again`: the offer repeats one
-        that failed or was rejected (a reselected node, a refreshed plan)."""
-        if node is None or not asks_devices(tg):
+    def _allocated_resources(self, tg: TaskGroup, node, again: bool = False,
+                             plain: bool = False):
+        """`allocated_resources`, counted; for a group that asks for a
+        device the instance ids are drawn past what batch-mates hold
+        (`DeviceHolds`), and the offer is counted and timed. `again`: the
+        offer repeats one that failed or was rejected (a reselected node,
+        a refreshed plan). `plain`: the caller has found, once for the
+        group, that `offer_needs_node(tg)` is false."""
+        self._offers += 1
+        if plain or node is None or not offer_needs_node(tg):
+            self._offers_skipped += 1
+            return group_resources(tg), None
+        if not asks_devices(tg):
             return allocated_resources(self.state, self.plan, tg, node)
-        reg = self._registry()
+        reg = planner_registry(self.planner)
         reg.inc("sched.device_offers")
         if again or self._attempts > 1:
             reg.inc("sched.device_offer_retries")
@@ -651,10 +657,13 @@ class GenericScheduler:
         return allocated_resources(self.state, self.plan, tg, node,
                                    offer_devices=offer_devices)
 
-    def _device_offers_end(self) -> None:
-        """The eval ends: its holds go, and the time its device offers
-        took is one `device_offer` phase of its trace (gathered on this
-        thread, recorded when `schedule` ends: lib/trace.py)."""
+    def _offers_end(self) -> None:
+        """The eval ends: its offers are counted, its holds go, and the
+        time its device offers took is one `device_offer` phase of its
+        trace (gathered on this thread, recorded when `schedule` ends:
+        lib/trace.py)."""
+        count_offers(self.planner, self._offers, self._offers_skipped)
+        self._offers = self._offers_skipped = 0
         if self._holds is None:
             return
         self._holds.release(self.eval.id)
@@ -697,6 +706,50 @@ class GenericScheduler:
         return None, None, 0.0, None, err
 
 
+def planner_registry(planner):
+    """Metrics registry for scheduler.* counters: the owning server's
+    when scheduling for a real server (EvalContext planner), else the
+    process-global one (harness / tests / bare stacks)."""
+    srv = getattr(planner, "server", None)
+    reg = getattr(srv, "metrics", None)
+    if reg is None:
+        from ..lib.metrics import default_registry
+
+        reg = default_registry()
+    return reg
+
+
+def offer_needs_node(tg: TaskGroup) -> bool:
+    """Whether an offer for `tg` consults the node it was placed on: a
+    group network, a task port or a device ask. A group that asks for
+    none of them is granted the same on every node, whatever lives there."""
+    return bool(tg.networks) or any(
+        t.resources.networks or t.resources.devices for t in tg.tasks)
+
+
+def group_resources(tg: TaskGroup) -> AllocatedResources:
+    """What `tg` is granted before any port or device instance: cpu and
+    memory per task, the group's disk. A fresh object per allocation
+    (in-place updates and the client write into it)."""
+    return AllocatedResources(
+        tasks={t.name: AllocatedTaskResources(
+            cpu=t.resources.cpu, memory_mb=t.resources.memory_mb)
+            for t in tg.tasks},
+        shared=AllocatedSharedResources(disk_mb=tg.ephemeral_disk.size_mb))
+
+
+def count_offers(planner, offers: int, skipped: int) -> None:
+    """One eval's offers into `sched.offers`, and those that built no
+    per-node index into `sched.offers_skipped`: once, when the eval ends
+    (an `inc` per allocation from every scheduler thread is a lock
+    hand-off per allocation, PERF.md §6, PR 26)."""
+    if offers:
+        registry = planner_registry(planner)
+        registry.inc("sched.offers", offers)
+        if skipped:
+            registry.inc("sched.offers_skipped", skipped)
+
+
 def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node,
                         offer_devices=None):
     """Grant resources + assign ports for a placement (reference:
@@ -704,6 +757,12 @@ def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node,
     Port assignment happens host-side against the node's NetworkIndex built
     from plan-relative proposed allocs — otherwise two allocs of one eval on
     one node double-book dynamic ports and the plan applier rejects it.
+
+    The proposed allocs, the NetworkIndex and the DeviceAllocator are built
+    only for a group that asks for a port or a device
+    (`offer_needs_node`): they decide nothing else (their collision result
+    was never read here, as in the reference), and building them for every
+    allocation of a thousand on a handful of nodes is quadratic in the job.
 
     Returns (resources, error): a non-None error means the node cannot
     satisfy the group's port asks and the placement MUST fail (the reference
@@ -713,45 +772,36 @@ def allocated_resources(state: State, plan: Plan, tg: TaskGroup, node,
     Device instance ids come from the same proposed allocs;
     `offer_devices(proposed)` stands in for that step where the caller
     knows more (GenericScheduler: what batch-mates hold)."""
+    if node is None or not offer_needs_node(tg):
+        return group_resources(tg), None
 
-    tasks: Dict[str, AllocatedTaskResources] = {}
-    shared = AllocatedSharedResources(disk_mb=tg.ephemeral_disk.size_mb)
+    proposed = proposed_allocs(state, plan, node.id)
+    net_idx = NetworkIndex()
+    net_idx.set_node(node)
+    net_idx.add_allocs(proposed)
+    if offer_devices is not None:
+        dev_offers, derr = offer_devices(proposed)
+    else:
+        dev_offers, derr = assign_task_devices(
+            DeviceAllocator(node, proposed), tg)
+    if dev_offers is None:
+        return None, derr
 
-    net_idx: Optional[NetworkIndex] = None
-    dev_offers: Dict[str, list] = {}
-    if node is not None:
-        proposed = proposed_allocs(state, plan, node.id)
-        net_idx = NetworkIndex()
-        net_idx.set_node(node)
-        net_idx.add_allocs(proposed)
-        if offer_devices is not None:
-            offers, derr = offer_devices(proposed)
-        else:
-            offers, derr = assign_task_devices(
-                DeviceAllocator(node, proposed), tg)
-        if offers is None:
-            return None, derr
-        dev_offers = offers
-
+    res = group_resources(tg)
     for t in tg.tasks:
-        tr = AllocatedTaskResources(
-            cpu=t.resources.cpu, memory_mb=t.resources.memory_mb,
-            devices=list(dev_offers.get(t.name, ())),
-        )
+        tr = res.tasks[t.name]
+        tr.devices = list(dev_offers.get(t.name, ()))
         for ask in t.resources.networks:
-            if net_idx is not None:
-                offer, err = net_idx.assign_network(ask)
-                if offer is None:
-                    return None, err or f"task {t.name}: no network offer"
-                net_idx.add_reserved(offer)
-                tr.networks.append(offer)
-        tasks[t.name] = tr
-
-    for ask in tg.networks:
-        if net_idx is not None:
             offer, err = net_idx.assign_network(ask)
             if offer is None:
-                return None, err or "group network: no offer"
+                return None, err or f"task {t.name}: no network offer"
             net_idx.add_reserved(offer)
-            shared.networks.append(offer)
-    return AllocatedResources(tasks=tasks, shared=shared), None
+            tr.networks.append(offer)
+
+    for ask in tg.networks:
+        offer, err = net_idx.assign_network(ask)
+        if offer is None:
+            return None, err or "group network: no offer"
+        net_idx.add_reserved(offer)
+        res.shared.networks.append(offer)
+    return res, None

@@ -32,7 +32,8 @@ from ..structs import (
     filter_terminal_allocs,
 )
 from ..tensor.cluster import ClusterTensors
-from .generic import allocated_resources
+from .generic import (allocated_resources, count_offers,
+                      offer_needs_node)
 from .reconcile import ALLOC_LOST, ALLOC_NOT_NEEDED, ALLOC_UPDATING
 from .stack import PlanContext, TPUStack
 from .util import (
@@ -73,13 +74,20 @@ class SystemScheduler:
         self.queued_allocs: Dict[str, int] = {}
         self.nodes = []
         self.nodes_by_dc: Dict[str, int] = {}
+        #: offers of this eval and those that built no per-node index
+        #: (`scheduler/generic.py count_offers`)
+        self._offers = self._offers_skipped = 0
 
     def process(self, eval: Evaluation) -> None:
         self.eval = eval
-        err = retry_max(
-            MAX_SYSTEM_ATTEMPTS, self._process,
-            lambda: progress_made(self.plan_result),
-        )
+        try:
+            err = retry_max(
+                MAX_SYSTEM_ATTEMPTS, self._process,
+                lambda: progress_made(self.plan_result),
+            )
+        finally:
+            count_offers(self.planner, self._offers, self._offers_skipped)
+            self._offers = self._offers_skipped = 0
         if err is not None:
             if isinstance(err, SetStatusError):
                 self._set_status(EVAL_STATUS_FAILED, str(err))
@@ -249,6 +257,7 @@ class SystemScheduler:
             has_dp = bool(dp_active.any())
             budget = int(params.n_place)  # < len(entries) iff constant-
             #                               LTarget dp caps total placements
+            plain = not offer_needs_node(tg)  # no index is built per node
 
             for node_id, prev in entries:
                 row = self.cluster.row_of.get(node_id)
@@ -304,6 +313,9 @@ class SystemScheduler:
                     # preemptions precede the NetworkIndex build.
                     for v in victims:
                         self.plan.append_preempted_alloc(v, alloc_id)
+                self._offers += 1
+                if plain or node is None:
+                    self._offers_skipped += 1
                 alloc_res, net_err = allocated_resources(
                     self.state, self.plan, tg, node
                 )
