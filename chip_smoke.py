@@ -298,8 +298,7 @@ def parity(state, nodes, rng: random.Random) -> dict:
     for job in jobs:
         state.upsert_job(job)
     stack = TPUStack(state.cluster)
-    stats, _evals, _oracle_s = oracle_parity(state, nodes, jobs, stack,
-                                             PARITY_COUNT)
+    stats = oracle_parity(state, nodes, jobs, stack, PARITY_COUNT)
     return {"kinds": list(PARITY_KINDS), **stats,
             **(compiled_parity(stack, jobs, PARITY_COUNT) or {})}
 

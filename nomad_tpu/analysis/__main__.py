@@ -4,7 +4,7 @@ Modes:
   (default)        print every finding + summary; exit 0
   --fail-on-new    compare against the baseline; print only NEW
                    findings; exit 2 if any (cheap enough for
-                   pre-commit / bench.py preflight: pure ast, no jax)
+                   pre-commit: pure ast, no jax)
   --write-baseline regenerate lint_baseline.json from the current tree
   --format json    machine-readable findings (file/line/rule/context/
                    message) for PR annotation; --json is the legacy

@@ -235,7 +235,7 @@ def _mark_traced(tree: ast.Module, fns: Dict[str, _FnInfo]) -> None:
                         target.traced = True
                         changed = True
                     # a static arg forwarded under the same name stays
-                    # static in the callee (place_packed_batch's `spec`
+                    # static in the callee (place_packed_chain's `spec`
                     # → _unpack_params' `spec`)
                     callee_params = {a.arg for a in target.node.args.args}
                     inherit = (info.static_names & callee_params) \

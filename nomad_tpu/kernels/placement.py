@@ -765,17 +765,6 @@ def _unpack_params(i32buf, f32buf, u8buf, spec) -> TGParams:
     return TGParams(**fields)
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "max_allocs"))
-def place_packed_batch(cluster: ClusterArrays, i32buf, f32buf, u8buf,
-                       spec, max_allocs: int) -> Tuple[jax.Array, jax.Array]:
-    """Packed-transport batched placement; returns only (sel_idx, sel_score)
-    so the device→host fetch is one small transfer too."""
-    batch = _unpack_params(i32buf, f32buf, u8buf, spec)
-    fn = functools.partial(place_task_group, max_allocs=max_allocs)
-    r = jax.vmap(fn, in_axes=(None, 0))(cluster, batch)
-    return r.sel_idx, r.sel_score
-
-
 def _chain_with_carry(cluster: ClusterArrays, batch: TGParams,
                       max_allocs: int, explain: bool = False):
     """Chain body shared by the packed and table dispatches: scan over

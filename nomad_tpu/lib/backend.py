@@ -3,7 +3,8 @@ compiled programs are cached.
 
 An accelerator belongs to one process at a time. The process that
 schedules states its platform once, at start (`resolve()` — called by
-`Server.start`, `bench.py`, `chip_smoke.py` and the driver entry), and
+`Server.start`, `chip_smoke.py`, the benchmark's launcher and the driver
+entry), and
 everything else in that process that needs to know about the device
 (`client/fingerprint.py`, `client/devicemanager.py`) asks `resolved()`
 instead of starting a child that would want the same chip.

@@ -33,7 +33,7 @@ chip is not measured; these count and attribute them):
   whichever thread resolves first, so the per-eval trace can no longer
   say whether pipelining overlapped anything. Served on
   `/v1/scheduler/timeline` (index long-poll, the `/v1/event/stream`
-  idiom), `operator timeline`, and bench.py's `e2e_pipeline` JSON tail.
+  idiom) and `operator timeline`.
 """
 from __future__ import annotations
 

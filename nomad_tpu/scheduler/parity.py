@@ -5,30 +5,24 @@ stanza, the compiled core (`native/core.cpp`).
 
 Both references are exact full-scan argmax, like the kernel, so a
 disagreement can only come from fp associativity, from ties — or from a
-kernel that moved a value inexactly. `bench.py` (parity + the oracle's
-rate) and `chip_smoke.py` (parity on the chip) share these loops, so the
-comparison that gates the chip is the one the bench reports.
+kernel that moved a value inexactly. `chip_smoke.py` runs these loops on
+the chip; the benchmark's own reference is `perfbench/reference.py`.
 """
 from __future__ import annotations
 
-import time
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 #: equal-score nodes are interchangeable under the reference's shuffle:
 #: a different node at the same normalized score is agreement
 TIE_EPS = 1e-5
 
 
-def oracle_parity(state, nodes, jobs: Sequence, stack, count: int,
-                  parity: bool = True) -> Tuple[Optional[dict], int, float]:
+def oracle_parity(state, nodes, jobs: Sequence, stack, count: int) -> dict:
     """Run `jobs` through the scalar oracle — full-node-scan Select per
-    alloc, sequential, plan threaded from step to step — and, with
-    `parity`, through the kernel on the identical snapshot, comparing
-    per-step normalized scores and node choices (the north star's
-    ≤1 %-deviation half; reference normalization rank.go:696-710).
-
-    Returns (parity stats or None, evals run, seconds spent in the
-    oracle alone — kernel time excluded)."""
+    alloc, sequential, plan threaded from step to step — and through the
+    kernel on the identical snapshot, comparing per-step normalized
+    scores and node choices (the north star's ≤1 %-deviation half;
+    reference normalization rank.go:696-710)."""
     from ..mock import alloc_resources
     from ..structs import Allocation
     from .oracle import OracleContext, select_option
@@ -39,33 +33,25 @@ def oracle_parity(state, nodes, jobs: Sequence, stack, count: int,
     devs = []
     agree = 0
     steps = 0
-    t0 = time.time()
-    kernel_dt = 0.0
     total = 0
     for job in jobs:
         ctx = OracleContext(nodes=nodes, allocs_by_node=allocs_by_node)
         tg = job.task_groups[0]
         res = job.combined_task_resources(tg)
-        sel = None
-        if parity:
-            tk = time.time()
-            sel = stack.select(job, tg, count)
-            kernel_dt += time.time() - tk
+        sel = stack.select(job, tg, count)
         for step in range(count):
             opt = select_option(ctx, job, tg)
-            if sel is not None:
-                k_node = sel.node_ids[step]
-                k_score = sel.scores[step]
-                steps += 1
-                if opt is None or k_node is None:
-                    # both-failed = agreement; one-sided placement is a
-                    # plain disagreement (the kernel's 0.0 unplaced
-                    # sentinel must not enter the deviation stats)
-                    agree += opt is None and k_node is None
-                else:
-                    dev = abs(k_score - opt.final_score)
-                    devs.append(dev)
-                    agree += k_node == opt.node.id or dev <= TIE_EPS
+            k_node = sel.node_ids[step]
+            steps += 1
+            if opt is None or k_node is None:
+                # both-failed = agreement; one-sided placement is a
+                # plain disagreement (the kernel's 0.0 unplaced
+                # sentinel must not enter the deviation stats)
+                agree += opt is None and k_node is None
+            else:
+                dev = abs(sel.scores[step] - opt.final_score)
+                devs.append(dev)
+                agree += k_node == opt.node.id or dev <= TIE_EPS
             if opt is None:
                 continue
             fake = Allocation(
@@ -91,19 +77,15 @@ def oracle_parity(state, nodes, jobs: Sequence, stack, count: int,
                                       for d in offs)
             ctx.plan_node_alloc.setdefault(opt.node.id, []).append(fake)
         total += 1
-    oracle_s = time.time() - t0 - kernel_dt
-    stats = None
-    if parity and steps:
-        stats = {
-            "score_deviation_pct": round(100.0 * (
-                sum(devs) / len(devs) if devs else 0.0), 4),
-            "score_deviation_max_pct": round(
-                100.0 * (max(devs) if devs else 0.0), 4),
-            "node_agreement_pct": round(100.0 * agree / steps, 2),
-            "parity_evals": total,
-            "parity_placements": steps,
-        }
-    return stats, total, oracle_s
+    return {
+        "score_deviation_pct": round(100.0 * (
+            sum(devs) / len(devs) if devs else 0.0), 4),
+        "score_deviation_max_pct": round(
+            100.0 * (max(devs) if devs else 0.0), 4),
+        "node_agreement_pct": round(100.0 * agree / steps, 2),
+        "parity_evals": total,
+        "parity_placements": steps,
+    }
 
 
 def compiled_parity(stack, jobs: Sequence, count: int) -> Optional[dict]:

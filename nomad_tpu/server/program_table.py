@@ -43,7 +43,6 @@ keeps the replicated packed transport.
 from __future__ import annotations
 
 import hashlib
-import os
 import threading
 import weakref
 from collections import OrderedDict
@@ -66,8 +65,8 @@ DIM_CEILINGS = {"v": 512, "c": 128, "a_n": 128, "s_n": 32, "dp_n": 32,
 #: rows) is the one dyn dim that can approach the node count
 DYN_CEILINGS = {"l_n": 512}
 
-#: table row capacity (LRU-evicted); env-tunable for huge job fleets
-TABLE_ROWS_ENV = "NOMAD_TPU_PROG_TABLE_ROWS"
+#: table row capacity (LRU-evicted)
+TABLE_ROWS = 512
 
 #: fixed insert-chunk width — one XLA compile for the row-insert kernel
 #: regardless of how many cold programs a dispatch carries
@@ -124,10 +123,9 @@ def _get_insert_jit():
 class DeviceProgramTable:
     """Content-addressed device table of packed static program rows."""
 
-    def __init__(self, capacity: Optional[int] = None) -> None:
+    def __init__(self, capacity: int = TABLE_ROWS) -> None:
         self._lock = threading.Lock()
-        self.capacity = capacity or int(
-            os.environ.get(TABLE_ROWS_ENV, "512"))
+        self.capacity = capacity
         #: running shape floors for the static dims; growth bumps `gen`
         #: and flushes the device tables
         self.caps: Dict[str, int] = {}

@@ -278,6 +278,8 @@ class SelectCoordinator:
     #: rides the sequential chain (a 1-lane wave is the chain, minus a
     #: shared compile)
     _MIN_WAVE_LANES = 2
+    #: ceiling on wave width: more disjoint groups than this share lanes
+    _MAX_WAVE_LANES = 8
 
     def __init__(self, window_s: float = 0.004, tracer=None,
                  timeline=None, registry=None) -> None:
@@ -695,11 +697,15 @@ class SelectCoordinator:
 
     def _dispatch_table(self, reqs, cluster, want_ex, led,
                         _kernel_done, spec: bool = False) -> bool:
-        """Dispatch one cluster group through the device program table.
-        Returns False (nothing dispatched, no side effects on reqs) when
-        the group can't ride the table — the caller then runs the legacy
-        transport. Requests spanning ≥2 disjoint broker conflict groups
-        dispatch as a WAVE (parallel lanes) instead of one chain.
+        """Dispatch one cluster group through the device program table:
+        as ONE chain (`place_table_chain`), or — when the requests span
+        ≥2 disjoint broker conflict groups — as a WAVE of parallel lanes
+        (`place_table_wave`: the program axis is [lanes, lane length]
+        and the kernel's carry is the per-row fold of the lane carries;
+        `_table_layout` has the layout, the only thing that differs).
+        Returns False untouched (no span, no stats, no lease, no side
+        effects on reqs) when the group can't ride the table — the
+        caller then runs the legacy transport.
 
         `spec` (ISSUE 15): resolve the view from the speculative chain
         (predicted post-commit state) instead of the committed cache,
@@ -707,28 +713,16 @@ class SelectCoordinator:
         STASH the outputs for commit-time certification instead of
         releasing the waiters — run() certifies once the predecessor's
         plans have all committed."""
-        from ..kernels.placement import place_table_chain
+        from ..kernels.placement import place_table_chain, place_table_wave
         from ..lib.transfer import guard_scope
         from ..scheduler import stack as stack_mod
         from .program_table import table_for
 
         lanes = self._wave_lanes(reqs)
-        if len(lanes) >= self._MIN_WAVE_LANES:
-            return self._dispatch_table_wave(lanes, cluster, want_ex,
-                                             led, _kernel_done,
-                                             spec=spec)
         table = table_for(cluster)
-        params_list = [r.params for r in reqs]
-        # pad the program axis to a power of two with inert programs so
-        # chain compiles are shared across batch sizes; the pad shares
-        # program 0's static table row (identical content) with a
-        # no-effect dynamic row
-        b = _bucket(len(reqs), lo=2)
-        if b > len(reqs):
-            pad = _inert_program(params_list[0])
-            params_list = params_list + [pad] * (b - len(reqs))
         t0 = time.monotonic()
         with host_span("pack"):
+            reqs, params_list, idxs, shape, lanes_idx = _table_layout(lanes)
             prep = table.prepare(params_list)
         if prep is None:
             return False
@@ -739,20 +733,14 @@ class SelectCoordinator:
             com = table.commit(prep, led)
             if com is None:
                 return False  # caps flush raced this prepare — the
-                # legacy fallback re-packs, so no stats/spans were
-                # recorded yet (they would double-count)
+                # legacy fallback re-packs
             ti, tf, tu, ins_nb, ins_count = com
-            self._trace(reqs, "pack", t0, t1)
-            if len(reqs) > 1:
-                self.stats["batched"] += len(reqs)
-            nb = (prep.rows.nbytes + prep.dyn_i.nbytes
-                  + prep.dyn_f.nbytes + prep.dyn_u.nbytes)
+            dyn = (prep.rows, prep.dyn_i, prep.dyn_f, prep.dyn_u)
+            if shape is not None:
+                dyn = tuple(a.reshape(shape + a.shape[1:]) for a in dyn)
+            nb = sum(a.nbytes for a in dyn)
             with led.timed("select_batch.dyn_rows", nb, count=4):
-                drows = jnp.asarray(prep.rows)
-                di = jnp.asarray(prep.dyn_i)
-                df = jnp.asarray(prep.dyn_f)
-                du = jnp.asarray(prep.dyn_u)
-            self.stats["pack_bytes"] += nb + ins_nb
+                drows, di, df, du = (jnp.asarray(a) for a in dyn)
             t2 = time.monotonic()
             # view AFTER pack, at the last possible instant before the
             # kernel (the predecessor batch's plans have committed and,
@@ -772,10 +760,19 @@ class SelectCoordinator:
                             # normally once the predecessor commits
                     else:
                         arrays = reqs[0].arrays_fn(lease_token=token)
+                # the launch is certain from here: only now is the pack
+                # counted, so a miss above leaves nothing for the real
+                # dispatch that follows it to count twice
+                self._trace(reqs, "pack", t0, t1)
+                if len(reqs) > 1:
+                    self.stats["batched"] += len(reqs)
+                self.stats["pack_bytes"] += nb + ins_nb
                 tv = time.monotonic()
                 self._trace(reqs, "delta_apply", t2, tv)
+                place = place_table_chain if shape is None \
+                    else place_table_wave
                 with host_span("launch"):
-                    out, carry = place_table_chain(
+                    out, carry = place(
                         arrays, ti, tf, tu, drows, di, df, du,
                         prep.sspec, prep.dspec, prep.m, explain=want_ex)
                 tl = time.monotonic()
@@ -786,11 +783,17 @@ class SelectCoordinator:
                 raise
         spec_state = None
         if spec:
-            spec_state = {"reqs": reqs, "idxs": None, "cluster": cluster,
-                          "token": token, "lanes":
-                          [list(range(len(reqs)))], "kernel_ms": 0.0}
+            spec_state = {"reqs": reqs, "idxs": idxs, "cluster": cluster,
+                          "token": token, "lanes": lanes_idx,
+                          "kernel_ms": 0.0}
+        if shape is not None and self.registry is not None:
+            self.registry.inc("wave.dispatches")
+            self.registry.inc("wave.programs", len(reqs))
+            self.registry.add_sample("wave.lanes", len(lanes))
+            self.registry.add_sample("wave.lane_len",
+                                     max(len(l) for l in lanes))
         return self._launched(
-            reqs, None, cluster, token, arrays, out, carry, spec_state,
+            reqs, idxs, cluster, token, arrays, out, carry, spec_state,
             _kernel_done,
             dict(programs=len(reqs), batched=len(reqs) > 1,
                  pack=(t0, t1), upload=(t1, t2), view=(t2, tv),
@@ -873,10 +876,10 @@ class SelectCoordinator:
         request has a known group: an order with no group id conflicts
         with everything, so its whole dispatch stays sequential.
 
-        Groups pack into at most NOMAD_TPU_WAVE_LANES lanes (default 8)
-        longest-first onto the least-loaded lane (LPT): the vmapped
-        scan's length is the LONGEST lane, so balancing lanes is what
-        actually shortens the serial chain. Concatenating disjoint
+        Groups pack into at most `_MAX_WAVE_LANES` lanes, longest-first
+        onto the least-loaded lane (LPT): the vmapped scan's length is
+        the LONGEST lane, so balancing lanes is what actually shortens
+        the serial chain. Concatenating disjoint
         groups inside one lane is always safe — a lane is sequential,
         and sequential is correct for any footprint relation."""
         if not self.group_ids:
@@ -889,128 +892,11 @@ class SelectCoordinator:
             groups.setdefault(gid, []).append(r)
         if len(groups) < self._MIN_WAVE_LANES:
             return [reqs]
-        import os
-
-        try:
-            max_lanes = max(int(os.environ.get("NOMAD_TPU_WAVE_LANES",
-                                               "8")), 1)
-        except ValueError:
-            max_lanes = 8
-        n_lanes = min(len(groups), max_lanes)
-        if n_lanes < self._MIN_WAVE_LANES:
-            return [reqs]
+        n_lanes = min(len(groups), self._MAX_WAVE_LANES)
         lanes: List[list] = [[] for _ in range(n_lanes)]
         for g in sorted(groups.values(), key=len, reverse=True):
             min(lanes, key=len).extend(g)
-        return [l for l in lanes if l]
-
-    def _dispatch_table_wave(self, lanes, cluster, want_ex, led,
-                             _kernel_done, spec: bool = False) -> bool:
-        """Dispatch ≥2 disjoint-footprint lanes as ONE fused wave
-        through the device program table (`place_table_wave`). Same
-        transport, lease, carry-note, and guard discipline as the chain
-        path; the program axis is [L, P] (lanes × bucketed lane length,
-        inert-padded) instead of flat, and the kernel's carry is the
-        per-row fold of the lane carries. Returns False untouched on
-        any table-residency miss — the caller then runs the legacy
-        packed transport as one sequential chain. `spec` as in
-        _dispatch_table: predicted view, chain carry, deferred waiters."""
-        from ..kernels.placement import place_table_wave
-        from ..lib.transfer import guard_scope
-        from ..scheduler import stack as stack_mod
-        from .program_table import table_for
-
-        reqs = [r for lane in lanes for r in lane]
-        table = table_for(cluster)
-        t0 = time.monotonic()
-        with host_span("pack"):
-            lane_len = _bucket(max(len(lane) for lane in lanes), lo=2)
-            n_lanes = _bucket(len(lanes), lo=2)
-            pad = _inert_program(lanes[0][0].params)
-            params_list: List = []
-            idxs: List[int] = []
-            for li, lane in enumerate(lanes):
-                for pi, r in enumerate(lane):
-                    idxs.append(li * lane_len + pi)
-                params_list.extend([r.params for r in lane])
-                params_list.extend([pad] * (lane_len - len(lane)))
-            # fully-inert pad lanes (bucketed lane count shares compiles);
-            # they share the template's table row and fold as no-ops
-            params_list.extend([pad] * ((n_lanes - len(lanes)) * lane_len))
-            prep = table.prepare(params_list)
-        if prep is None:
-            return False
-        t1 = time.monotonic()
-        with guard_scope():
-            import jax.numpy as jnp
-
-            com = table.commit(prep, led)
-            if com is None:
-                return False  # caps flush raced this prepare
-            ti, tf, tu, ins_nb, ins_count = com
-            self._trace(reqs, "pack", t0, t1)
-            self.stats["batched"] += len(reqs)
-            rows2 = prep.rows.reshape(n_lanes, lane_len)
-            di3 = prep.dyn_i.reshape(n_lanes, lane_len,
-                                     prep.dyn_i.shape[1])
-            df3 = prep.dyn_f.reshape(n_lanes, lane_len,
-                                     prep.dyn_f.shape[1])
-            du3 = prep.dyn_u.reshape(n_lanes, lane_len,
-                                     prep.dyn_u.shape[1])
-            nb = (rows2.nbytes + di3.nbytes + df3.nbytes + du3.nbytes)
-            with led.timed("select_batch.dyn_rows", nb, count=4):
-                drows = jnp.asarray(rows2)
-                di = jnp.asarray(di3)
-                df = jnp.asarray(df3)
-                du = jnp.asarray(du3)
-            self.stats["pack_bytes"] += nb + ins_nb
-            t2 = time.monotonic()
-            # view AFTER pack + atomic lease, exactly like the chain
-            # path (see _dispatch_table)
-            token = next(_DISPATCH_TOKENS)
-            try:
-                with led.scope() as moved, host_span("view"):
-                    if spec:
-                        arrays = stack_mod.spec_chain_view(cluster, token)
-                        if arrays is None:
-                            return False
-                    else:
-                        arrays = reqs[0].arrays_fn(lease_token=token)
-                tv = time.monotonic()
-                self._trace(reqs, "delta_apply", t2, tv)
-                with host_span("launch"):
-                    out, carry = place_table_wave(
-                        arrays, ti, tf, tu, drows, di, df, du,
-                        prep.sspec, prep.dspec, prep.m, explain=want_ex)
-                tl = time.monotonic()
-            except BaseException:
-                stack_mod.release_view(cluster, token)
-                raise
-        spec_state = None
-        if spec:
-            pos = 0
-            lanes_idx: List[List[int]] = []
-            for lane in lanes:
-                lanes_idx.append(list(range(pos, pos + len(lane))))
-                pos += len(lane)
-            spec_state = {"reqs": reqs, "idxs": idxs, "cluster": cluster,
-                          "token": token, "lanes": lanes_idx,
-                          "kernel_ms": 0.0}
-        if self.registry is not None:
-            self.registry.inc("wave.dispatches")
-            self.registry.inc("wave.programs", len(reqs))
-            self.registry.add_sample("wave.lanes", len(lanes))
-            self.registry.add_sample("wave.lane_len",
-                                     max(len(l) for l in lanes))
-        return self._launched(
-            reqs, idxs, cluster, token, arrays, out, carry, spec_state,
-            _kernel_done,
-            dict(programs=len(reqs), batched=True,
-                 pack=(t0, t1), upload=(t1, t2), view=(t2, tv),
-                 kernel_start=tv, launch_end=tl,
-                 transfer_bytes=nb + ins_nb + moved[0],
-                 transfer_count=4 + ins_count + moved[1],
-                 speculative=spec))
+        return lanes
 
     # ---- speculative launch + commit-time certification (ISSUE 15) ----
 
@@ -1223,6 +1109,36 @@ class SelectCoordinator:
             if ctx is not None and ctx.trace_id not in out:
                 out.append(ctx.trace_id)
         return out
+
+
+def _table_layout(lanes):
+    """Lay a table dispatch's lanes (`_wave_lanes`) out on the program
+    axis. Pure: no device, no lock. Returns (reqs in axis order, the
+    params list padded with inert programs, idxs, shape, lanes_idx):
+    `lanes_idx` holds each lane's positions in `reqs`. One lane is the
+    flat chain: `idxs` and `shape` are None and the axis is bucketed to
+    a power of two so chain compiles are shared across batch sizes. ≥2
+    lanes are a wave: `shape` = (lane count, lane length), both
+    bucketed, `idxs[j]` = `reqs[j]`'s slot lane*length+position, short
+    lanes and the fully-inert pad lanes filled with the pad, which
+    shares the first program's static table row and folds as a no-op."""
+    reqs = [r for lane in lanes for r in lane]
+    lane_len = _bucket(max(len(lane) for lane in lanes), lo=2)
+    n_lanes = _bucket(len(lanes), lo=2) if len(lanes) > 1 else 1
+    pad = _inert_program(reqs[0].params) \
+        if n_lanes * lane_len > len(reqs) else None
+    params_list: List = []
+    idxs: List[int] = []
+    lanes_idx: List[List[int]] = []
+    for li, lane in enumerate(lanes):
+        lanes_idx.append(list(range(len(idxs), len(idxs) + len(lane))))
+        idxs.extend(li * lane_len + pi for pi in range(len(lane)))
+        params_list.extend(r.params for r in lane)
+        params_list.extend([pad] * (lane_len - len(lane)))
+    params_list.extend([pad] * ((n_lanes - len(lanes)) * lane_len))
+    if n_lanes == 1:
+        return reqs, params_list, None, None, lanes_idx
+    return reqs, params_list, idxs, (n_lanes, lane_len), lanes_idx
 
 
 def _inert_program(p):

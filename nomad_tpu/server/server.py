@@ -16,7 +16,6 @@ Endpoint behaviors implemented as methods (HTTP layer calls these):
 """
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -240,13 +239,10 @@ class Server:
         # the broker belongs to the STATE STORE (it must survive the
         # leadership-gated Server rebuild and receive follower-side FSM
         # applies), so reuse an already-attached one and only re-bind
-        # its instruments to this Server's registry. NOMAD_TPU_EVENTS=0
-        # detaches the store hook entirely (the bench A/B arm).
+        # its instruments to this Server's registry.
         broker = getattr(self.state, "event_broker", None)
         if broker is None:
-            broker = ClusterEventBroker()
-            if os.environ.get("NOMAD_TPU_EVENTS", "1") != "0":
-                self.state.event_broker = broker
+            broker = self.state.event_broker = ClusterEventBroker()
         broker.bind_metrics(self.metrics)
         self.events = broker
         self.timetable = TimeTable()

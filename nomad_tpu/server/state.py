@@ -109,7 +109,7 @@ class StateStore(InMemState):
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         #: cluster event stream (server/event_broker.py): attached by
-        #: the owning Server (None ⇒ no events, e.g. NOMAD_TPU_EVENTS=0)
+        #: the owning Server (None ⇒ a bare store: no events)
         self.event_broker = None
         self._emit_local = threading.local()
         #: restores replay history through the normal mutators — they

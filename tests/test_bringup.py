@@ -224,19 +224,6 @@ class TestChipSmoke:
         assert "distinct-cell" in out["parity"]["kinds"]
 
 
-class TestBench:
-    def test_no_chip_no_metric_line(self):
-        """No accelerator and no explicit JAX_PLATFORMS=cpu: bench.py
-        exits non-zero with the reason and prints no metric line."""
-        r = _run(["bench.py"],
-                 env=_env(JAX_PLATFORMS=None, NOMAD_TPU_BENCH_LINT="0"),
-                 timeout=120)
-        assert r.returncode != 0
-        assert r.stdout.decode().strip() == ""
-        assert "no accelerator" in \
-            r.stderr.decode().strip().splitlines()[-1]
-
-
 class TestBuiltFromSource:
     def test_native_library_is_keyed_on_source_content(self):
         import hashlib

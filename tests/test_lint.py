@@ -284,7 +284,7 @@ def test_ratchet_fails_on_new_violation(tmp_path):
                                load_baseline(str(p))) == []
 
 
-# ---- CLI (the pre-commit/bench preflight) ----
+# ---- CLI (the pre-commit gate) ----
 
 def test_cli_fail_on_new_clean_then_dirty(tmp_path, capsys):
     """End-to-end CLI ratchet on a kernels-only copy (rel paths — and
@@ -449,8 +449,8 @@ def test_cli_stats_lists_waiver_ledger(capsys):
 
 def test_analyzer_wall_clock_budget():
     """The whole analyzer (per-file rules + whole-program lock graph)
-    must stay under 10s on the full tree — it gates bench preflight and
-    pre-commit (ISSUE 14 acceptance)."""
+    must stay under 10s on the full tree — it gates pre-commit and runs
+    in every PR's tier-1 suite (ISSUE 14 acceptance)."""
     import time as _time
 
     t0 = _time.monotonic()
@@ -577,7 +577,7 @@ def test_cursor_discipline_holds_on_stack():
 
 def test_analyzer_needs_no_jax_import():
     """Lint time must not pay (or require) a jax import — the CLI is a
-    pre-commit/bench preflight that must run anywhere, fast."""
+    pre-commit gate that must run anywhere, fast."""
     import subprocess
     import sys
     code = (

@@ -126,7 +126,8 @@ class TestNetworkIndexIntegration:
 class TestCompiledSelect:
     """The C++ select loop (nomad_select_eval) must agree with the TPU
     kernel / Python oracle on node choice and normalized score — it is the
-    bench's compiled baseline and must not measure a different algorithm."""
+    compiled reference of `scheduler/parity.py` (the chip smoke's
+    `compiled_parity` check) and must not decide by a different algorithm."""
 
     @pytest.mark.skipif(not native.available(), reason="no native lib")
     def test_agrees_with_kernel(self):

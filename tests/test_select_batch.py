@@ -10,6 +10,7 @@ Covers the three layers of the batched control plane:
 - server: a batched server places identically to a sequential one and
   meets plan-apply with zero partials when capacity suffices.
 """
+import collections
 import os
 import random
 import threading
@@ -358,3 +359,183 @@ class TestServerBatchedPath:
             assert s.workers[0].batch_stats.get("batched", 0) > 0
         finally:
             s.shutdown()
+
+
+# ---- the one table-dispatch body: chain and wave ----
+
+class _P(collections.namedtuple(
+        "_P", "tag n_place delta_idx delta_res pclr_idx pclr_port "
+        "pset_idx pset_port")):
+    """The fields `_inert_program` touches, plus a tag."""
+
+
+class _R:
+    def __init__(self, order):
+        self.order = order
+        self.params = _P(order, np.int32(1), np.array([3]), np.ones((1, 4)),
+                         np.array([2]), np.array([80]), np.array([1]),
+                         np.array([81]))
+
+
+def _lanes_of(sizes):
+    """Lanes as `_wave_lanes` cuts them from conflict groups of `sizes`
+    (one group: the chain's single lane)."""
+    from nomad_tpu.server.select_batch import SelectCoordinator
+
+    coord = SelectCoordinator()
+    reqs, gid_of = [], {}
+    for gid, n in enumerate(sizes):
+        for _ in range(n):
+            gid_of[len(reqs)] = gid
+            reqs.append(_R(len(reqs)))
+    if len(sizes) > 1:
+        coord.group_ids = gid_of
+    return coord._wave_lanes(reqs), reqs, gid_of
+
+
+class TestTableLayout:
+    @pytest.mark.parametrize("sizes,shape,lane_lens", [
+        ((3,), None, [3]),
+        ((3, 1), (2, 4), [3, 1]),
+        # nine groups onto 8 lanes, longest first onto the least loaded:
+        # the ninth joins a one-program lane, none grows past the longest
+        ((3, 2, 2, 1, 1, 1, 1, 1, 1), (8, 4), [3, 2, 2, 2, 1, 1, 1, 1]),
+    ], ids=["one-lane", "two-unequal-lanes", "nine-groups-eight-lanes"])
+    def test_layout(self, sizes, shape, lane_lens):
+        from nomad_tpu.server.select_batch import _table_layout
+
+        lanes, reqs, gid_of = _lanes_of(sizes)
+        assert sorted(map(len, lanes), reverse=True) == lane_lens
+        out, params, idxs, got_shape, lanes_idx = _table_layout(lanes)
+        assert got_shape == shape
+        assert sorted(r.order for r in out) == list(range(len(reqs)))
+        assert [len(l) for l in lanes_idx] == [len(l) for l in lanes]
+        assert [j for l in lanes_idx for j in l] == list(range(len(out)))
+        if shape is None:
+            assert idxs is None and out == reqs
+            slots = list(range(len(out)))
+            assert len(params) == 4          # _bucket(3, lo=2)
+        else:
+            n_lanes, lane_len = shape
+            assert len(params) == n_lanes * lane_len
+            slots = idxs
+            for li, lane in enumerate(lanes_idx):
+                assert [idxs[j] for j in lane] == \
+                    [li * lane_len + p for p in range(len(lane))]
+                # a conflict group never straddles two lanes
+                for j in lane:
+                    assert all(gid_of[out[k].order] != gid_of[out[j].order]
+                               for k in range(len(out)) if k not in lane)
+        for j, r in enumerate(out):
+            assert params[slots[j]] is r.params
+        for s in set(range(len(params))) - set(slots):
+            pad = params[s]
+            assert pad.n_place == 0 and pad.tag == out[0].params.tag
+            assert (pad.delta_idx == -1).all() and not pad.delta_res.any()
+            assert (pad.pclr_idx == -1).all() and (pad.pset_idx == -1).all()
+
+
+class _SpanLog:
+    """The tracer half `_trace` / `_dist_traces` use."""
+
+    def __init__(self):
+        self.phases = []
+
+    def record(self, tid, phase, start=None, end=None):
+        self.phases.append((tid, phase))
+
+    def binding(self, tid):
+        return None
+
+
+def _table_dispatch(wave, monkeypatch=None, miss=None):
+    """Two dc-pinned programs (disjoint footprints) handed straight to
+    `_dispatch_table`, as a chain or — with their conflict groups
+    known — as a wave. Returns (ok, coord, reqs, cluster, registry)."""
+    from nomad_tpu.lib.metrics import MetricsRegistry
+    from nomad_tpu.lib.transfer import DispatchTimeline, default_ledger
+    from nomad_tpu.server.program_table import table_for
+    from nomad_tpu.server.select_batch import SelectCoordinator, _SelectReq
+    from tests.test_spec import _dc_cluster, _dc_job
+
+    cl = _dc_cluster()
+    reqs = []
+    for i, dc in enumerate(("dc1", "dc2")):
+        job = _dc_job(dc)
+        stack = TPUStack(cl)
+        params, _m = stack.compile_tg(job, job.task_groups[0], 1, None)
+        reqs.append(_SelectReq(stack.device_arrays, params, 1, i))
+    reg = MetricsRegistry()
+    coord = SelectCoordinator(tracer=_SpanLog(), registry=reg,
+                              timeline=DispatchTimeline(reg))
+    coord.trace_ids = {0: "e0", 1: "e1"}
+    if wave:
+        coord.group_ids = {0: 0, 1: 1}
+    if miss == "prepare":
+        monkeypatch.setattr(table_for(cl), "prepare", lambda pl: None)
+    elif miss == "commit":
+        monkeypatch.setattr(table_for(cl), "commit", lambda prep, led: None)
+    led = default_ledger()
+    ok = coord._dispatch_table(reqs, cl, False, led,
+                               coord._kernel_done_factory(led),
+                               spec=miss == "spec-view")
+    return ok, coord, reqs, cl, reg
+
+
+def _leases(cl):
+    from nomad_tpu.scheduler import stack as stack_mod
+
+    return set((stack_mod._DEV_CACHE.get(cl) or {}).get("leases", ()))
+
+
+class TestOneTableDispatchBody:
+    @pytest.mark.parametrize("wave", [False, True], ids=["chain", "wave"])
+    def test_dispatch_leaves_the_same_record(self, wave):
+        from nomad_tpu.scheduler import stack as stack_mod
+
+        ok, coord, reqs, cl, reg = _table_dispatch(wave)
+        assert ok is True
+        assert coord.stats["batched"] == 2 and coord.stats["pack_bytes"] > 0
+        assert sorted(coord.tracer.phases) == sorted(
+            (e, p) for e in ("e0", "e1") for p in ("pack", "delta_apply"))
+        holder, _i, token = reqs[0].out
+        assert all(r.event.is_set() and r.out[0] is holder
+                   and r.out[2] == token for r in reqs)
+        # released at launch: the view stays leased, and the carry note
+        # under the dispatch's token is not adoptable yet
+        assert _leases(cl) == {token}
+        note = stack_mod._DEV_CACHE[cl]["carry"]
+        assert note["token"] == token and note["predicted"] is None
+        assert note["evals"] == {"e0", "e1"}
+        sel = holder.resolve()[0]
+        # the first resolve lands the kernel: prediction in, lease out
+        assert _leases(cl) == set()
+        assert {int(sel[r.out[1]][0]) for r in reqs} == \
+            set().union(*note["predicted"].values())
+        assert all(int(sel[r.out[1]][0]) >= 0 for r in reqs)
+        _, (rec,) = coord.timeline.records_after(0)
+        assert (rec["programs"], rec["batched"], rec["speculative"]) == \
+            (2, True, False)
+        assert rec["transfer_count"] >= 4 + len(holder.resolve())
+        for k in ("pack_ms", "upload_ms", "view_ms", "host_ms", "kernel_ms",
+                  "launch_ms", "release_ms", "wake_ms", "fetch_block_ms"):
+            assert rec[k] is not None and rec[k] >= 0.0, k
+        c = reg.counters()
+        assert c.get("pipeline.dispatches") == 1
+        assert c.get("pipeline.programs") == 2
+        assert c.get("wave.dispatches", 0) == (1 if wave else 0)
+        assert c.get("wave.programs", 0) == (2 if wave else 0)
+
+    @pytest.mark.parametrize("miss", ["prepare", "commit", "spec-view"])
+    @pytest.mark.parametrize("wave", [False, True], ids=["chain", "wave"])
+    def test_miss_returns_false_untouched(self, wave, miss, monkeypatch):
+        ok, coord, reqs, cl, reg = _table_dispatch(wave, monkeypatch, miss)
+        assert ok is False
+        assert coord.tracer.phases == []
+        assert coord.stats == {"dispatches": 0, "programs": 0,
+                               "batched": 0, "pack_bytes": 0}
+        assert _leases(cl) == set()
+        assert coord._spec is None
+        assert not any(r.event.is_set() or r.out for r in reqs)
+        assert coord.timeline.records_after(0)[1] == []
+        assert not reg.counters()

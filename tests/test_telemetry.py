@@ -295,63 +295,6 @@ class TestHttpSurfaces:
             assert line.startswith("# ") or len(line.split(" ")) == 2
 
 
-class TestRoofline:
-    class _Dev:
-        def __init__(self, platform, kind):
-            self.platform = platform
-            self.device_kind = kind
-
-    def test_device_peaks_table(self):
-        from nomad_tpu.lib.roofline import device_peaks
-
-        f, bw, kind = device_peaks(self._Dev("tpu", "TPU v5 lite"))
-        assert (f, bw) == (197e12, 819e9) and kind == "TPU v5 lite"
-        f, bw, _ = device_peaks(self._Dev("tpu", "TPU v4"))
-        assert (f, bw) == (275e12, 1228e9)
-        f, bw, _ = device_peaks(self._Dev("cpu", "cpu"))
-        assert f is None and bw is None
-
-    def test_summarize_bound_and_headroom(self):
-        from nomad_tpu.lib.roofline import summarize
-
-        dev = self._Dev("tpu", "TPU v5 lite")
-        # intensity 0.5 FLOP/B << ridge (~240): memory-bound; at exactly
-        # peak BW the headroom is 1.0
-        cost = {"flops": 819e9 * 0.5, "bytes_accessed": 819e9}
-        s = summarize("k", cost, seconds_per_call=1.0, device=dev)
-        assert s["bound"] == "memory"
-        assert s["pct_of_peak_hbm_bw"] == 100.0
-        assert s["headroom_x"] == 1.0
-        # compute-heavy kernel: intensity above the ridge point
-        cost = {"flops": 197e12, "bytes_accessed": 1e6}
-        s = summarize("k", cost, seconds_per_call=2.0, device=dev)
-        assert s["bound"] == "compute"
-        assert s["pct_of_peak_flops"] == 50.0
-        assert s["headroom_x"] == 2.0
-
-    def test_summarize_unknown_device(self):
-        from nomad_tpu.lib.roofline import summarize
-
-        s = summarize("k", {"flops": 10.0, "bytes_accessed": 5.0},
-                      seconds_per_call=0.1, device=self._Dev("cpu", "cpu"))
-        assert s["bound"] == "unknown"
-        assert s["achieved_flops_per_sec"] == 100.0
-        assert s["peak_flops_per_sec"] is None
-
-    def test_kernel_cost_from_compiled_jit(self):
-        """cost_analysis on a real compiled function (CPU backend
-        exposes flops too)."""
-        import jax
-        import jax.numpy as jnp
-
-        from nomad_tpu.lib.roofline import kernel_cost
-
-        f = jax.jit(lambda a, b: a @ b)
-        x = jnp.ones((64, 64), jnp.float32)
-        cost = kernel_cost(f.lower(x, x).compile())
-        assert cost["flops"] > 0
-
-
 class TestStatsdRoundTrip:
     def test_registry_snapshot_reaches_statsd_socket(self):
         """Full push path: registry → snapshot → flatten → UDP statsd

@@ -506,7 +506,7 @@ class TestDistributedPropagation:
         assert "Traceback" not in cap.err
 
     def test_disabled_tracing_stamps_nothing(self, cluster3, monkeypatch):
-        """NOMAD_TPU_TRACE=0 (the bench A/B lever): submits succeed,
+        """NOMAD_TPU_TRACE=0: submits succeed,
         no trace id is returned, no spans are recorded for the job."""
         monkeypatch.setenv("NOMAD_TPU_TRACE", "0")
         agents, apis = cluster3
@@ -527,7 +527,7 @@ class TestTraceSoak:
     """Soak-length stitch gate: sustained traced submits through the
     3-server cluster, every trace read back complete. The fast suite
     proves one tree; this proves the stitch RATE holds under a steady
-    stream (the bench `e2e_trace` acceptance read, >= 0.99)."""
+    stream (acceptance read: >= 0.99)."""
 
     def test_sustained_submits_stitch_rate(self, cluster3):
         agents, apis = cluster3
