@@ -192,6 +192,7 @@ class Agent:
         self.client = None
         self.cluster = None
         self._gc_watch = None
+        self._gc_policy = None
         self._started_at = time.time()
         # agent log ring for /v1/agent/monitor (hclog → monitor stream):
         # one process-wide handler fans out to the live agents' rings
@@ -269,11 +270,15 @@ class Agent:
         # scheduler could find it taken.
         if self.server is not None:
             self.server.start()
-            # collector pauses of the scheduling process: runtime.gc_*
-            from ..lib.backend import GcWatch
+            # the scheduling process's collector: its settings, with
+            # the full sweeps on the server's GC ticker, and its pauses
+            # (runtime.gc_*)
+            from ..lib.backend import GcPolicy, GcWatch
 
             self._gc_watch = GcWatch()
             self._gc_watch.install()
+            self._gc_policy = self.server.gc_policy = GcPolicy()
+            self._gc_policy.install()
         if self.client is not None:
             # advertise this agent's HTTP endpoint on the node BEFORE
             # registration — remote ephemeral-disk migration dials the
@@ -310,6 +315,8 @@ class Agent:
             self.client.shutdown()
         if self.server is not None:
             self.server.shutdown()
+        if self._gc_policy is not None:
+            self._gc_policy.remove()
         if self._gc_watch is not None:
             self._gc_watch.remove()
 

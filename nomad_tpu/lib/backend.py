@@ -20,6 +20,7 @@ from __future__ import annotations
 import gc
 import logging
 import os
+import threading
 import time
 from typing import List, NamedTuple, Optional, Tuple
 
@@ -158,7 +159,9 @@ class GcWatch:
     """`gc.callbacks` hook of a serving process: every collection's
     pause into the histogram `runtime.gc_pause_ms`, full (generation 2)
     collections into the counter `runtime.gc_full`, and a `nomad/gc`
-    span into a running profile.
+    span into a running profile. It only watches the collector; what
+    changes it is its counterpart `GcPolicy`, whose sweeps pass through
+    this hook like any collection.
 
     A collection can start on a thread that holds an instrument's lock
     (a reader sorting the histogram's window allocates), so the hook
@@ -202,3 +205,106 @@ class GcWatch:
             self._pending.pop()
         if self._pending_full and self._full.try_inc(self._pending_full):
             self._pending_full = 0
+
+
+#: The young generation of a process that serves as a Nomad server holds
+#: the scheduler's unit of work: a 1,000-allocation plan is 20-40 thousand
+#: tracked objects that survive every collection started while it is built,
+#: and CPython's 700 starts ~35 of them per thousand allocations committed.
+#: Chosen on the chip among (20,000, 10), (50,000, 4), (50,000, 10) and
+#: (100,000, 2), side by side (PERF.md §6, PR 31): `c1m-5k.flood` spent
+#: 14.2 / 13.4 / 13.5 / 11.8 % of its window in the collector (30.5 % under
+#: CPython's defaults) at 4,845 / 4,772 / 4,763 / 4,800 allocs/s (3,897),
+#: `c1m-5k.singles` 1.8 / 1.2 / - / 1.1 % (8.7 %) at 215 / 215 / - / 218
+#: evals/s (201): the rates cannot tell the pairs apart, the collector's
+#: share falls with the first threshold to the end of the range. What a
+#: young pass walks is everything allocated since the last one that is
+#: still alive — the count that starts it is net of what was freed — so a
+#: larger generation lets more of a plan's scratch die unwalked.
+GC_THRESHOLD0 = 100_000
+#: young collections per generation-1 collection: a generation-1 pass walks
+#: two or three young generations, 85 ms on average in `c1m-5k.flood`
+GC_THRESHOLD1 = 2
+#: generation 2's threshold, out of reach of its counter: what survives
+#: generation 1 — the store's records, the tensor mirror's Python side,
+#: compiled-program caches — is never walked on an allocation count
+_GC_NEVER = 2 ** 31 - 1
+#: A full sweep every so many periods of the server's GC ticker
+#: (`ServerConfig.gc_interval`, 60 s: a sweep every 600 s). One sweep over
+#: `c1m-5k.flood`'s store takes 2.4-2.5 s at the end of a run (7.2-7.4
+#: million tracked objects) and 3.6 s at the end of a traced one (10.7
+#: million): 0.4 % and 0.6 % of the period, where upstream Nomad's 300 s
+#: (`EvalGCInterval` and its like) would make it 0.8 % and 1.2 %. The
+#: sweeps found nothing in any run (PERF.md §5), so waiting longer for one
+#: keeps no memory.
+GC_SWEEP_TICKS = 10
+
+
+class GcPolicy:
+    """The collector's settings in a process that serves as a Nomad
+    server (`Agent.start` installs one, `Agent.shutdown` removes it;
+    `GcWatch` beside it watches what the collector then does).
+
+    No automatic generation-2 collection: the long-lived store holds no
+    cyclic garbage by construction (`Allocation` -> `Job`, never back), so
+    walking it whenever enough objects were allocated finds nothing and
+    stops every thread for its length. A young generation sized for the
+    scheduler's unit of work (`GC_THRESHOLD0`). Full sweeps on the
+    clock instead: `Server._run_gc_ticker` calls `tick()` when it has
+    enqueued the core evals that delete evals, jobs and nodes — the moment
+    garbage that only a full collection finds can appear — and every
+    `GC_SWEEP_TICKS`-th tick sweeps: counter `runtime.gc_sweeps`,
+    histogram `runtime.gc_sweep_ms`, and what the sweeps found in the
+    counter `runtime.gc_sweep_collected`, which says whether anything
+    cyclic reaches generation 2 at all.
+
+    The thresholds belong to the process, so installs are counted: the
+    first saves what stood before, the last removal puts it back."""
+
+    _lock = threading.Lock()
+    _installed = 0
+    _before: Optional[Tuple[int, int, int]] = None
+
+    def __init__(self, registry=None) -> None:
+        from .metrics import default_registry
+
+        reg = registry or default_registry()
+        self._sweeps = reg.counter("runtime.gc_sweeps")
+        self._sweep_ms = reg.histogram("runtime.gc_sweep_ms")
+        self._collected = reg.counter("runtime.gc_sweep_collected")
+        self._holds = False
+        self._ticks = 0
+
+    def install(self) -> None:
+        with GcPolicy._lock:
+            if self._holds:
+                return
+            self._holds = True
+            if GcPolicy._installed == 0:
+                GcPolicy._before = gc.get_threshold()
+                gc.set_threshold(GC_THRESHOLD0, GC_THRESHOLD1, _GC_NEVER)
+            GcPolicy._installed += 1
+
+    def remove(self) -> None:
+        with GcPolicy._lock:
+            if not self._holds:
+                return
+            self._holds = False
+            GcPolicy._installed -= 1
+            if GcPolicy._installed == 0:
+                gc.set_threshold(*GcPolicy._before)
+                GcPolicy._before = None
+
+    def tick(self) -> None:
+        """One period of the server's GC ticker has passed."""
+        self._ticks += 1
+        if self._ticks % GC_SWEEP_TICKS == 0:
+            self.sweep()
+
+    def sweep(self) -> None:
+        """One full collection, on the caller's thread."""
+        t0 = time.perf_counter()
+        found = gc.collect()
+        self._sweep_ms.add((time.perf_counter() - t0) * 1e3)
+        self._sweeps.inc()
+        self._collected.inc(found)

@@ -247,6 +247,10 @@ class Server:
         self.events = broker
         self.timetable = TimeTable()
         self._gc_thread: Optional[threading.Thread] = None
+        #: the collector's settings of a serving process (lib/backend.py
+        #: `GcPolicy`), set by the agent that installs them: its full
+        #: sweeps ride the GC ticker. None for a bare server.
+        self.gc_policy = None
         self._stop_event = threading.Event()
         self._running = False
         # (ns, job_id) → group → bounded scale-event history
@@ -487,6 +491,8 @@ class Server:
             for kind in (CORE_JOB_EVAL_GC, CORE_JOB_JOB_GC, CORE_JOB_NODE_GC,
                          CORE_JOB_DEPLOYMENT_GC):
                 self.enqueue_core_eval(kind)
+            if self.gc_policy is not None:
+                self.gc_policy.tick()
 
     def enqueue_core_eval(self, kind: str) -> Evaluation:
         """Create a `_core` eval routed to CoreScheduler (leader.go
