@@ -132,8 +132,15 @@ PROM_REQUIRED = frozenset({
     # the bench e2e_drain tail aggregates from these
     "nomad_drain_drains", "nomad_drain_batch_width",
     "nomad_drain_groups", "nomad_drain_hold_ms", "nomad_drain_window_ms",
+    # the footprint partition's cost (ISSUE 32): one sample a drain for
+    # `_group_picks`, estimator included; one a batch for the worker's
+    # re-estimate of the footprints that certify a speculative launch
+    "nomad_drain_partition_ms", "nomad_sched_footprint_ms",
     # wave dispatch (ISSUE 12): lane structure of fused mega-batches
     "nomad_wave_dispatches", "nomad_wave_programs", "nomad_wave_lanes",
+    # slots of the bucketed [lanes, lane length] axis (ISSUE 32):
+    # programs / slots is the share that is not inert pads
+    "nomad_wave_lane_len", "nomad_wave_slots",
     # speculative wave dispatch (ISSUE 15): launch/certify/rollback
     # outcomes, exact re-dispatch counts, wasted device time — the
     # the bench e2e_spec tail and the adaptive gate read these

@@ -398,7 +398,11 @@ class EvalBroker:
         # order within a priority — the aging slot was delivered first
         # among its peers, i.e. in seq order)
         picks.sort(key=lambda it: -it[0].priority)
-        groups = self._group_picks(picks)
+        t_part = time.monotonic()
+        with host_span("partition"):
+            groups = self._group_picks(picks)
+        self.metrics.add_sample("drain.partition_ms",
+                                (time.monotonic() - t_part) * 1e3)
         self.metrics.inc("drain.drains")
         self.metrics.add_sample("drain.batch_width", len(picks))
         self.metrics.add_sample("drain.groups", len(groups))

@@ -789,6 +789,8 @@ class SelectCoordinator:
         if shape is not None and self.registry is not None:
             self.registry.inc("wave.dispatches")
             self.registry.inc("wave.programs", len(reqs))
+            # the bucketed [lanes, lane length] axis: programs + inert pads
+            self.registry.inc("wave.slots", shape[0] * shape[1])
             self.registry.add_sample("wave.lanes", len(lanes))
             self.registry.add_sample("wave.lane_len",
                                      max(len(l) for l in lanes))

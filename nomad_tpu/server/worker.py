@@ -415,15 +415,19 @@ class Worker:
                  if spec_enabled() and get_active_mesh() is None
                  else None)
         futs = []
+        fp_s = 0.0  # the estimates' own time, summed over the batch
         for order, (ev, tok) in enumerate(items):
             coord.trace_ids[order] = ev.id
             if group_of is not None:
                 coord.group_ids[order] = group_of[order]
             if fp_fn is not None:
+                t0 = time.monotonic()
                 try:
-                    coord.footprints[order] = fp_fn(ev)
+                    with host_span("footprint"):
+                        coord.footprints[order] = fp_fn(ev)
                 except Exception:  # noqa: BLE001 — estimate only
                     coord.footprints[order] = None
+                fp_s += time.monotonic() - t0
             coord.add_thread()
             try:
                 futs.append(pool.submit(
@@ -437,6 +441,8 @@ class Worker:
                     self.server.broker.nack(ev.id, tok)
                 except ValueError:
                     pass
+        if fp_fn is not None:
+            self.metrics.add_sample("sched.footprint_ms", fp_s * 1e3)
         return coord, futs, items
 
     def finish_batch(self, coord, futs, items) -> None:

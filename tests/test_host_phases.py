@@ -32,9 +32,9 @@ from nomad_tpu.lib.transfer import DispatchTimeline
 EVAL_PARTS = ("prepare", "park", "result_wait", "plan_build", "plan_apply")
 READ_PARTS = ("launch_ms", "release_ms", "spec_hold_ms", "wake_ms",
               "fetch_block_ms")
-HOST_SPANS = ("drain_hold", "snapshot", "prepare", "park", "pack", "view",
-              "launch", "release", "certify", "result_wait", "plan_build",
-              "plan_apply", "gc")
+HOST_SPANS = ("drain_hold", "partition", "snapshot", "footprint", "prepare",
+              "park", "pack", "view", "launch", "release", "certify",
+              "result_wait", "plan_build", "plan_apply", "gc")
 N_JOBS, BATCH = 16, 8
 
 

@@ -297,6 +297,30 @@ class TestSeriesNameStability:
         assert snap["counters"].get(
             "connect.issue_denied_no_alloc", 0) >= 1
 
+    def test_partition_and_wave_slots_are_counted_once(self, loaded_agent):
+        """ISSUE 32's instruments against the ones that stood: a drain
+        leaves exactly one `drain.partition_ms` sample (a drain of one
+        eval too), a batch one `sched.footprint_ms` sample, and a wave's
+        slots (the bucketed [lanes, lane length] axis) hold at least
+        its programs."""
+        a, _api = loaded_agent
+
+        def counted_once():
+            # the agent is live and a snapshot is not one instant: a
+            # drain or a batch under way reads one apart, and is over
+            snap = a.server.metrics.snapshot()
+            h, c = snap["histograms"], snap["counters"]
+            return (h["drain.partition_ms"]["count"] == c["drain.drains"]
+                    == h["drain.groups"]["count"] >= 3
+                    and h["sched.footprint_ms"]["count"]
+                    == c["worker.0.batch.batches"] >= 3)
+
+        assert _wait(counted_once)
+        c = a.server.metrics.snapshot()["counters"]
+        assert c["wave.slots"] >= c["wave.programs"] >= 2
+        # lanes and lane length are bucketed to powers of two, from 2 up
+        assert c["wave.slots"] % 4 == 0
+
     def test_trace_and_slo_series_are_live(self, loaded_agent):
         """The ninth-layer families (ISSUE 17) must be fed by real
         flows, not just pre-created at tracker init: every HTTP submit
