@@ -136,6 +136,9 @@ PROM_REQUIRED = frozenset({
     # `_group_picks`, estimator included; one a batch for the worker's
     # re-estimate of the footprints that certify a speculative launch
     "nomad_drain_partition_ms", "nomad_sched_footprint_ms",
+    # lookups of an eval's static footprint mask and those the cache
+    # answered (ISSUE 33): added once a drain from plain integers
+    "nomad_drain_footprint_estimates", "nomad_drain_footprint_hits",
     # wave dispatch (ISSUE 12): lane structure of fused mega-batches
     "nomad_wave_dispatches", "nomad_wave_programs", "nomad_wave_lanes",
     # slots of the bucketed [lanes, lane length] axis (ISSUE 32):

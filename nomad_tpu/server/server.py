@@ -124,6 +124,59 @@ def _constraint_mask(cl, attrs, constraints, n):
     return mask
 
 
+#: `masks.get` default: None is a verdict ("nothing bounds it")
+_MISS = object()
+
+
+def _constraint_sig(constraints) -> tuple:
+    return tuple((c.operand, c.ltarget, c.rtarget) for c in constraints)
+
+
+def _footprint_key(job) -> tuple:
+    """Everything `_static_footprint` reads of a job, hashable: its
+    datacenters, its constraints and, per task group, the group's and
+    its tasks' constraints."""
+    return (tuple(job.datacenters), _constraint_sig(job.constraints),
+            tuple(_constraint_sig(tg.constraints)
+                  + tuple(s for t in tg.tasks
+                          for s in _constraint_sig(t.constraints))
+                  for tg in job.task_groups))
+
+
+def _static_footprint(cl, attrs, job, n):
+    """The part of an eval's footprint that is a function of (the job's
+    datacenters, its constraints, the node table) alone: datacenter
+    pre-filter ∩ job constraints ∩ (∪ over task groups of the group's
+    and its tasks' constraints). None = no datacenter list and nothing
+    narrowed. `Server._eval_footprint` keeps it per `_footprint_key` in
+    `ClusterTensors.static_masks`."""
+    import numpy as np
+
+    if job.datacenters:
+        # the caller saw the key tokenized and inside `attrs`
+        k_dc = cl.vocab.lookup_key("node.datacenter")
+        kv = cl.vocab.key_vocabs[k_dc]
+        toks = [t for t in (kv.lookup(dc) for dc in job.datacenters)
+                if t >= 0]
+        mask = (np.isin(attrs[:, k_dc], toks) if toks
+                else np.zeros(n, dtype=bool))
+    else:
+        mask = np.ones(n, dtype=bool)
+    mask &= _constraint_mask(cl, attrs, job.constraints, n)
+    tg_union = None
+    for tg in job.task_groups:
+        cons = list(tg.constraints)
+        for t in tg.tasks:
+            cons.extend(t.constraints)
+        m = _constraint_mask(cl, attrs, cons, n)
+        tg_union = m if tg_union is None else (tg_union | m)
+    if tg_union is not None:
+        mask &= tg_union
+    if not job.datacenters and bool(mask.all()):
+        return None
+    return mask
+
+
 class Server:
     def __init__(self, config: Optional[ServerConfig] = None,
                  state: Optional[StateStore] = None) -> None:
@@ -218,6 +271,14 @@ class Server:
         #: clients were only a log line before — eagerly created so the
         #: series is always exposed
         self._ctr_hb_expired = self.metrics.counter("heartbeat.expired")
+        #: lookups of an eval's static footprint mask and those the
+        #: cache answered (`_eval_footprint`): plain integers, because
+        #: the estimator runs 64 times a drain between two batches; the
+        #: worker adds them to the registry once a drain
+        #: (`count_footprints`)
+        self._fp_estimates = self._fp_hits = 0
+        self.metrics.counter("drain.footprint_estimates")
+        self.metrics.counter("drain.footprint_hits")
         self.workers: List[Worker] = [
             Worker(self, i) for i in range(self.config.num_schedulers)
         ]
@@ -343,6 +404,21 @@ class Server:
             migrations and their resource/port deltas land there;
           - ∪ the eval's own node row (node-update/drain triggers).
 
+        The first two are a pure function of (the job's datacenters,
+        its constraints, the node table): `_static_footprint`, kept per
+        `_footprint_key` in `ClusterTensors.static_masks` for as long as
+        the node table stands — a drain of 32 evals asks 64 times (the
+        broker's partition, then `Worker.start_batch` for speculation's
+        certification) for a handful of distinct masks. A hit with no
+        current alloc and no node row of its own (every new job) returns
+        the cached array ITSELF, read-only: consumers copy before they
+        merge (`EvalBroker._group_picks`) or only read
+        (`SelectCoordinator._fp_hit`), and a slip raises. The last two
+        move with every plan and are set in a copy. No lock on any path
+        (`static_masks` says why that is enough); a cluster whose nodes
+        churn misses after every node write and pays what this cost
+        before the cache plus one dict store.
+
         Reads of the live cluster tensors are lock-free and racy by
         design: a node added between estimate and dispatch can make two
         "disjoint" evals collide — the wave kernel counts cross-lane
@@ -354,7 +430,9 @@ class Server:
         if not ev.job_id:
             return None
         cl = self.state.cluster
-        attrs = cl.attrs  # one reference; concurrent growth swaps arrays
+        # one reference (concurrent growth swaps arrays), and the masks
+        # already computed from it
+        attrs, masks = cl.static_masks()
         n = attrs.shape[0]
         job = self.state.job_by_id(ev.namespace, ev.job_id)
         if job is not None:
@@ -362,26 +440,15 @@ class Server:
                 k_dc = cl.vocab.lookup_key("node.datacenter")
                 if k_dc < 0 or k_dc >= attrs.shape[1]:
                     return None
-                kv = cl.vocab.key_vocabs[k_dc]
-                toks = [t for t in (kv.lookup(dc)
-                                    for dc in job.datacenters)
-                        if t >= 0]
-                col = attrs[:, k_dc]
-                mask = (np.isin(col, toks) if toks
-                        else np.zeros(n, dtype=bool))
+            key = _footprint_key(job)
+            mask = masks.get(key, _MISS)
+            self._fp_estimates += 1
+            if mask is _MISS:
+                mask = cl.masks_put(
+                    masks, key, _static_footprint(cl, attrs, job, n))
             else:
-                mask = np.ones(n, dtype=bool)
-            mask &= _constraint_mask(cl, attrs, job.constraints, n)
-            tg_union = None
-            for tg in job.task_groups:
-                cons = list(tg.constraints)
-                for t in tg.tasks:
-                    cons.extend(t.constraints)
-                m = _constraint_mask(cl, attrs, cons, n)
-                tg_union = m if tg_union is None else (tg_union | m)
-            if tg_union is not None:
-                mask &= tg_union
-            if not job.datacenters and bool(mask.all()):
+                self._fp_hits += 1
+            if mask is None:
                 # no datacenter list and nothing narrowed = every node
                 # is a candidate; nothing cheap bounds the read set
                 return None
@@ -389,14 +456,33 @@ class Server:
             # job gone (deregister/stop evals): only the current alloc
             # rows can be touched
             mask = np.zeros(n, dtype=bool)
-        for row, _tg in cl.job_allocs.get(ev.job_id, {}).values():
-            if 0 <= row < n:
-                mask[row] = True
+        # the part that moves with every plan: never cached, and set in
+        # a copy — `mask` may be the cached array itself (read-only),
+        # which is what a new job's eval gets back
+        rows = [row for row, _tg in
+                cl.job_allocs.get(ev.job_id, {}).values() if 0 <= row < n]
         if ev.node_id:
             row = cl.row_of.get(ev.node_id)
             if row is not None and row < n:
-                mask[row] = True
+                rows.append(row)
+        if rows:
+            if not mask.flags.writeable:
+                mask = mask.copy()
+            mask[rows] = True
         return mask
+
+    def count_footprints(self) -> None:
+        """The static-mask lookups since the last call into
+        `drain.footprint_estimates` / `drain.footprint_hits`. One worker
+        thread estimates and calls this today; with more, an increment
+        that lands between the read and the reset is lost — to a share
+        nobody schedules by."""
+        est, hits = self._fp_estimates, self._fp_hits
+        self._fp_estimates = self._fp_hits = 0
+        if est:
+            self.metrics.inc("drain.footprint_estimates", est)
+            if hits:
+                self.metrics.inc("drain.footprint_hits", hits)
 
     def _restore_evals(self) -> None:
         """Re-enqueue non-terminal evals from state into the broker/blocked

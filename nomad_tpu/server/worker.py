@@ -226,6 +226,9 @@ class Worker:
                         and batch[0][0].type in BATCHABLE_TYPES:
                     started = self.start_batch(batch, group_of=group_of)
                     batch = None
+                    # the drain's static-mask lookups, the partition's
+                    # and the batch's: once a drain, not 64 times
+                    self.server.count_footprints()
                 if inflight is not None:
                     if started is not None:
                         # speculative dispatch (ISSUE 15): when batch
@@ -403,8 +406,11 @@ class Worker:
         # per-program footprint masks for speculative certification
         # (select_batch._certify_spec): the same estimator the broker
         # partitions with, re-read at batch start so the mask reflects
-        # this batch's state. None (no estimator / nothing cheap bounds
-        # the eval) conflicts with every stale row — sound, never fast.
+        # this batch's state (the job's current allocation rows; the
+        # part the node table decides is a dict hit, ISSUE 33, so the
+        # second call costs microseconds and stays where it is). None
+        # (no estimator / nothing cheap bounds the eval) conflicts with
+        # every stale row — sound, never fast.
         # Skipped entirely when speculation can never run (hard opt-out
         # or an active mesh): masks nobody reads are pure batch-start
         # latency.

@@ -154,6 +154,9 @@ class ClusterTensors:
         # bumped only on node-set/attribute changes (not alloc churn) —
         # freshness oracle for cached host-evaluated constraint masks
         self.node_version = 0
+        #: (node_version, attrs, key → mask) of `static_masks`
+        self._static_masks: Tuple[int, np.ndarray, Dict] = (
+            0, self.attrs, {})
         # ---- per-version delta logs (device-view incremental refresh) --
         # Each mutation that touches a hot tensor row (used/node_ok/
         # dyn_free) or a port-bitmap row appends (version-after-bump,
@@ -345,6 +348,57 @@ class ClusterTensors:
             "version": self.version,
             "ports_version": self.ports_version,
         }
+
+    # ---- masks that are a function of the node table alone ----
+
+    #: masks `static_masks` holds at most: bool[n_cap] each (16 KiB at
+    #: 16,384 rows); a deployment's jobs name a handful of
+    #: (datacenters, constraints) shapes, and one past the bound empties
+    #: the dict rather than choosing a victim
+    STATIC_MASKS_MAX = 32
+
+    def static_masks(self) -> Tuple[np.ndarray, Dict]:
+        """`(attrs, masks)`: the attribute table as it stands and the
+        dict of host-evaluated row masks computed FROM IT, by whatever
+        key their maker chose (`Server._eval_footprint`: a job's
+        datacenters + constraint signature). The dict lives exactly as
+        long as its inputs: one `node_version` (`upsert_node` /
+        `remove_node`, the only writers of `attrs`) and one identity of
+        the `attrs` array (a row- or key-bucket growth swaps it). A
+        change of either hands out a new, empty dict, so a deployment
+        whose nodes churn misses after every node write and pays the
+        fresh computation plus one dict store.
+
+        No lock, on purpose (PR 26: on the scheduling threads a lock
+        costs a hundred times its CPU time). `node_version` is read
+        BEFORE `attrs`, and the maker computes from the `attrs` it was
+        handed: a node write that lands meanwhile bumps the version
+        after its last attribute write, so a mask computed from a
+        half-written row is filed under the version that write retires.
+        Two threads that both find the entry stale each install an empty
+        dict and the last one stands; two that miss on one key store
+        equal masks, last writer wins. The values are immutable
+        (read-only arrays, or None), so a reader never sees one change.
+        `masks_put` keeps the bound."""
+        v = self.node_version
+        attrs = self.attrs
+        ent = self._static_masks
+        if ent[0] != v or ent[1] is not attrs:
+            ent = self._static_masks = (v, attrs, {})
+        return attrs, ent[2]
+
+    @classmethod
+    def masks_put(cls, masks: Dict, key, mask: Optional[np.ndarray]
+                  ) -> Optional[np.ndarray]:
+        """File `mask` (made read-only here, so a consumer's slip into
+        an in-place write raises instead of widening or narrowing every
+        later hit) under `key` in a dict `static_masks` handed out."""
+        if mask is not None:
+            mask.setflags(write=False)
+        if len(masks) >= cls.STATIC_MASKS_MAX:
+            masks.clear()
+        masks[key] = mask
+        return mask
 
     # ---- nodes ----
 

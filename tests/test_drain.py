@@ -123,6 +123,54 @@ class TestDequeueBatchPartition:
         assert len(groups) == 1 and len(groups[0]) == 2
 
 
+#: name -> (kind of each job in the order delivered, the job (if any)
+#: that already runs an allocation in dc2, the conflict groups by index)
+CACHED_FEEDS = {
+    "a-third-each": (["pinned-dc1", "pinned-dc2", "pinned-dc3"] * 4, None,
+                     [[0, 3, 6, 9], [1, 4, 7, 10], [2, 5, 8, 11]]),
+    "one-job-spans": (["pinned-dc1", "pinned-dc3", "pinned-dc2", "affinity",
+                       "pinned-dc1"], None, [[0, 1, 2, 3, 4]]),
+    "an-allocation-bridges-two": (
+        ["pinned-dc3", "pinned-dc1", "pinned-dc2", "pinned-dc1"], 3,
+        [[0], [1, 2, 3]]),
+}
+
+
+@pytest.mark.parametrize("feed", sorted(CACHED_FEEDS))
+def test_partition_is_the_same_with_cached_footprints(feed):
+    """ISSUE 33: `Server._eval_footprint` answers the static part of a
+    footprint from a cache. The same drain partitioned with the cache
+    warm and with it emptied before every estimate: the same groups in
+    the same order."""
+    import tests.test_footprint_cache as tfc
+
+    kinds, bridge, want = CACHED_FEEDS[feed]
+    s, nodes = tfc._server(n_nodes=60)
+    jobs = [tfc._job(kind, k=i) for i, kind in enumerate(kinds)]
+    picks = [(tfc._eval_of(s, j), "") for j in jobs]
+    if bridge is not None:
+        s.state.upsert_alloc(tfc.synth_alloc(
+            random.Random(feed),
+            next(n for n in nodes if n.datacenter == "dc2"), jobs[bridge]))
+    estimate = s.broker.footprint_fn
+
+    def fresh_every_time(ev):
+        tfc._empty(s)
+        return estimate(ev)
+
+    def partition():
+        return [[picks.index(p) for p in g]
+                for g in s.broker._group_picks(picks)]
+
+    s.broker.footprint_fn = fresh_every_time
+    cold = partition()
+    s.broker.footprint_fn = estimate
+    hits0 = s._fp_hits
+    first, warm = partition(), partition()
+    assert cold == first == warm == want
+    assert s._fp_hits - hits0 >= 2 * len(picks) - len(set(kinds))
+
+
 class TestDequeueBatchFairness:
     def test_failed_queue_head_rides_every_batch(self):
         """Under a continuous healthy feed, a delivery-limited eval

@@ -321,6 +321,20 @@ class TestSeriesNameStability:
         # lanes and lane length are bucketed to powers of two, from 2 up
         assert c["wave.slots"] % 4 == 0
 
+    def test_footprint_lookups_are_counted_and_hit(self, loaded_agent):
+        """ISSUE 33: a drain's lookups of the static footprint masks
+        reach the registry (once a drain, from plain integers), and the
+        fixture's jobs, which share their datacenters and constraints
+        by the handful, are answered from the cache."""
+        a, _api = loaded_agent
+
+        def counted():
+            c = a.server.metrics.snapshot()["counters"]
+            return (c["drain.footprint_estimates"]
+                    >= c["drain.footprint_hits"] >= 2)
+
+        assert _wait(counted)
+
     def test_trace_and_slo_series_are_live(self, loaded_agent):
         """The ninth-layer families (ISSUE 17) must be fed by real
         flows, not just pre-created at tracker init: every HTTP submit

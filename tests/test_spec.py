@@ -364,6 +364,53 @@ class TestCertifyMapping:
             monkeypatch, frozenset({7}), [[0], [1]], fps, n=2)
         assert rolled == [1] and certified == [0]
 
+    @pytest.mark.parametrize("stale_at,want", [
+        ("dc1", [0, 1]), ("dc3", [3]), ("allocation-in-dc2", [1, 2]),
+        ("past-every-mask", [0, 1, 2, 3]), ("nothing", [])])
+    def test_cached_footprints_certify_as_fresh_ones(self, monkeypatch,
+                                                     stale_at, want):
+        """ISSUE 33: the masks `Worker.start_batch` hands the
+        coordinator come from `Server._eval_footprint`, whose static
+        part is cached. Cache warm or emptied before every estimate:
+        the same programs keep their speculative result. Job 1 already
+        runs an allocation in dc2, so its mask is a copy with that row
+        set; the others are the cached arrays themselves."""
+        import tests.test_footprint_cache as tfc
+
+        s, nodes = tfc._server(n_nodes=60)
+        cl = s.state.cluster
+        jobs = [tfc._job(kind, k=i) for i, kind in enumerate(
+            ("pinned-dc1", "pinned-dc1", "pinned-dc2", "pinned-dc3"))]
+        evs = [tfc._eval_of(s, j) for j in jobs]
+        bridge = next(n for n in nodes if n.datacenter == "dc2")
+        s.state.upsert_alloc(tfc.synth_alloc(random.Random(4), bridge,
+                                             jobs[1]))
+        row_in = {dc: cl.row_of[next(n.id for n in nodes
+                                     if n.datacenter == dc)]
+                  for dc in ("dc1", "dc3")}
+        stale = {"dc1": {row_in["dc1"]}, "dc3": {row_in["dc3"]},
+                 "allocation-in-dc2": {cl.row_of[bridge.id]},
+                 "past-every-mask": {cl.n_cap + 3},
+                 "nothing": set()}[stale_at]
+
+        def estimates(cold):
+            out = {}
+            for i, ev in enumerate(evs):
+                if cold:
+                    tfc._empty(s)
+                out[i] = s._eval_footprint(ev)
+            return out
+
+        verdicts = []
+        for cold in (True, False, False):
+            fps = estimates(cold)
+            _c, _s, rolled, certified, _reg = self._run(
+                monkeypatch, frozenset(stale), [[0, 1], [2], [3]], fps)
+            verdicts.append((rolled, certified))
+        assert not fps[0].flags.writeable and fps[1].flags.writeable
+        assert verdicts[0] == verdicts[1] == verdicts[2]
+        assert verdicts[0] == (want, sorted(set(range(4)) - set(want)))
+
     def test_unprovable_rolls_back_all(self, monkeypatch):
         fps = {i: self._mask(8, i) for i in range(4)}
         _c, spec, rolled, certified, reg = self._run(
