@@ -8,19 +8,18 @@ this: datacenter = (i // 3) % 3, so every datacenter holds every class;
 fillers are dealt to nodes in proportion to the class multiplier, so every
 node starts at about the same share and none over; asks are small enough
 that a window cannot fill the cluster; the share of device jobs is set by
-the GPUs there are. Sizes, choices and the mix come from the configuration's
-file.
+the GPUs there are. Sizes, choices, the mix and the shape of each job kind
+(`kinds`: a record a kind) come from the configuration's file.
 """
 from __future__ import annotations
 
+import copy
 import random
 import uuid
 from typing import Dict, List
 
 import numpy as np
 
-#: weights that are no powers of two, one over the wide key
-AFFINITY_WEIGHTS = (37, 61)
 DIMS = ("cpu", "memory", "disk")
 
 
@@ -112,18 +111,20 @@ def kinds_sequence(cfg: dict, seed: int, n: int) -> List[str]:
 def make_job(cfg: dict, seed: int, k: int, kind: str, count: int) -> dict:
     """Job number `k` of a run, as a plain spec. The asks walk the nine
     (cpu, memory) pairs in an order shuffled per block of nine, so every
-    seed sends the same totals."""
+    seed sends the same totals. The job's shape is the default job's
+    (`binpack`: every datacenter, the `linux` constraint, nothing else; the
+    launcher's fillers are made of it) under the kind's record in the
+    configuration's `kinds`: the shape keys that differ, as literal values.
+    A kind without a record, or a record with a key that is no key of the
+    shape — one that `reference.py` and `adapter.to_job` would not read —
+    is an error: no cell is judged by a reference that ignores part of its
+    job."""
     j = cfg["job"]
     pairs = [(c, m) for c in j["cpu"] for m in j["memory"]]
     _rng(seed, f"asks/{k // len(pairs)}").shuffle(pairs)
     cpu, mem = pairs[k % len(pairs)]
     rng = _rng(seed, f"job/{k}")
-    spec = {
-        "k": k,
-        "id": f"svc-{rng.getrandbits(48):012x}",
-        "kind": kind,
-        "count": int(count),
-        "cpu": cpu, "memory": mem, "disk": int(j["disk"]),
+    shape = {
         "datacenters": datacenters(cfg),
         "constraints": [["${attr.kernel.name}", "=", "linux"]],
         "affinities": [],
@@ -132,27 +133,28 @@ def make_job(cfg: dict, seed: int, k: int, kind: str, count: int) -> dict:
         "distinct_property": None,
         "gpus": 0,
     }
-    if kind == "affinity":
-        spec["constraints"].append(["${attr.cpu.numcores}", ">=", "4"])
-        spec["affinities"] = [
-            ["${node.class}", "=", list(cfg["classes"])[-1],
-             AFFINITY_WEIGHTS[0]],
-            ["${meta.cell}", "=", f"c{int(cfg['cells']) - 211}",
-             AFFINITY_WEIGHTS[1]],
-        ]
-    elif kind == "spread":
-        spec["spread"] = {"attribute": "${node.datacenter}", "weight": 100,
-                          "targets": [["dc1", 50], ["dc2", 30], ["dc3", 20]]}
-        spec["distinct_hosts"] = True
-    elif kind.startswith("pinned-"):
-        spec["datacenters"] = [kind[len("pinned-"):]]
-    elif kind == "distinct-cell":
-        spec["distinct_property"] = ["${meta.cell}", 1]
-    elif kind == "devices":
-        spec["gpus"] = 1
-    elif kind != "binpack":
-        raise ValueError(f"unknown job kind {kind!r}")
-    return spec
+    record = (cfg.get("kinds") or {}).get(kind)
+    if kind == "binpack":
+        if record:
+            raise ValueError("`binpack` is the default job: it takes no "
+                             "record")
+    elif record is None:
+        raise ValueError(f"job kind {kind!r} has no record in the "
+                         f"configuration's `kinds`")
+    else:
+        unknown = sorted(set(record) - set(shape))
+        if unknown:
+            raise ValueError(f"job kind {kind!r}: {unknown} are no keys of "
+                             f"a job's shape {sorted(shape)}")
+        shape.update(copy.deepcopy(record))
+    return {
+        "k": k,
+        "id": f"svc-{rng.getrandbits(48):012x}",
+        "kind": kind,
+        "count": int(count),
+        "cpu": cpu, "memory": mem, "disk": int(j["disk"]),
+        **shape,
+    }
 
 
 class Cluster:

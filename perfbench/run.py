@@ -52,7 +52,11 @@ RECORDED_COUNTERS = (
     "spec.launches", "spec.certified", "spec.rolled_back",
     "view.carry_adopts", "view.chain_adopts", "view.carry_rejects",
     "view.chain_rejects", "pipeline.dispatches", "pipeline.programs",
-    "wave.dispatches", "plan_apply.partial", "plan_apply.applied")
+    "wave.dispatches", "wave.programs", "wave.slots", "wave.collisions",
+    "plan_apply.partial", "plan_apply.applied",
+    "plan_apply.rejected_devices", "sched.device_offers",
+    "sched.device_offer_retries", "sched.offers", "sched.offers_skipped",
+    "drain.footprint_estimates", "drain.footprint_hits")
 ALLOC_INDEX = re.compile(r"\[(\d+)\]$")
 
 
@@ -523,6 +527,23 @@ def window(gen: Generator, child: Child, seconds: float, trace_span: float,
     t1 = t0 + seconds
     if drained:
         gen.start_steady()
+    # the window closes on a thread of its own: stopping a profile can hold
+    # this one for tens of seconds past t1 (c1m-5k.flood), and a loop left
+    # running meanwhile sends, and has read back, twice the window's jobs
+    closed = {}
+
+    def close() -> None:
+        try:
+            gen.stop_steady()
+            closed["answered_all"] = gen.wait(sent_so_far(gen, t1),
+                                              timeout_s)
+        except BaseException as e:  # raised again where the run can end
+            closed["error"] = e
+        closed["t"] = time.monotonic()
+
+    closer = threading.Timer(max(0.0, t1 - time.monotonic()), close)
+    closer.daemon = True
+    closer.start()
     traced = None
     if trace_span:
         span = min(trace_span, seconds / 2.0)
@@ -536,18 +557,30 @@ def window(gen: Generator, child: Child, seconds: float, trace_span: float,
     time.sleep(max(0.0, t1 - time.monotonic()))
     if not drained:
         end = flat(child.ask("snap"))
-    gen.stop_steady()
-    answered_all = gen.wait(sent_so_far(gen, t1), timeout_s)
-    t_close = time.monotonic() if drained else t1
+    closer.join()
+    if "error" in closed:
+        raise closed["error"]
+    t_close = closed["t"] if drained else t1
     if drained:
         end = flat(child.ask("snap"))
     w = {"start": start, "end": end, "t0": t0, "t1": t1, "traced": traced,
-         "t_close": t_close,
-         "drain_s": time.monotonic() - t1, "answered_all": answered_all,
+         "t_close": t_close, "drain_s": closed["t"] - t1,
+         "answered_all": closed["answered_all"],
          "memory": child.ask("memory"),
          "trace": child.ask("reduce") if trace_span else None}
     gen.shutdown()
     return w
+
+
+def metrics_of(bench: dict, workload: str, trace) -> list:
+    """The entries of BENCHMARK.json a run of the cell reports: its
+    per-layer metrics in a traced run, its end-to-end metrics otherwise.
+    The entry's `workloads` list alone says where a metric is read (an
+    entry without one is read in every cell), so a later PR gives a new
+    cell its metrics by adding the cell's name to lists of BENCHMARK.json:
+    `metrics/<name>.json` says how a metric is read and names no cell."""
+    return [m for m in bench["per_layer" if trace else "end_to_end"]
+            if workload in m.get("workloads", [workload])]
 
 
 def judge(plain, reqs, asked, workload: str, seed: int, control: bool):
@@ -698,21 +731,19 @@ def main(argv=None) -> int:
                          and trace["devices"] and trace["window_s"] > 0)
         metrics = {}
         notes = {}
-        if args.rehearsal:
-            wanted = []  # counts and `correct`: a CPU gives no metric
-        elif args.trace:
-            wanted = bench["per_layer"]
+        # counts and `correct` alone in a rehearsal: a CPU gives no metric
+        wanted = ([] if args.rehearsal
+                  else metrics_of(bench, args.workload, args.trace))
+        if wanted and args.trace:
             ctx = {"start": start, "end": end, "traced": w["traced"],
                    "trace": trace if traced_ok else None,
                    "memory": w["memory"], "parent": parent,
-                   "window_s": end["t"] - start["t"], "config": cfg,
+                   # the window's own length: `end` is read when a
+                   # profile's stop lets it, after a server gone idle
+                   "window_s": t_close - t0, "config": cfg,
                    "traffic": traffic, "device": device,
                    "row_bucket": ready["row_bucket"], "notes": notes}
-        else:
-            wanted = bench["end_to_end"]
         for m in wanted:
-            if args.workload not in m.get("workloads", [args.workload]):
-                continue
             if args.trace:
                 spec = load_json(HERE, "metrics", f"{m['name']}.json")
                 v = plugin("readers", spec["reader"]).read(spec, ctx)

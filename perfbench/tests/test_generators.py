@@ -1,6 +1,9 @@
 """The generators: same seed, same cluster and schedule; exact shares per
 kind; no node over capacity at the start; every datacenter holds every
-class; the sizing rule's totals."""
+class; the sizing rule's totals; a job kind is a record in the
+configuration's file, and every cell's jobs are byte for byte what they were
+when `make_job` was an `elif` a kind."""
+import hashlib
 import json
 import os
 from collections import Counter
@@ -54,9 +57,10 @@ def test_node_shapes_are_the_configurations_own():
     assert c.cap[1].tolist() == [2950.0, 6096.0, 4000.0]
     assert {n["rack"] for n in c.nodes} == {f"r{i}" for i in range(7)}
     assert c.gpus.tolist() == [2 if i % 5 == 0 else 0 for i in range(40)]
-    spec = cl.make_job(cfg, 3, 0, "affinity", 8)
+    cfg["kinds"] = {"to-b": {"affinities": [["${node.class}", "=", "b", 9]]}}
+    spec = cl.make_job(cfg, 3, 0, "to-b", 8)
     assert spec["datacenters"] == ["dc1", "dc2"]
-    assert spec["affinities"][0][2] == "b"
+    assert spec["affinities"] == [["${node.class}", "=", "b", 9]]
 
 
 def test_no_node_starts_over_capacity_and_shares_are_even(baseline):
@@ -141,3 +145,88 @@ def test_payloads_equal_the_codecs_own_also_with_awkward_ids():
         spec = cl.make_job(cfg, 1000003, k, kinds[k], 8)
         assert bodies.of(spec) == adapter.job_payload(spec)
     assert "987652" in cl.make_job(cfg, 1000003, 4272, "binpack", 8)["id"]
+
+
+#: SHA-256 of `json.dumps` of the first 1,024 jobs of a run, taken on the
+#: parent commit of PR 34 (33c0edb), whose `make_job` built each kind in code
+GOLDEN = {
+    ("c1m-5k", 1, 0):
+        "a9375771483734935ad9d33d76f423c42c805daa47a6d06eff4e8002975c2102",
+    ("c1m-5k", 1, 3):
+        "afc94c0d629150904cbeaff16b5b3eb0ad0924540955e9dc6e4735fe9cf6b775",
+    ("c1m-5k", 1, 1000003):
+        "1a21b4b640ec9185a53b7238ab19ee6920301d2d598e0ab56376231dd019cae9",
+    ("c1m-5k", 1, 2147485621):
+        "681df3235f4111a3c10f5c641c41335a3e04ee2d97bc99138bded6843551b0db",
+    ("c1m-5k", 1000, 0):
+        "f67567ce8e47d8c9ba32b6adfbe5c4f0c3e0fe682aed7df17c5b01cabf211930",
+    ("c1m-5k", 1000, 3):
+        "a1514bdf4671261a6410cc5bf5f707ba7664059741d4e74ccbcfa379262d91f9",
+    ("c1m-5k", 1000, 1000003):
+        "9a97c4f4cddc614aab8ddae1570d74536e678ad8bbd87ac2ada8b9fb769053b3",
+    ("c1m-5k", 1000, 2147485621):
+        "83abe42c3aa892596f849fbcd4fb52e6e4f7d449d61bcbfef18a0ef4f8a0ff45",
+    ("baseline-10k", 8, 0):
+        "927a683e2e9880a1e6c660e6f57faf49e23f08596358bf448bc8679fc88bcd66",
+    ("baseline-10k", 8, 3):
+        "cbb2cd2ffc7b72aaab438035ec84f95e5ee43e37adc1d4595bbc66ca66a4be5a",
+    ("baseline-10k", 8, 1000003):
+        "969cb30805604e1aed356255231a9ebebdb5ecf468355a1b2e3fb64802c7d22e",
+    ("baseline-10k", 8, 2147485621):
+        "a2c4d3fc02a6820ee7af5702124b495325faa7a199f3ee4691f27cad7ab70d3e",
+    ("pinned-10k", 8, 0):
+        "59f81dde14bbe8fadc3f5e6bd99be2234dcba695e7bc0e765903ef34e589e5ec",
+    ("pinned-10k", 8, 3):
+        "e8a5a6886213b568625a2eb1308fd976d57feb5e71c69a211d3b77d0ecfcbdc4",
+    ("pinned-10k", 8, 1000003):
+        "1eb3472a7b4575115c6fe2cfcb1a073d27f4c1edcc9adb488c2fbfe1c069c259",
+    ("pinned-10k", 8, 2147485621):
+        "e5cb0c1801180f4f7f447c3d6dc77d00a7961ff46af65f86e5c95fcef2b0dd32",
+}
+
+
+@pytest.mark.parametrize("name,count,seed", sorted(GOLDEN))
+def test_every_cell_sends_the_jobs_it_sent_before_kinds_were_records(
+        name, count, seed):
+    cfg = cfg_of(name)
+    kinds = cl.kinds_sequence(cfg, seed, 1024)
+    jobs = [cl.make_job(cfg, seed, k, kinds[k], count) for k in range(1024)]
+    assert hashlib.sha256(json.dumps(jobs).encode()).hexdigest() == \
+        GOLDEN[name, count, seed]
+
+
+@pytest.mark.parametrize("name", ["c1m-5k", "baseline-10k", "pinned-10k"])
+def test_every_kind_of_a_mix_has_its_record(name):
+    cfg = cfg_of(name)
+    records = cfg.get("kinds", {})     # none where the mix is `binpack`
+    assert set(cfg["mix"]) - {"binpack"} == set(records)
+    for kind in cfg["mix"]:
+        spec = cl.make_job(cfg, 1, 0, kind, 8)
+        assert set(records.get(kind, {})) < set(spec)
+        # a record's value stands in the default's place, whole
+        for key, value in records.get(kind, {}).items():
+            assert spec[key] == value
+
+
+def test_a_kind_is_its_record_and_nothing_else():
+    cfg = dict(cfg_of("c1m-5k"), kinds={
+        "one-node": {"constraints": [["${node.unique.name}", "=", "node-5"]],
+                     "gpus": 2},
+        "typo": {"constraint": []},
+        "binpack": {}})
+    plain = cl.make_job(cfg, 7, 3, "binpack", 8)   # needs no record
+    spec = cl.make_job(cfg, 7, 3, "one-node", 8)
+    assert {k for k in spec if spec[k] != plain[k]} == \
+        {"kind", "constraints", "gpus"}
+    assert list(spec) == list(plain)
+    spec["constraints"].append("x")                # a job owns its lists
+    assert len(cfg["kinds"]["one-node"]["constraints"]) == 1
+    with pytest.raises(ValueError, match="no keys of a job's shape"):
+        cl.make_job(cfg, 7, 3, "typo", 8)          # an unknown key
+    with pytest.raises(ValueError, match="has no record"):
+        cl.make_job(cfg, 7, 3, "spread", 8)        # a name with no record
+    with pytest.raises(ValueError, match="has no record"):
+        cl.make_job(dict(cfg, kinds=None), 7, 3, "one-node", 8)
+    with pytest.raises(ValueError, match="takes no record"):
+        cl.make_job(dict(cfg, kinds={"binpack": {"gpus": 1}}), 7, 3,
+                    "binpack", 8)

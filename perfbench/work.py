@@ -22,7 +22,7 @@ from __future__ import annotations
 
 #: published peaks of one chip, keyed by `jax.devices()[0].device_kind`.
 #: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
-#: 819 GB/s HBM); the same numbers as `nomad_tpu/lib/roofline.py`.
+#: 819 GB/s HBM); the benchmark's own table, the program keeps none.
 PEAKS = {
     "TPU v5 lite": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
     "TPU v5e": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
