@@ -139,6 +139,13 @@ PROM_REQUIRED = frozenset({
     # lookups of an eval's static footprint mask and those the cache
     # answered (ISSUE 33): added once a drain from plain integers
     "nomad_drain_footprint_estimates", "nomad_drain_footprint_hits",
+    # masks the cache took out, one a store, to keep its bound (ISSUE 36)
+    "nomad_drain_footprint_evictions",
+    # what the device program table did (ISSUE 36): programs resolved to
+    # a row (inert pads included), rows inserted for content it did not
+    # hold, rows the LRU gave up for them
+    "nomad_hbm_table_resolved", "nomad_hbm_table_inserts",
+    "nomad_hbm_table_evictions",
     # wave dispatch (ISSUE 12): lane structure of fused mega-batches
     "nomad_wave_dispatches", "nomad_wave_programs", "nomad_wave_lanes",
     # slots of the bucketed [lanes, lane length] axis (ISSUE 32):

@@ -52,6 +52,7 @@ import numpy as np
 
 from ..kernels.placement import (DYN_FIELDS, STATIC_FIELDS, TGParams,
                                  pack_param_rows_batch)
+from ..lib.metrics import default_registry
 from ..parallel.mesh import STATIC_DIMS, pad_params, param_dims
 
 #: per-dim ceilings for table residency: a program past any of these
@@ -65,8 +66,14 @@ DIM_CEILINGS = {"v": 512, "c": 128, "a_n": 128, "s_n": 32, "dp_n": 32,
 #: rows) is the one dyn dim that can approach the node count
 DYN_CEILINGS = {"l_n": 512}
 
-#: table row capacity (LRU-evicted)
-TABLE_ROWS = 512
+#: table row capacity (LRU-evicted). A deployment's working set is its
+#: distinct (constraint LUT, ask) contents: kinds x 9 ask pairs in the
+#: benchmark's cells — 9 to 63 rows, and 576 where every job names one
+#: of 64 `meta` partitions (ISSUE 36), which 512 rows turned over 3.7
+#: times a drain. A row spans the packed static fields, of which the
+#: host-check mask is one byte a node row: ~20 KiB at 16,384 rows, so
+#: the three tables are ~20 MiB of a chip's 16 GiB at this capacity.
+TABLE_ROWS = 1024
 
 #: fixed insert-chunk width — one XLA compile for the row-insert kernel
 #: regardless of how many cold programs a dispatch carries
@@ -144,6 +151,11 @@ class DeviceProgramTable:
         #: inserts since construction (test/bench introspection)
         self.inserts = 0
         self.flushes = 0
+        # what a table does, in the process registry from its first
+        # dispatch on, at 0 (`prepare`, `_alloc_row_locked`)
+        for name in ("hbm.table_resolved", "hbm.table_inserts",
+                     "hbm.table_evictions"):
+            default_registry().counter(name)
 
     # ---- host side ----
 
@@ -177,6 +189,7 @@ class DeviceProgramTable:
             si_b, sf_b, su_b, sspec = pack_param_rows_batch(
                 padded, STATIC_FIELDS)
             rows = np.empty(len(padded), dtype=np.int32)
+            inserts0 = self.inserts
             if self._widths is None:
                 self._widths = (si_b.shape[1], sf_b.shape[1],
                                 su_b.shape[1])
@@ -197,6 +210,14 @@ class DeviceProgramTable:
                 else:
                     self._rows.move_to_end(key)
                 rows[i] = row
+            # what the table did for this dispatch (ISSUE 36): programs
+            # resolved to a row, inert pads included, and rows it had
+            # to insert for content it did not hold — first met, or
+            # evicted since (`hbm.table_evictions`, counted where the
+            # victim goes). Twice a dispatch, not once a program.
+            reg = default_registry()
+            reg.inc("hbm.table_resolved", len(padded))
+            reg.inc("hbm.table_inserts", self.inserts - inserts0)
             dyn_i, dyn_f, dyn_u, dspec = pack_param_rows_batch(
                 padded, DYN_FIELDS)
             return _Prep(self.gen, rows, dyn_i, dyn_f, dyn_u,
@@ -226,8 +247,6 @@ class DeviceProgramTable:
                 # residency: eviction reclaims the row's slot bytes for
                 # the incoming program (the table buffers themselves
                 # stay resident at fixed size)
-                from ..lib.metrics import default_registry
-
                 reg = default_registry()
                 reg.inc("hbm.table_evictions")
                 reg.inc("hbm.table_reclaimed_bytes", self._row_bytes())
@@ -244,8 +263,6 @@ class DeviceProgramTable:
             # generation flush drops the device tables wholesale; count
             # the reclaimed bytes (the ledger bookings release with the
             # buffers themselves)
-            from ..lib.metrics import default_registry
-
             default_registry().inc(
                 "hbm.table_flush_bytes",
                 self._ti.nbytes + self._tf.nbytes + self._tu.nbytes)

@@ -277,8 +277,11 @@ class Server:
         #: worker adds them to the registry once a drain
         #: (`count_footprints`)
         self._fp_estimates = self._fp_hits = 0
+        #: `ClusterTensors.static_mask_evictions` as last counted
+        self._fp_evictions_seen = 0
         self.metrics.counter("drain.footprint_estimates")
         self.metrics.counter("drain.footprint_hits")
+        self.metrics.counter("drain.footprint_evictions")
         self.workers: List[Worker] = [
             Worker(self, i) for i in range(self.config.num_schedulers)
         ]
@@ -473,16 +476,23 @@ class Server:
 
     def count_footprints(self) -> None:
         """The static-mask lookups since the last call into
-        `drain.footprint_estimates` / `drain.footprint_hits`. One worker
-        thread estimates and calls this today; with more, an increment
-        that lands between the read and the reset is lost — to a share
-        nobody schedules by."""
+        `drain.footprint_estimates` / `drain.footprint_hits`, and the
+        masks the cache took out to keep its bound into
+        `drain.footprint_evictions`. One worker thread estimates and
+        calls this today; with more, an increment that lands between
+        the read and the reset is lost — to a share nobody schedules
+        by."""
         est, hits = self._fp_estimates, self._fp_hits
         self._fp_estimates = self._fp_hits = 0
         if est:
             self.metrics.inc("drain.footprint_estimates", est)
             if hits:
                 self.metrics.inc("drain.footprint_hits", hits)
+            gone = self.state.cluster.static_mask_evictions
+            if gone != self._fp_evictions_seen:
+                self.metrics.inc("drain.footprint_evictions",
+                                 gone - self._fp_evictions_seen)
+                self._fp_evictions_seen = gone
 
     def _restore_evals(self) -> None:
         """Re-enqueue non-terminal evals from state into the broker/blocked

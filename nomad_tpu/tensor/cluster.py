@@ -157,6 +157,9 @@ class ClusterTensors:
         #: (node_version, attrs, key → mask) of `static_masks`
         self._static_masks: Tuple[int, np.ndarray, Dict] = (
             0, self.attrs, {})
+        #: masks `masks_put` took out to keep its bound: a plain
+        #: integer, read once a drain (`Server.count_footprints`)
+        self.static_mask_evictions = 0
         # ---- per-version delta logs (device-view incremental refresh) --
         # Each mutation that touches a hot tensor row (used/node_ok/
         # dyn_free) or a port-bitmap row appends (version-after-bump,
@@ -351,11 +354,13 @@ class ClusterTensors:
 
     # ---- masks that are a function of the node table alone ----
 
-    #: masks `static_masks` holds at most: bool[n_cap] each (16 KiB at
-    #: 16,384 rows); a deployment's jobs name a handful of
-    #: (datacenters, constraints) shapes, and one past the bound empties
-    #: the dict rather than choosing a victim
-    STATIC_MASKS_MAX = 32
+    #: bytes of host memory `static_masks` may hold: bool[n_cap] a mask,
+    #: so 512 masks at 8,192 rows and 256 at 16,384 — a deployment
+    #: whose jobs each name one of a few hundred partitions
+    #: (`${meta.<key>} = <one>` on 64 values: ISSUE 36) keeps every
+    #: shape it sends; `masks_put` reckons the count from the table's
+    #: rows and, at the bound, takes ONE mask out
+    STATIC_MASKS_BYTES = 4 << 20
 
     def static_masks(self) -> Tuple[np.ndarray, Dict]:
         """`(attrs, masks)`: the attribute table as it stands and the
@@ -387,16 +392,33 @@ class ClusterTensors:
             ent = self._static_masks = (v, attrs, {})
         return attrs, ent[2]
 
-    @classmethod
-    def masks_put(cls, masks: Dict, key, mask: Optional[np.ndarray]
+    def masks_put(self, masks: Dict, key, mask: Optional[np.ndarray]
                   ) -> Optional[np.ndarray]:
         """File `mask` (made read-only here, so a consumer's slip into
         an in-place write raises instead of widening or narrowing every
-        later hit) under `key` in a dict `static_masks` handed out."""
+        later hit) under `key` in a dict `static_masks` handed out.
+
+        The dict holds `STATIC_MASKS_BYTES` of masks at most, a mask
+        being one byte a row. At the bound the OLDEST entry goes (a
+        dict keeps insertion order; hits do not reorder, which would
+        be a write on every lookup) and `static_mask_evictions` counts
+        it: a working set one larger than the bound, drawn in shuffled
+        order, misses only when it draws the one shape that is out,
+        where emptying the dict missed on every shape once more (a
+        strict rotation of bound + 1 shapes defeats any order of age,
+        this one too). No lock: two threads at the bound may both take
+        a victim (one mask too few for one store), and a dict that
+        changed under the victim's lookup costs this store its
+        eviction, not its answer."""
         if mask is not None:
             mask.setflags(write=False)
-        if len(masks) >= cls.STATIC_MASKS_MAX:
-            masks.clear()
+        if len(masks) >= max(self.STATIC_MASKS_BYTES // self.n_cap, 1) \
+                and key not in masks:
+            try:
+                masks.pop(next(iter(masks)), None)
+                self.static_mask_evictions += 1
+            except (StopIteration, RuntimeError):
+                pass  # emptied or resized by another thread meanwhile
         masks[key] = mask
         return mask
 

@@ -304,26 +304,105 @@ def test_what_comes_back_cannot_be_written_through(standing):
     assert _same(s._eval_footprint(evs[0]), _fresh(s, evs[0]))
 
 
-def test_the_cache_keeps_its_bound(standing):
-    """More shapes than it holds: it empties rather than grows, and every
-    answer on the way is a fresh estimate's."""
+def _partition_evals(s, n, k0=400):
+    """`n` evals, each of a job hard-constrained to a `meta.cell` value of
+    its own (ISSUE 36's stanza): `n` distinct static shapes."""
+    evs = []
+    for i in range(n):
+        job = _job("binpack", k=k0 + i)
+        job.constraints.append(Constraint("${meta.cell}", f"c{i}", "="))
+        evs.append(_eval_of(s, job))
+    return evs
+
+
+def _bound_of(monkeypatch, s, masks):
+    """Hold the cache to `masks` masks at this table's rows."""
+    monkeypatch.setattr(ClusterTensors, "STATIC_MASKS_BYTES",
+                        masks * s.state.cluster.n_cap)
+
+
+#: 65 shapes through a cache of 64, each eval looked up twice as a drain
+#: does (the partition's estimate, then `start_batch`'s) -> the least hit
+#: share. In rotation every first lookup misses whatever goes by age, and
+#: the second finds what the first stored; in shuffled blocks (how a cell
+#: deals its kinds) a first lookup misses only on the one shape that is
+#: out.
+ORDERS = {"rotation": 50.0, "shuffled-blocks": 97.0}
+
+
+@pytest.mark.parametrize("order", sorted(ORDERS))
+def test_one_shape_past_the_bound_costs_one_mask_a_miss(
+        standing, monkeypatch, order):
     s, _nodes = standing
     _empty(s)
     cl = s.state.cluster
-    bound = ClusterTensors.STATIC_MASKS_MAX
-    evs = []
-    for i in range(2 * bound + 5):
-        job = _job("binpack", k=400 + i)
-        job.constraints.append(Constraint("${meta.cell}", f"c{i}", "!="))
-        evs.append(_eval_of(s, job))
-    sizes = []
-    for ev in evs:
+    bound = 64
+    _bound_of(monkeypatch, s, bound)
+    evs = _partition_evals(s, bound + 1)
+    rng = random.Random(36)
+    feed = []
+    for _ in range(12):
+        block = list(evs)
+        if order == "shuffled-blocks":
+            rng.shuffle(block)
+        feed.extend(block)
+    gone0 = cl.static_mask_evictions
+    s.count_footprints()
+    c0 = dict(s.metrics.counters())
+    sizes, lookups = [], 0
+    for ev in feed[:bound + 1]:         # the first pass: all misses
         assert _same(s._eval_footprint(ev), _fresh(s, ev))
         sizes.append(len(cl.static_masks()[1]))
-    assert max(sizes) == bound and sizes[bound] == 1
-    assert sizes[-1] == (2 * bound + 5) % bound
-    for ev in evs[-3:]:                 # still held: hits
-        assert not s._eval_footprint(ev).flags.writeable
+    # the bound is met and kept: the 65th store took ONE mask out
+    assert sizes[bound - 1] == sizes[bound] == bound
+    assert cl.static_mask_evictions - gone0 == 1
+    s.count_footprints()
+    c1 = dict(s.metrics.counters())
+    for ev in feed[bound + 1:]:
+        for _ in range(2):
+            got = s._eval_footprint(ev)
+            assert _same(got, _fresh(s, ev))
+            assert not got.flags.writeable  # after eviction and re-insert too
+            lookups += 1
+        assert len(cl.static_masks()[1]) == bound
+    s.count_footprints()
+    c2 = dict(s.metrics.counters())
+    est = c2["drain.footprint_estimates"] - c1["drain.footprint_estimates"]
+    hits = c2["drain.footprint_hits"] - c1["drain.footprint_hits"]
+    gone = c2["drain.footprint_evictions"] - c1["drain.footprint_evictions"]
+    assert est == lookups
+    assert 100.0 * hits / est >= ORDERS[order], (hits, est)
+    assert gone == est - hits           # a miss at the bound: one victim
+    assert c1["drain.footprint_evictions"] \
+        - c0["drain.footprint_evictions"] == 1
+    assert cl.static_mask_evictions - gone0 == 1 + gone
+
+
+def test_the_bound_is_reckoned_from_the_tables_rows(standing, monkeypatch):
+    """A mask is one byte a row: the cache holds `STATIC_MASKS_BYTES` of
+    them, so ISSUE 36's 64 partitions fit at both row buckets the
+    benchmark runs (8,192 and 16,384) with room, and a table of a
+    million rows still keeps four."""
+    budget = ClusterTensors.STATIC_MASKS_BYTES
+    assert budget // 8192 >= 4 * 64 and budget // 16384 >= 4 * 64
+    assert budget // (1 << 20) == 4
+    s, _nodes = standing
+    _empty(s)
+    cl = s.state.cluster
+    _bound_of(monkeypatch, s, 3)
+    evs = _partition_evals(s, 5, k0=480)
+    keys = []
+    for ev in evs:
+        s._eval_footprint(ev)
+        keys.append(list(cl.static_masks()[1]))
+    assert [len(k) for k in keys] == [1, 2, 3, 3, 3]
+    assert keys[3] == keys[2][1:] + keys[3][-1:]    # the oldest went
+    assert keys[4] == keys[3][1:] + keys[4][-1:]
+    # a key that is held is stored over, not evicted for
+    gone = cl.static_mask_evictions
+    masks = cl.static_masks()[1]
+    cl.masks_put(masks, keys[4][0], masks[keys[4][0]].copy())
+    assert cl.static_mask_evictions == gone and list(masks) == keys[4]
 
 
 def test_lookups_reach_the_registry_once_a_drain(standing):

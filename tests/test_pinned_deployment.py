@@ -66,35 +66,39 @@ def _jobs(rng, dcs):
     return jobs
 
 
-def _serve(dcs, monkeypatch, tamper=None, seed=32):
+def _serve(feed, monkeypatch, tamper=None, seed=32, cluster=None, jobs=None):
     """The feed through a `Server` with fused batches of 32, every job
     registered before the worker starts: ONE drain holds them all.
-    `tamper(idxs, lanes_idx)` may spoil a wave's slots in place.
+    `tamper(idxs, lanes_idx, lanes)` may spoil a wave's slots in place.
+    `cluster(rng)` and `jobs(rng, feed)` build another deployment than
+    this file's (`tests/test_computed_class_deployment.py`).
     -> nodes, fillers, jobs, served ({job id: allocations by index}),
-    counters, hists, shapes (what the table dispatches were laid out as)."""
+    counters, hists, shapes (what the table dispatches were laid out as),
+    laid (the programs of each lane of each dispatch)."""
     from nomad_tpu.server import Server, ServerConfig, select_batch
 
     monkeypatch.delenv("NOMAD_TPU_EVAL_BATCH", raising=False)
-    shapes = []
+    shapes, laid = [], []
     layout = select_batch._table_layout
 
     def spy(lanes):
         out = layout(lanes)
         shapes.append(out[3])
+        laid.append([[r.params for r in lane] for lane in lanes])
         if tamper is not None and out[2] is not None:
-            tamper(out[2], out[4])
+            tamper(out[2], out[4], lanes)
         return out
 
     monkeypatch.setattr(select_batch, "_table_layout", spy)
     rng = random.Random(seed)
     s = Server(ServerConfig(num_schedulers=1, heartbeat_ttl=3600.0,
                             eval_batch=32))
-    nodes, fillers = _cluster(rng)
+    nodes, fillers = (cluster or _cluster)(rng)
     for n in nodes:
         s.state.upsert_node(n)
     for a in fillers:
         s.state.upsert_alloc(a)
-    jobs = _jobs(rng, dcs)
+    jobs = (jobs or _jobs)(rng, feed)
     evs = [s.job_register(j) for j in jobs]
     s.start()
     try:
@@ -116,18 +120,25 @@ def _serve(dcs, monkeypatch, tamper=None, seed=32):
         s.shutdown()
     return types.SimpleNamespace(
         nodes=nodes, fillers=fillers, jobs=jobs, served=served,
-        counters=counters, hists=hists, shapes=shapes)
+        counters=counters, hists=hists, shapes=shapes, laid=laid)
 
 
-def _against_the_plain_scheduler(run):
+def _in_its_datacenter(job, node):
+    return node.datacenter in job.datacenters
+
+
+def _against_the_plain_scheduler(run, at_home=_in_its_datacenter,
+                                 outside="outside its datacenter"):
     """The serial replay: eval by eval in the order enqueued, allocation
     by allocation, the served node applied after each question. -> the
-    list of what differs (empty: every node and score equal)."""
+    list of what differs (empty: every node and score equal).
+    `at_home(job, node)`: the deployment's gate, named `outside` where a
+    served node fails it."""
     nodes = run.nodes
     by_node = {}
     for a in run.fillers:
         by_node.setdefault(a.node_id, []).append(a)
-    dc_of = {n.id: n.datacenter for n in nodes}
+    node_of = {n.id: n for n in nodes}
     diffs = []
     for j in run.jobs:
         allocs = run.served[j.id]
@@ -140,9 +151,8 @@ def _against_the_plain_scheduler(run):
             opt = select_option(ctx, j, j.task_groups[0])
             score = next((sm.norm_score for sm in a.metrics.score_meta
                           if sm.node_id == a.node_id), None)
-            if dc_of[a.node_id] not in j.datacenters:
-                diffs.append((j.id, a.name, "outside its datacenter",
-                              dc_of[a.node_id]))
+            if not at_home(j, node_of[a.node_id]):
+                diffs.append((j.id, a.name, outside, a.node_id))
             elif opt is None or a.node_id != opt.node.id:
                 diffs.append((j.id, a.name, "node", a.node_id,
                               opt and opt.node.id))
@@ -197,7 +207,7 @@ def test_the_comparison_catches_two_lanes_swapped(monkeypatch):
     """The negative: the layout hands the first programs of two lanes
     each other's slot, so each reads the other lane's result. Plan apply
     finds room on those nodes and commits; the replay must not agree."""
-    def swap(idxs, lanes_idx):
+    def swap(idxs, lanes_idx, _lanes):
         a, b = lanes_idx[0][0], lanes_idx[1][0]
         idxs[a], idxs[b] = idxs[b], idxs[a]
 

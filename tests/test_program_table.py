@@ -240,6 +240,72 @@ class TestTableMechanics:
         assert table.stats()["rows"] <= 4
 
 
+    def test_a_working_set_past_the_capacity_resolves_right(self):
+        """ISSUE 36's deployment: 64 partitions x 9 (cpu, memory) asks are
+        576 distinct static rows, more than the 512 the table held until
+        then. Through a table one row short of the set every program
+        still resolves to a row that holds ITS content after eviction
+        and re-insert (the rows a table with room gathers, bit for bit),
+        and `hbm.table_resolved` / `hbm.table_inserts` /
+        `hbm.table_evictions` count what it did; `TABLE_ROWS` itself
+        holds the set whole."""
+        from nomad_tpu.server.program_table import TABLE_ROWS
+
+        assert TABLE_ROWS >= 2 * 64 * 9 - 128   # the set, with room
+        cl = ClusterTensors()
+        for i in range(64):
+            n = mock.node()
+            n.id = f"node-{i}"
+            n.meta["cell"] = f"c{i % 16}"
+            cl.upsert_node(n)
+        asks = [(c, m) for c in (21, 37, 53) for m in (17, 29, 43)]
+        jobs = []
+        for k in range(16):
+            for cpu, mem in asks:
+                j = mock.job()
+                j.task_groups[0].networks = []
+                j.task_groups[0].tasks[0].resources.cpu = cpu
+                j.task_groups[0].tasks[0].resources.memory_mb = mem
+                j.constraints.append(Constraint("${meta.cell}", f"c{k}", "="))
+                jobs.append(j)
+        _stack, params = _compile(cl, jobs)
+        n = len(params)                      # 144 distinct static rows
+        small, roomy = DeviceProgramTable(capacity=n - 1), \
+            DeviceProgramTable(capacity=2 * n)
+        reg = default_registry()
+        names = ("hbm.table_resolved", "hbm.table_inserts",
+                 "hbm.table_evictions")
+
+        def counted():
+            c = reg.counters(prefix="hbm.")
+            return [c.get(k[4:], 0) for k in names]
+
+        def gathered(table, batch):
+            prep = table.prepare(batch)
+            assert prep is not None
+            ti, tf, tu, _nb, _count = table.commit(prep, default_ledger())
+            return [np.asarray(t)[prep.rows] for t in (ti, tf, tu)]
+
+        for rnd in range(3):                 # the set in rotation, thrice
+            for at in range(0, n, 16):
+                batch = params[at:at + 16]
+                want = gathered(roomy, batch)
+                c0 = counted()
+                got = gathered(small, batch)
+                c1 = counted()
+                for a, b in zip(got, want):
+                    assert np.array_equal(a, b)
+                d = [b - a for a, b in zip(c0, c1)]
+                assert d[0] == 16
+                # LRU under a rotation one past its capacity: every
+                # program's row went before it came round again
+                assert d[1] == 16
+                assert d[2] == (16 if rnd else (1 if at + 16 >= n else 0))
+        # the one flush is the first prepare's, which sized the rows
+        assert small.stats()["rows"] == n - 1 and small.flushes == 1
+        assert roomy.inserts == n            # with room: inserted once
+
+
 def _run_round(cl, jobs, coord=None, eval_ids=None, plans=None):
     coord = coord or SelectCoordinator()
     if eval_ids:

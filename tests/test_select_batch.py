@@ -435,6 +435,55 @@ class TestTableLayout:
             assert (pad.pclr_idx == -1).all() and (pad.pset_idx == -1).all()
 
 
+    @pytest.mark.parametrize("sizes", [
+        (2, 2) + (1,) * 28,                  # 32 evals, 30 partitions
+        (3, 2, 2, 2, 2) + (1,) * 21,         # 26 groups, 32 evals
+        (5, 3, 3) + (1,) * 21,               # one long group: 24 groups
+        (9,) + (1,) * 23,                    # a lane to itself, bucket 16
+        (1,) * 30,                           # 30 evals, 30 partitions
+    ], ids=["30-groups", "26-groups", "24-groups", "one-long-group",
+            "30-singles"])
+    def test_many_groups_share_eight_lanes(self, sizes):
+        """ISSUE 36: more disjoint groups than `_MAX_WAVE_LANES`. The
+        groups go longest first onto the least loaded of 8 lanes, so the
+        longest lane — the wave's serial depth — is LPT's, and every
+        program gets a slot of its own in the (8, bucket) axis."""
+        import heapq
+
+        from nomad_tpu.server.select_batch import _bucket, _table_layout
+
+        lanes, reqs, gid_of = _lanes_of(sizes)
+        assert len(lanes) == 8
+        loads = [0] * 8
+        heapq.heapify(loads)
+        for n in sorted(sizes, reverse=True):   # LPT, on its own
+            heapq.heappush(loads, heapq.heappop(loads) + n)
+        assert sorted(map(len, lanes)) == sorted(loads)
+        longest = max(loads)
+        assert longest <= max(max(sizes), -(-sum(sizes) // 8) + 1)
+        out, params, idxs, shape, lanes_idx = _table_layout(lanes)
+        assert shape == (8, _bucket(longest, lo=2))
+        assert len(params) == shape[0] * shape[1]
+        # a permutation into the slots: none twice, none outside
+        assert len(set(idxs)) == len(idxs) == len(reqs)
+        assert all(0 <= i < len(params) for i in idxs)
+        assert sorted(r.order for r in out) == list(range(len(reqs)))
+        for li, lane in enumerate(lanes_idx):
+            assert [idxs[j] for j in lane] == \
+                [li * shape[1] + p for p in range(len(lane))]
+            # whole groups: none straddles two lanes, and inside a lane
+            # a group's programs keep their order
+            orders = [out[j].order for j in lane]
+            for gid in {gid_of[o] for o in orders}:
+                mine = [o for o in orders if gid_of[o] == gid]
+                assert mine == sorted(mine)
+                assert len(mine) == sizes[gid]
+        for j, r in enumerate(out):
+            assert params[idxs[j]] is r.params
+        assert sum(1 for p in params if p.n_place == 0) \
+            == len(params) - len(reqs)
+
+
 class _SpanLog:
     """The tracer half `_trace` / `_dist_traces` use."""
 
