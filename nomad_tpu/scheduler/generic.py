@@ -324,63 +324,66 @@ class GenericScheduler:
         rides the registry). Dimension keys keep their display names —
         the exposition layer mangles to [a-z0-9_]."""
         reg = planner_registry(self.planner)
-        if ex.get("filtered_constraint"):
+        if ex["filtered_constraint"]:
             reg.inc("scheduler.filter.constraint", ex["filtered_constraint"])
-        if ex.get("filtered_device_plugin"):
+        if ex["filtered_device_plugin"]:
             reg.inc("scheduler.filter.device_plugin",
                     ex["filtered_device_plugin"])
-        dh = sum(s["filtered_distinct_hosts"] for s in ex["steps"])
-        dp = sum(s["filtered_distinct_property"] for s in ex["steps"])
+        dh = sum(ex["filtered_distinct_hosts"])
+        dp = sum(ex["filtered_distinct_property"])
         if dh:
             reg.inc("scheduler.filter.distinct_hosts", dh)
         if dp:
             reg.inc("scheduler.filter.distinct_property", dp)
         dims: Dict[str, int] = {}
-        for s in ex["steps"]:
-            for dim, n in s["dimension_exhausted"].items():
+        for exhausted in ex["dimension_exhausted"]:
+            for dim, n in exhausted.items():
                 dims[dim] = dims.get(dim, 0) + n
         for dim, n in dims.items():
             reg.inc(f"scheduler.exhausted.{dim}", n)
 
     @staticmethod
-    def _apply_explain(metrics: AllocMetric, ex: dict, step: int) -> None:
-        """Fill one placement's AllocMetric from the kernel attribution
-        (reference: the iterator chain fills these as it walks,
-        feasible.go filter_node / rank.go exhausted_node / kheap score
-        meta — here the fused kernel already counted, so this is a
-        host-side copy, not a recount)."""
+    def _group_metrics(ex: Optional[dict], n: int, n_ready: int,
+                       by_dc: Dict[str, int]) -> List[AllocMetric]:
+        """One AllocMetric a placement of a task group, from the
+        kernel attribution's columns (scheduler/stack.py
+        explain_columns; reference: the iterator chain fills these as
+        it walks, feasible.go filter_node / rank.go exhausted_node /
+        kheap score meta — here the fused kernel already counted, so
+        this is a host-side copy, not a recount). What every placement
+        of the group shares is worked out once. Without attribution
+        (NOMAD_TPU_EXPLAIN=0, an opted-out program) a metric carries the
+        host's ready counts alone."""
+        if ex is None:
+            return [AllocMetric(nodes_evaluated=n_ready,
+                                nodes_available=dict(by_dc))
+                    for _ in range(n)]
         # the kernel count supersedes the host's per-DC ready count: it
         # respects sampled-candidate restriction, and the
         # evaluated−filtered−exhausted arithmetic only closes against
         # the same taxonomy (DC membership is a counted LUT row here)
-        metrics.nodes_evaluated = ex["nodes_evaluated"]
-        metrics.nodes_filtered = ex["nodes_filtered"]
-        for label, n in ex["constraint_filtered"].items():
-            metrics.constraint_filtered[label] = (
-                metrics.constraint_filtered.get(label, 0) + n)
+        evaluated = ex["nodes_evaluated"]
+        filtered = ex["filtered_constraint"] + ex["filtered_device_plugin"]
+        by_constraint = dict(ex["constraint_filtered"])
         if ex["filtered_device_plugin"]:
-            metrics.constraint_filtered["device-plugin/host checks"] = \
+            by_constraint["device-plugin/host checks"] = \
                 ex["filtered_device_plugin"]
-        if step < len(ex["steps"]):
-            s = ex["steps"][step]
-            if s["filtered_distinct_hosts"]:
-                metrics.nodes_filtered += s["filtered_distinct_hosts"]
-                metrics.constraint_filtered["distinct_hosts"] = \
-                    s["filtered_distinct_hosts"]
-            if s["filtered_distinct_property"]:
-                metrics.nodes_filtered += s["filtered_distinct_property"]
-                metrics.constraint_filtered["distinct_property"] = \
-                    s["filtered_distinct_property"]
-            metrics.nodes_exhausted = s["nodes_exhausted"]
-            for dim, n in s["dimension_exhausted"].items():
-                metrics.dimension_exhausted[dim] = (
-                    metrics.dimension_exhausted.get(dim, 0) + n)
-            for entry in s["top_nodes"]:
-                for name, v in entry["scores"].items():
-                    if v != 0.0:
-                        metrics.score_node(entry["node_id"], name, v)
-                metrics.score_node(entry["node_id"], "normalized-score",
-                                   entry["norm_score"])
+        out = []
+        for dh, dp, exhausted, dims, top in zip(
+                ex["filtered_distinct_hosts"],
+                ex["filtered_distinct_property"], ex["nodes_exhausted"],
+                ex["dimension_exhausted"], ex["score_meta"]):
+            cf = dict(by_constraint)
+            if dh:
+                cf["distinct_hosts"] = dh
+            if dp:
+                cf["distinct_property"] = dp
+            out.append(AllocMetric(
+                nodes_evaluated=evaluated, nodes_filtered=filtered + dh + dp,
+                nodes_available=dict(by_dc), constraint_filtered=cf,
+                nodes_exhausted=exhausted, dimension_exhausted=dims,
+                score_meta=top))
+        return out
 
     def _compute_placements(
         self,
@@ -441,19 +444,17 @@ class GenericScheduler:
             # whether what commits is what the kernel added
             plain = not offer_needs_node(tg)
             certified = False
+            # kernel-native attribution (same fused dispatch): filtered
+            # stages, exhausted dimensions, top-K score breakdown — for
+            # successes AND failures
+            group_metrics = self._group_metrics(
+                result.explain, len(entries), n_ready, by_dc)
 
             for i, (p, prev, _dest) in enumerate(entries):
                 node_id = result.node_ids[i]
                 score = result.scores[i]
                 victims: List[Allocation] = []
-                metrics = AllocMetric()
-                metrics.nodes_evaluated = n_ready
-                metrics.nodes_available = dict(by_dc)
-                if result.explain is not None:
-                    # kernel-native attribution (same fused dispatch):
-                    # filtered stages, exhausted dimensions, top-K score
-                    # breakdown — for successes AND failures
-                    self._apply_explain(metrics, result.explain, i)
+                metrics = group_metrics[i]
                 if node_id is None and self.preemption_enabled:
                     # Second pass with eviction enabled (reference
                     # selectNextOption, generic_sched.go:720-738)
@@ -542,8 +543,7 @@ class GenericScheduler:
                     client_status=ALLOC_CLIENT_PENDING,
                     job_version=self.job.version,
                 )
-                alloc.metrics.score_node(node_id, "normalized-score", score)
-                alloc.metrics.populate_score_meta()
+                metrics.score_selected(node_id, score)
                 if victims:
                     alloc.preempted_allocations = [v.id for v in victims]
                 if prev is not None:

@@ -25,7 +25,7 @@ from ..lib.metrics import default_registry
 from ..kernels.placement import (EXPLAIN_SCORE_NAMES, ClusterArrays,
                                  PlacementExplain, PlacementResult, TGParams)
 from ..utils import bucket as _shared_bucket, widen_lut
-from ..structs import Allocation, Job, TaskGroup
+from ..structs import Allocation, Job, NodeScoreMeta, TaskGroup
 from ..structs.job import (CONSTRAINT_DISTINCT_HOSTS,
                            CONSTRAINT_DISTINCT_PROPERTY)
 from ..tensor.cluster import DELTA_LOG_LEN, R_TOTAL, ClusterTensors
@@ -66,8 +66,8 @@ class SelectResult:
     nodes_feasible: int
     nodes_fit: List[int]
     raw: PlacementResult = None
-    #: host-shaped attribution (see TPUStack._explain_host) — None when
-    #: the dispatch ran without explain outputs
+    #: host-shaped attribution (see explain_columns) — None when the
+    #: dispatch ran without explain outputs
     explain: Optional[dict] = None
     #: the compiled ask vector (f32[R]) this selection placed against —
     #: the scheduler compares each committed placement's usage row to it
@@ -1860,22 +1860,18 @@ class TPUStack:
                 ex_np = PlacementExplain(
                     *(np.asarray(x) for x in result.explain))  # nomadlint: ok NLD01 solo fallback, outside ledger/guard by design (_to_device)
         snap_rows = self.cluster.node_of_row
-        node_ids: List[Optional[str]] = []
-        out_scores: List[float] = []
-        for i in range(n_place):
-            row = int(sel[i])
-            node_ids.append(snap_rows[row] if row >= 0 else None)
-            out_scores.append(float(scores[i]))
         explain_host = None
         if ex_np is not None:
             prog = self._static_program(job, tg, volumes)
-            explain_host = self._explain_host(ex_np, prog["cc"].labels,
-                                              n_place)
+            explain_host = explain_columns(
+                ex_np, prog["cc"].labels, n_place, self._dimension_names(),
+                snap_rows)
         return SelectResult(
-            node_ids=node_ids,
-            scores=out_scores,
+            node_ids=[snap_rows[row] if row >= 0 else None
+                      for row in sel[:n_place].tolist()],
+            scores=scores[:n_place].tolist(),
             nodes_feasible=n_feas,
-            nodes_fit=[int(x) for x in np.asarray(n_fit)[:n_place]],
+            nodes_fit=np.asarray(n_fit)[:n_place].tolist(),
             raw=result,
             explain=explain_host,
             ask=np.asarray(params.ask, dtype=np.float32),
@@ -1891,63 +1887,74 @@ class TPUStack:
             names[col] = f"devices: {pool}"
         return names
 
-    def _explain_host(self, ex: PlacementExplain, labels: Sequence[str],
-                      n_place: int) -> dict:
-        """Numpy PlacementExplain → the host-shaped attribution dict.
 
-        All counts become plain Python ints (the wire codec rejects
-        numpy scalars). Constraint columns beyond `labels` are padding
-        (all-true rows) and always count 0; top-K rows with scores at
-        the mask floor are infeasible tail entries and are dropped."""
-        dim_names = self._dimension_names()
-        rows = self.cluster.node_of_row
-        cfilt = {}
-        for c, label in enumerate(labels):
-            v = int(ex.filt_constraint[c])
-            if v:
-                cfilt[label] = cfilt.get(label, 0) + v
-        steps = []
-        for i in range(min(n_place, int(ex.filt_distinct.shape[0]))):
-            dims = {}
-            for r, name in enumerate(dim_names):
-                v = int(ex.exh_dim[i, r])
-                if v:
-                    dims[name] = v
-            if int(ex.exh_dyn_ports[i]):
-                dims["dynamic-ports"] = int(ex.exh_dyn_ports[i])
-            if int(ex.exh_res_ports[i]):
-                dims["reserved-ports"] = int(ex.exh_res_ports[i])
-            top = []
-            for k in range(ex.topk_idx.shape[1]):
-                score = float(ex.topk_score[i, k])
-                row = int(ex.topk_idx[i, k])
-                if score <= -1e29 or row < 0 or row >= len(rows):
-                    continue  # infeasible tail of the top-K
-                nid = rows[row]
-                if nid is None:
-                    continue
-                top.append({
-                    "node_id": nid,
-                    "norm_score": score,
-                    "scores": {name: float(ex.topk_parts[i, k, j])
-                               for j, name in
-                               enumerate(EXPLAIN_SCORE_NAMES)},
-                })
-            steps.append({
-                "filtered_distinct_hosts": int(ex.filt_distinct[i]),
-                "filtered_distinct_property": int(ex.filt_dp[i]),
-                "nodes_exhausted": sum(dims.values()),
-                "dimension_exhausted": dims,
-                "top_nodes": top,
-            })
-        return {
-            "nodes_evaluated": int(ex.nodes_evaluated),
-            "filtered_constraint": int(ex.filt_lut),
-            "filtered_device_plugin": int(ex.filt_extra),
-            "nodes_filtered": int(ex.filt_lut) + int(ex.filt_extra),
-            "constraint_filtered": cfilt,
-            "steps": steps,
-        }
+def explain_columns(ex: PlacementExplain, labels: Sequence[str],
+                    n_place: int, dim_names: Sequence[str],
+                    node_of_row: Sequence[Optional[str]]) -> dict:
+    """Numpy PlacementExplain → one task group's host-shaped
+    attribution, in ONE pass over whole arrays: every leaf is cut to
+    the group's placements and converted once (`.tolist()` — plain
+    Python ints and floats, the wire codec rejects numpy scalars), and
+    what an AllocMetric holds is built straight from those lists.
+
+    The group's own counts are scalars (`nodes_evaluated`,
+    `filtered_constraint`, `filtered_device_plugin`) and one dict
+    (`constraint_filtered`, by LUT-row label); the other keys are
+    columns with one entry a placement, in placement order:
+    `filtered_distinct_hosts`, `filtered_distinct_property`,
+    `nodes_exhausted`, `dimension_exhausted` (a dict each: resource
+    columns in column order, then dynamic-ports, reserved-ports) and
+    `score_meta` (a NodeScoreMeta list each, in the kernel's top-K
+    order, which is descending; `norm_score` set, "normalized-score"
+    last of an entry's scores, a part that is 0.0 left out).
+
+    Constraint columns beyond `labels` are padding (all-true rows) and
+    always count 0; top-K rows with scores at the mask floor are
+    infeasible tail entries and are dropped, as are rows outside the
+    table or without a node. The kernel's top-K rows are distinct
+    (`lax.top_k`), so a placement names a node once."""
+    cfilt: Dict[str, int] = {}
+    for label, v in zip(labels, ex.filt_constraint.tolist()):
+        if v:
+            cfilt[label] = cfilt.get(label, 0) + v
+    n_rows = len(node_of_row)
+    dimension_exhausted = []
+    for row, dyn, res in zip(ex.exh_dim[:n_place].tolist(),
+                             ex.exh_dyn_ports[:n_place].tolist(),
+                             ex.exh_res_ports[:n_place].tolist()):
+        dims = {name: v for name, v in zip(dim_names, row) if v}
+        if dyn:
+            dims["dynamic-ports"] = dyn
+        if res:
+            dims["reserved-ports"] = res
+        dimension_exhausted.append(dims)
+    score_meta = []
+    for rows, scores, parts in zip(ex.topk_idx[:n_place].tolist(),
+                                   ex.topk_score[:n_place].tolist(),
+                                   ex.topk_parts[:n_place].tolist()):
+        top = []
+        for row, score, part in zip(rows, scores, parts):
+            if score <= -1e29 or row < 0 or row >= n_rows:
+                continue  # infeasible tail of the top-K
+            nid = node_of_row[row]
+            if nid is None:
+                continue
+            by_name = {EXPLAIN_SCORE_NAMES[j]: v
+                       for j, v in enumerate(part) if v != 0.0}
+            by_name["normalized-score"] = score
+            top.append(NodeScoreMeta(nid, by_name, score))
+        score_meta.append(top)
+    return {
+        "nodes_evaluated": int(ex.nodes_evaluated),
+        "filtered_constraint": int(ex.filt_lut),
+        "filtered_device_plugin": int(ex.filt_extra),
+        "constraint_filtered": cfilt,
+        "filtered_distinct_hosts": ex.filt_distinct[:n_place].tolist(),
+        "filtered_distinct_property": ex.filt_dp[:n_place].tolist(),
+        "nodes_exhausted": [sum(d.values()) for d in dimension_exhausted],
+        "dimension_exhausted": dimension_exhausted,
+        "score_meta": score_meta,
+    }
 
 
 def _sparse_counts(counts: Dict[int, float]) -> Tuple[np.ndarray, np.ndarray]:

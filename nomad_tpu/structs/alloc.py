@@ -149,6 +149,23 @@ class AllocMetric:
         sm = NodeScoreMeta(node_id=node_id, scores={name: score})
         self.score_meta.append(sm)
 
+    def score_selected(self, node_id: str, score: float, k: int = 5) -> None:
+        """The selected node's own final score over whatever the list
+        holds for it: `score_node(node_id, "normalized-score", score)`,
+        then `populate_score_meta(k)`. A list that came populated and
+        in descending order (the kernel's top-K, scheduler/stack.py
+        explain_columns) with the node first, and still first under the
+        new score, is in that order already: nothing to search or
+        sort."""
+        top = self.score_meta
+        if (top and len(top) <= k and top[0].node_id == node_id
+                and (len(top) == 1 or score >= top[1].norm_score)):
+            top[0].scores["normalized-score"] = score
+            top[0].norm_score = score
+            return
+        self.score_node(node_id, "normalized-score", score)
+        self.populate_score_meta(k)
+
     def populate_score_meta(self, k: int = 5) -> None:
         """Derive each node's norm_score from its "normalized-score" entry,
         then retain only the top-K nodes, descending (reference
