@@ -21,6 +21,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
+from ..lib.trace import host_span
 from ..structs.codec import from_json_tree, from_wire, to_json_tree, to_wire
 
 
@@ -81,8 +82,18 @@ class HTTPApi:
 
     def __init__(self, agent, host: str = "127.0.0.1", port: int = 0,
                  tls=None) -> None:
+        from ..lib.metrics import default_registry
+
         self.agent = agent
         api = self
+        # every PUT/POST that returns a response (ISSUE 38): its wall
+        # from the handler's entry to the response written, and the
+        # handler thread's CPU over the same interval — process-wide,
+        # like the RPC transport's instruments
+        reg = default_registry()
+        self._http_ms = reg.histogram("http.handler_ms")
+        self._http_requests = reg.counter("http.requests")
+        self._http_cpu_ms = reg.counter("http.cpu_ms")
 
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
@@ -136,6 +147,17 @@ class HTTPApi:
                 self.wfile.write(body)
 
             def _handle(self, method: str) -> None:
+                if method != "PUT":
+                    self._serve(method)
+                    return
+                t0, cpu0 = time.monotonic(), time.thread_time()
+                with host_span("http"):
+                    self._serve(method)
+                api._http_ms.add_sample((time.monotonic() - t0) * 1e3)
+                api._http_requests.inc()
+                api._http_cpu_ms.inc((time.thread_time() - cpu0) * 1e3)
+
+            def _serve(self, method: str) -> None:
                 try:
                     parsed = urlparse(self.path)
                     # web console (ui/ in the reference; served from the

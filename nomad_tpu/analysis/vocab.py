@@ -200,6 +200,20 @@ PROM_REQUIRED = frozenset({
     "nomad_events_topic_job", "nomad_events_topic_eval",
     "nomad_events_topic_alloc", "nomad_events_topic_deployment",
     "nomad_events_topic_node", "nomad_events_topic_plan",
+    # the host's critical path, counted where the work happens (ISSUE
+    # 38): the PUT/POST handlers' wall, count and thread CPU; every offer
+    # of a speculative launch, its wall and one series an outcome (they
+    # sum to the offers); a plan's wait for the commit-point mutex, its
+    # verify loop and its commit
+    "nomad_http_handler_ms", "nomad_http_requests", "nomad_http_cpu_ms",
+    "nomad_spec_offers", "nomad_spec_offer_ms",
+    "nomad_spec_offer_launched", "nomad_spec_offer_no_successor",
+    "nomad_spec_offer_off", "nomad_spec_offer_gate",
+    "nomad_spec_offer_rendezvous", "nomad_spec_offer_binding",
+    "nomad_spec_offer_pack", "nomad_spec_offer_view",
+    "nomad_spec_offer_error",
+    "nomad_plan_apply_wait_ms", "nomad_plan_apply_verify_ms",
+    "nomad_plan_apply_commit_ms",
 })
 
 #: the raft node's promised series (ISSUE 13) — exposed from the NODE's
@@ -263,6 +277,8 @@ ALLOWED_PREFIXES = (
     "nomad_runtime_",         # JAX compiles / tracing+lowering and
                               # collector pauses of the process
                               # (ISSUE 26, lib/backend.py)
+    "nomad_http_",            # the PUT/POST handlers' wall, count and
+                              # thread CPU (ISSUE 38, agent/http.py)
 )
 
 #: the only label names any exposed series may carry
@@ -316,8 +332,10 @@ ALLOWED_SITES = frozenset(TRANSFER_SITES | RESIDENCY_SITES)
 #:   eval          child of the span current at broker enqueue
 #:                 (rpc.forward when forwarded, http.submit when local)
 #:   eval.<phase>  child of `eval` — one per lib/trace.py PHASES entry
-#:                 up to `ack` (the scheduler thread's own four phases
-#:                 are not mirrored), off the EvalTracer's monotonic spans
+#:                 up to `ack` (the scheduler thread's own phases are not
+#:                 mirrored, nor a fused dispatch's pack / delta_apply /
+#:                 kernel, which its timeline record holds once),
+#:                 off the EvalTracer's monotonic spans
 #:   plan.apply    child of `eval` — span id LEADER-MINTED in
 #:                 plan_apply.apply (like `now=`) and stamped onto the
 #:                 plan's allocs before the raft entry is journaled
@@ -329,8 +347,7 @@ SPAN_NAMES = frozenset({
     "rpc.forward",
     "eval",
     "eval.queue_wait", "eval.claim", "eval.snapshot", "eval.schedule",
-    "eval.pack", "eval.delta_apply", "eval.kernel", "eval.plan_apply",
-    "eval.ack",
+    "eval.plan_apply", "eval.ack",
     "plan.apply",
     "alloc.start",
     "alloc.health",

@@ -24,17 +24,21 @@ Phase taxonomy (what each span bounds):
 - `claim`       dequeue → scheduler start (batch drain + thread handoff)
 - `snapshot`    state.snapshot_min_index (MVCC view resolution)
 - `schedule`    scheduler process() total (reconcile + compile + select + plan)
-- `pack`        coordinator param stack/pack (host-side batch prep)
-- `delta_apply` device cluster-view refresh at dispatch (delta row
-                update or full upload — TPUStack.device_arrays)
-- `kernel`      fused placement-kernel dispatch (device + transfer)
 - `plan_apply`  submit_plan → PlanResult (queue hop + verify + commit)
 - `ack`         broker ack/nack point (zero-length terminator)
 
+A fused dispatch's own intervals — `pack` (coordinator param
+stack/pack), `delta_apply` (device cluster-view refresh: delta row
+update or full upload) and `kernel` (launch → first read) — are recorded
+ONCE a dispatch, on its `DispatchTimeline` record, which names the evals
+that rode it; `get()` joins them into each of those evals' traces
+(ISSUE 38: copying them to each of ≤32 evals was ~96 records a dispatch
+on the thread that paces the drain).
+
 On the fused batch path `schedule` is also split into what the
-scheduler thread was doing (ISSUE 26), so that `schedule` = `prepare` +
-`park` + `result_wait` + `plan_build` + `plan_apply` + a small tail
-(eval status update):
+scheduler thread was doing (ISSUES 26, 38), so that `schedule` =
+`prepare` + `park` + `result_wait` + `plan_build` + `plan_apply` +
+`finish`, each starting at the instant the one before it ended:
 
 - `prepare`     process() start (or a refreshed plan's return) → the
                 coordinator's select() entry (reconcile, compile_tg)
@@ -44,6 +48,9 @@ scheduler thread was doing (ISSUE 26), so that `schedule` = `prepare` +
                 (lock wait, or the blocking device→host fetch)
 - `plan_build`  select() return → the next select() / submit_plan()
                 entry (the per-allocation loop of scheduler/generic.py)
+- `finish`      submit_plan()'s return with no refreshed plan → process()'s
+                return: the eval's status update (`_set_status` →
+                `update_eval` → store upsert → event publish)
 - `device_offer` inside `plan_build`, only for an eval whose group asks
                 for a device: the time its offers spent drawing instance
                 ids (DeviceAllocator + assign_task_devices), summed into
@@ -53,12 +60,14 @@ scheduler thread was doing (ISSUE 26), so that `schedule` = `prepare` +
 on purpose: their `time.thread_time()` and wall deltas also sum into
 the counters `sched.phase_cpu_ms` / `sched.phase_wall_ms`, whose ratio
 says how much of "host work" is waiting for the GIL or a store lock.
-These four are in the eval's own trace and the histograms, not in the
+`finish` takes store and event-broker locks, so it stays out of them.
+These are in the eval's own trace and the histograms, not in the
 cross-process SpanStore: mirroring them cost 8 % of singles' rate.
 
 `host_span(name)` puts the same phases (each `device_offer` by itself;
-and the coordinator's, the applier's and the collector's) into the JAX
-profiler's host plane as `nomad/<name>`, on the device trace's clock.
+and the coordinator's, the applier's, the HTTP handlers' and the
+collector's) into the JAX profiler's host plane as `nomad/<name>`, on
+the device trace's clock.
 """
 from __future__ import annotations
 
@@ -74,7 +83,11 @@ from .tracectx import SpanStore, TraceContext, new_span_id
 #: canonical span order for display/aggregation
 PHASES = ("queue_wait", "claim", "snapshot", "schedule", "pack",
           "delta_apply", "kernel", "plan_apply", "ack",
-          "prepare", "park", "result_wait", "plan_build", "device_offer")
+          "prepare", "park", "result_wait", "plan_build", "finish",
+          "device_offer")
+#: the scheduler thread's phases whose CPU and wall count into
+#: `sched.phase_cpu_ms` / `sched.phase_wall_ms` (they never block on purpose)
+_UNBLOCKED = ("prepare", "plan_build")
 
 #: (`jax.profiler.TraceAnnotation`, JAX's profile state), looked up on
 #: first use so that importing this module never imports JAX; False
@@ -143,6 +156,10 @@ class EvalTracer:
         #: per scheduler thread: the phases of the eval it is running
         self._tls = threading.local()
         self._phase_hists: Dict[str, object] = {}
+        #: the server's `DispatchTimeline` (lib/transfer.py), whose
+        #: records hold the fused dispatches' pack / delta_apply / kernel
+        #: intervals and the ids of the evals that rode each
+        self.timeline = None
         if registry is not None:
             self._phase_cpu = registry.counter("sched.phase_cpu_ms")
             self._phase_wall = registry.counter("sched.phase_wall_ms")
@@ -263,31 +280,39 @@ class EvalTracer:
         tls.phases, tls.open = [], None
         tls.cpu_s = tls.wall_s = 0.0
 
-    def host_begin(self, phase: str) -> None:
-        """Open `phase` (`prepare` / `plan_build`: the thread never
-        blocks on purpose in them) on the calling thread, closing the
-        one still open; a no-op on a thread that is not armed."""
+    def host_begin(self, phase: str, at: Optional[float] = None) -> None:
+        """Open `phase` (`prepare` / `plan_build` / `finish`) on the
+        calling thread at monotonic `at` (now by default: a caller that
+        just closed the phase before passes its end, so that the two
+        meet), closing the one still open; a no-op on a thread that is
+        not armed."""
         tls = self._tls
         if getattr(tls, "phases", None) is None:
             return
-        self.host_end()
+        self.host_end(at)
         ann = host_span(phase)
         ann.__enter__()
-        tls.open = (phase, time.monotonic(), time.thread_time(), ann)
+        cpu0 = time.thread_time() if phase in _UNBLOCKED else None
+        tls.open = (phase, time.monotonic() if at is None else at, cpu0,
+                    ann)
 
-    def host_end(self) -> None:
-        """Close the calling thread's open phase, if any."""
+    def host_end(self, at: Optional[float] = None) -> Optional[float]:
+        """Close the calling thread's open phase, if any, at monotonic
+        `at` (now by default); returns the instant it closed, or None
+        when none was open."""
         tls = self._tls
         cur = getattr(tls, "open", None)
         if cur is None:
-            return
+            return None
         phase, t0, cpu0, ann = cur
         tls.open = None
-        end = time.monotonic()
-        tls.cpu_s += time.thread_time() - cpu0
-        tls.wall_s += end - t0
+        end = time.monotonic() if at is None else at
+        if cpu0 is not None:
+            tls.cpu_s += time.thread_time() - cpu0
+            tls.wall_s += end - t0
         ann.__exit__(None, None, None)
         tls.phases.append((phase, t0, end))
+        return end
 
     def host_add(self, phase: str, start: float, end: float) -> None:
         """A phase the caller timed itself (`park`, `result_wait`)."""
@@ -295,15 +320,16 @@ class EvalTracer:
         if phases is not None:
             phases.append((phase, start, end))
 
-    def host_flush(self, trace_id: str) -> None:
-        """Disarm the calling thread and record what it gathered: the
-        `eval.phase.<name>_ms` histograms, the eval's spans, and the
-        phases' CPU and wall time into `sched.phase_cpu_ms` /
-        `sched.phase_wall_ms`."""
+    def host_flush(self, trace_id: str,
+                   end: Optional[float] = None) -> None:
+        """Disarm the calling thread, closing its open phase at `end`,
+        and record what it gathered: the `eval.phase.<name>_ms`
+        histograms, the eval's spans, and the unblocked phases' CPU and
+        wall time into `sched.phase_cpu_ms` / `sched.phase_wall_ms`."""
         tls = self._tls
         if getattr(tls, "phases", None) is None:
             return
-        self.host_end()
+        self.host_end(end)
         phases, tls.phases = tls.phases, None
         if not phases:
             return
@@ -327,7 +353,9 @@ class EvalTracer:
 
     def get(self, trace_id: str) -> Optional[Dict]:
         """Ordered span view: offsets are seconds since the trace's
-        enqueue anchor (monotonic deltas stamped onto a wall anchor)."""
+        enqueue anchor (monotonic deltas stamped onto a wall anchor),
+        the intervals of the fused dispatches the eval rode joined in
+        from `timeline` where one is set."""
         with self._lock:
             tr = self._traces.get(trace_id)
             if tr is None:
@@ -335,6 +363,9 @@ class EvalTracer:
             spans = [dict(s) for s in tr.spans]
             anchor_mono = tr.mono_anchor
             anchor_wall = tr.wall_anchor
+        if self.timeline is not None:
+            spans.extend({"phase": p, "start": s, "end": e}
+                         for p, s, e in self.timeline.eval_spans(trace_id))
         spans.sort(key=lambda s: (s["start"], s["end"]))
         out = []
         for s in spans:

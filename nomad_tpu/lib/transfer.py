@@ -319,6 +319,7 @@ class DispatchTimeline:
                upload: Optional[Tuple[float, float]] = None,
                speculative: bool = False,
                traces: Optional[List[str]] = None,
+               evals: Optional[List[Optional[str]]] = None,
                launch_end: Optional[float] = None) -> int:
         """Append a dispatch record at kernel launch; returns its seq.
         `pack`/`upload`/`view` are monotonic (start, end) intervals —
@@ -326,6 +327,9 @@ class DispatchTimeline:
         between pack and view (zero-length when absent), kept as its
         own phase so the upload cost lands in a named bucket instead
         of leaking into bubble_ms.
+
+        `evals` are the ids of the evals whose programs ride the
+        dispatch: `eval_spans` joins the record into their traces.
 
         `speculative` marks a dispatch launched against the predicted
         post-commit view (ISSUE 15); its outcome arrives later via
@@ -357,6 +361,7 @@ class DispatchTimeline:
                 # this dispatch — ties the timeline record into the
                 # cross-process trace tree (lib/tracectx.py).
                 "traces": [t for t in (traces or []) if t],
+                "evals": frozenset(e for e in (evals or ()) if e),
             }
             self._ring.append(rec)
             self._finalize_locked(seq)
@@ -463,6 +468,26 @@ class DispatchTimeline:
             reg.inc("pipeline.spec_certified"
                     if outcome == "certified"
                     else "pipeline.spec_rolled_back")
+
+    def eval_spans(self, eval_id: str) -> List[Tuple[str, float, float]]:
+        """(phase, start, end) of the `pack`, `delta_apply` (the view's
+        resolve) and `kernel` (launch → first read, once read) intervals
+        of every retained dispatch that eval `eval_id` rode: recorded
+        once a dispatch, joined into the eval's trace on query
+        (lib/trace.py `EvalTracer.get`)."""
+        out: List[Tuple[str, float, float]] = []
+        with self._cv:
+            for rec in self._ring:
+                if eval_id not in rec["evals"]:
+                    continue
+                if rec["pack_end"] > rec["pack_start"]:
+                    out.append(("pack", rec["pack_start"], rec["pack_end"]))
+                out.append(("delta_apply", rec["view_start"],
+                            rec["view_end"]))
+                if rec["kernel_end"] is not None:
+                    out.append(("kernel", rec["kernel_start"],
+                                rec["kernel_end"]))
+        return out
 
     def _find_locked(self, seq: int) -> Optional[dict]:
         # recent seqs live at the right end; scan backwards

@@ -294,6 +294,13 @@ class PlanApplier:
         self._ctr = {k: self.metrics.counter(f"plan_apply.{k}")
                      for k in self.STAT_KEYS}
         self._apply_ms = self.metrics.histogram("plan_apply.apply_ms")
+        # where a plan's time at the commit point goes (ISSUE 38): from
+        # `submit_plan` to the mutex (the inline attempt, the queue and
+        # the mutex), the verify loop under the store's mutation lock,
+        # the commit under it again; lock waits included in each
+        self._wait_ms = self.metrics.histogram("plan_apply.wait_ms")
+        self._verify_ms = self.metrics.histogram("plan_apply.verify_ms")
+        self._commit_ms = self.metrics.histogram("plan_apply.commit_ms")
         #: partial / applied — the server-side twin of the bench tail's
         #: `e2e_plan_partial_rate` (optimistic-concurrency cost), always
         #: exposed (ISSUE 13)
@@ -368,6 +375,11 @@ class PlanApplier:
         # must not let two workers commit plans for the same eval.
         t0 = time.perf_counter()
         wall0 = time.time()
+        # the mutex is held from here: stamped by the submitting worker
+        # (EvalContext.submit_plan) on its monotonic clock, host-local
+        submitted = getattr(plan, "submitted_at", None)
+        if submitted is not None:
+            self._wait_ms.add_sample(max(time.monotonic() - submitted, 0.0) * 1e3)
         if self.broker is not None and plan.eval_token:
             if not self.broker.outstanding(plan.eval_id, plan.eval_token):
                 self._ctr["stale_token"].inc()
@@ -395,7 +407,8 @@ class PlanApplier:
         lock = (self.state.mutation_lock()
                 if hasattr(self.state, "mutation_lock")
                 else contextlib.nullcontext())
-        with lock:
+        t_verify = time.perf_counter()
+        with host_span("plan_verify"), lock:
             # verify against the LIVE store (not a snapshot): the mutation
             # lock already guarantees internal consistency, and a full
             # StateSnapshot copy per plan (~0.6 ms at 10K allocs) was the
@@ -417,6 +430,7 @@ class PlanApplier:
                     self._ctr["rejected_nodes"].inc()
                     if reason == REASON_DEVICES:
                         self._ctr["rejected_devices"].inc()
+        self._verify_ms.add_sample((time.perf_counter() - t_verify) * 1e3)
         if partial and plan.all_at_once:
             # all-at-once plans commit nothing on any failure — including the
             # stops, or destructive updates would halt services with no
@@ -469,7 +483,8 @@ class PlanApplier:
             # the rows whose placements verification dropped
             rej_rows = [r for r in (cl.row_of.get(nid) for nid in rejected)
                         if r is not None] if rejected else None
-            with self.state.mutation_lock():
+            t_commit = time.perf_counter()
+            with host_span("plan_commit"), self.state.mutation_lock():
                 v_lo = cl.version
                 self.state.upsert_plan_results(plan, result)
                 cl.mark_plan_window(
@@ -478,7 +493,10 @@ class PlanApplier:
                     token=getattr(plan, "carry_token", None),
                     rejected_rows=rej_rows)
         else:
-            self.state.upsert_plan_results(plan, result)
+            t_commit = time.perf_counter()
+            with host_span("plan_commit"):
+                self.state.upsert_plan_results(plan, result)
+        self._commit_ms.add_sample((time.perf_counter() - t_commit) * 1e3)
         result.alloc_index = self.state.index.value
         if partial:
             result.refresh_index = self.state.index.value

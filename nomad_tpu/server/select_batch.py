@@ -181,6 +181,41 @@ class SpecGate:
                 self._cooldown = self.COOLDOWN
 
 
+#: how an offer of a speculative launch to the successor batch ended
+#: (`SelectCoordinator._offer_spec`): launched, or refused because there
+#: was no successor, speculation was off for it, the gate was disarmed,
+#: its rendezvous missed the park deadline, a request was bound to another
+#: cluster, the program table refused the pack, there was no chain view,
+#: or an error
+SPEC_OFFER_OUTCOMES = ("launched", "no_successor", "off", "gate",
+                       "rendezvous", "binding", "pack", "view", "error")
+
+
+class SpecOfferMeter:
+    """The registry side of a coordinator's counted offers: `spec.offers`,
+    one `spec.offer.<outcome>` a SPEC_OFFER_OUTCOMES entry (they sum to
+    `spec.offers`) and the histogram `spec.offer_ms`, resolved once;
+    `count` moves what one coordinator counted, once a batch."""
+
+    def __init__(self, registry) -> None:
+        self._offers = registry.counter("spec.offers")
+        self._outcomes = {o: registry.counter("spec.offer." + o)
+                          for o in SPEC_OFFER_OUTCOMES}
+        self._offer_ms = registry.histogram("spec.offer_ms")
+
+    def count(self, coord: "SelectCoordinator") -> None:
+        for ms in coord.spec_offer_ms:
+            self._offer_ms.add_sample(ms)
+        n = 0
+        for outcome, k in coord.spec_offers.items():
+            self._outcomes[outcome].inc(k)
+            n += k
+        if n:
+            self._offers.inc(n)
+        coord.spec_offers = {}
+        coord.spec_offer_ms = []
+
+
 #: cluster → SpecGate (weak: gates die with their cluster)
 _SPEC_GATES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _SPEC_GATES_LOCK = threading.Lock()
@@ -294,8 +329,9 @@ class SelectCoordinator:
         # ledger — the attribution test cross-checks the two.
         self.stats = {"dispatches": 0, "programs": 0, "batched": 0,
                       "pack_bytes": 0}
-        #: eval-lifecycle tracer + program-order → eval-id map (worker
-        #: fills trace_ids in start_batch) for per-eval pack/kernel spans
+        #: eval-lifecycle tracer (the waiters' host phases) + program-
+        #: order → eval-id map (worker fills trace_ids in start_batch),
+        #: which each dispatch's timeline record carries
         self.tracer = tracer
         self.trace_ids: Dict[int, str] = {}
         #: program-order → broker conflict-group id (worker fills in
@@ -319,6 +355,13 @@ class SelectCoordinator:
         #: consumed at the top of run())
         self._spec: Optional[dict] = None
         self._ran = False
+        #: this coordinator's offers of a speculative launch to its
+        #: successor (`_offer_spec`), made on the worker's thread: outcome
+        #: (SPEC_OFFER_OUTCOMES) → count, and each offer's wall in ms —
+        #: plain values, added to the registry once a batch
+        #: (`SpecOfferMeter.count`, from `Worker.finish_batch`)
+        self.spec_offers: Dict[str, int] = {}
+        self.spec_offer_ms: List[float] = []
         #: server metrics registry for the wave.* instruments (None for
         #: bare coordinators in tests — wave stats still land in .stats)
         self.registry = registry
@@ -350,9 +393,9 @@ class SelectCoordinator:
         until the fused chain actually lands."""
         req = _SelectReq(arrays_fn, params, n_place, order, explain)
         tracer = self.tracer
-        if tracer is not None:
-            tracer.host_end()  # prepare (or the last group's build)
-        t_in = time.monotonic()
+        # park starts where prepare (or the last group's build) ends
+        t_in = (tracer.host_end() if tracer is not None else None) \
+            or time.monotonic()
         with self._cv:
             self._parked.append(req)
             self._cv.notify_all()
@@ -365,9 +408,10 @@ class SelectCoordinator:
         with host_span("result_wait"):
             out = holder.resolve()
         if tracer is not None:
+            t_out = time.monotonic()
             tracer.host_add("park", t_in, t_set)
-            tracer.host_add("result_wait", t_set, time.monotonic())
-            tracer.host_begin("plan_build")
+            tracer.host_add("result_wait", t_set, t_out)
+            tracer.host_begin("plan_build", at=t_out)
         sel, score, feas, fit = out[:4]
         # a fused dispatch runs with explain when ANY program asked —
         # but a program that opted out must not receive attribution it
@@ -514,7 +558,7 @@ class SelectCoordinator:
                 cluster = getattr(owner, "cluster", None)
                 if cluster is not None and get_active_mesh() is None:
                     if self._dispatch_table(reqs, cluster, want_ex, led,
-                                            _kernel_done):
+                                            _kernel_done) == "launched":
                         continue
             if len(reqs) == 1:
                 r = reqs[0]
@@ -522,7 +566,6 @@ class SelectCoordinator:
                 with led.scope() as moved, host_span("view"):
                     arrays = resolved.get(key) or r.arrays_fn()
                 tk = time.monotonic()
-                self._trace([r], "delta_apply", tv, tk)
                 (p,), m = pad_params([r.params])
                 with host_span("launch"):
                     res = place_task_group_jit(arrays, p, m,
@@ -538,7 +581,8 @@ class SelectCoordinator:
                         pack=(tv, tv), view=(tv, tk),
                         kernel_start=tk, launch_end=tl,
                         transfer_bytes=moved[0], transfer_count=moved[1],
-                        traces=self._dist_traces([r]))
+                        traces=self._dist_traces([r]),
+                        evals=self._eval_ids([r]))
                 dev = (res.sel_idx, res.sel_score,
                        res.nodes_feasible, res.nodes_fit)
                 if res.explain is not None:
@@ -563,7 +607,6 @@ class SelectCoordinator:
                 # not measured on an attached chip)
                 ibuf, fbuf, ubuf, spec = pack_params(stacked)
             t1 = time.monotonic()
-            self._trace(reqs, "pack", t0, t1)
             # Everything device-touching from here to launch runs under
             # the transfer guard (NOMAD_TPU_TRANSFER_GUARD): transfers
             # on this path are all EXPLICIT and ledger-accounted, so a
@@ -587,7 +630,6 @@ class SelectCoordinator:
                 with led.scope() as moved, host_span("view"):
                     arrays = resolved.get(key) or reqs[0].arrays_fn()
                 tv = time.monotonic()
-                self._trace(reqs, "delta_apply", t2, tv)
                 with host_span("launch"):
                     dev_out = place_packed_chain(
                         arrays, dibuf, dfbuf, dubuf, spec, m,
@@ -601,7 +643,8 @@ class SelectCoordinator:
                     kernel_start=tv, launch_end=tl,
                     transfer_bytes=nb + moved[0],
                     transfer_count=3 + moved[1],
-                    traces=self._dist_traces(reqs))
+                    traces=self._dist_traces(reqs),
+                    evals=self._eval_ids(reqs))
             out = _BatchOut(dev_out, _kernel_done(reqs, tv, seq))
             # release waiters at LAUNCH: each materializes the shared
             # output as the chain lands and rolls straight into its plan
@@ -636,7 +679,6 @@ class SelectCoordinator:
                     # certification reads this to account the wasted
                     # share of a rolled-back speculative kernel
                     spec_state["kernel_ms"] = (t_end - t_launch) * 1e3
-                self._trace(reqs, "kernel", t_launch, t_end)
                 # the device→host fetch happened HERE (np.asarray on the
                 # first-resolving waiter's thread): credit it to the
                 # dispatch's timeline record + the fetch ledger site
@@ -696,16 +738,18 @@ class SelectCoordinator:
         return _kernel_done
 
     def _dispatch_table(self, reqs, cluster, want_ex, led,
-                        _kernel_done, spec: bool = False) -> bool:
+                        _kernel_done, spec: bool = False) -> str:
         """Dispatch one cluster group through the device program table:
         as ONE chain (`place_table_chain`), or — when the requests span
         ≥2 disjoint broker conflict groups — as a WAVE of parallel lanes
         (`place_table_wave`: the program axis is [lanes, lane length]
         and the kernel's carry is the per-row fold of the lane carries;
         `_table_layout` has the layout, the only thing that differs).
-        Returns False untouched (no span, no stats, no lease, no side
-        effects on reqs) when the group can't ride the table — the
-        caller then runs the legacy transport.
+        Returns "launched", or why the group can't ride the table,
+        untouched (no span, no stats, no lease, no side effects on
+        reqs): "pack" (the table's prepare or commit refused it) or
+        "view" (a speculative launch found nothing to predict from) —
+        the caller then runs the legacy transport.
 
         `spec` (ISSUE 15): resolve the view from the speculative chain
         (predicted post-commit state) instead of the committed cache,
@@ -725,14 +769,14 @@ class SelectCoordinator:
             reqs, params_list, idxs, shape, lanes_idx = _table_layout(lanes)
             prep = table.prepare(params_list)
         if prep is None:
-            return False
+            return "pack"
         t1 = time.monotonic()
         with guard_scope():
             import jax.numpy as jnp
 
             com = table.commit(prep, led)
             if com is None:
-                return False  # caps flush raced this prepare — the
+                return "pack"  # caps flush raced this prepare — the
                 # legacy fallback re-packs
             ti, tf, tu, ins_nb, ins_count = com
             dyn = (prep.rows, prep.dyn_i, prep.dyn_f, prep.dyn_u)
@@ -755,7 +799,7 @@ class SelectCoordinator:
                     if spec:
                         arrays = stack_mod.spec_chain_view(cluster, token)
                         if arrays is None:
-                            return False  # nothing predictable — the
+                            return "view"  # nothing predictable — the
                             # caller re-parks and the batch dispatches
                             # normally once the predecessor commits
                     else:
@@ -763,12 +807,10 @@ class SelectCoordinator:
                 # the launch is certain from here: only now is the pack
                 # counted, so a miss above leaves nothing for the real
                 # dispatch that follows it to count twice
-                self._trace(reqs, "pack", t0, t1)
                 if len(reqs) > 1:
                     self.stats["batched"] += len(reqs)
                 self.stats["pack_bytes"] += nb + ins_nb
                 tv = time.monotonic()
-                self._trace(reqs, "delta_apply", t2, tv)
                 place = place_table_chain if shape is None \
                     else place_table_wave
                 with host_span("launch"):
@@ -805,7 +847,7 @@ class SelectCoordinator:
                  speculative=spec))
 
     def _launched(self, reqs, idxs, cluster, token, arrays, out, carry,
-                  spec_state, _kernel_done, record: dict) -> bool:
+                  spec_state, _kernel_done, record: dict) -> str:
         """The `release` interval of a table dispatch, chain (`idxs`
         None) or wave (`idxs` = each request's [lane, position] slot):
         timeline record, carry note, lazy holder, then the waiters are
@@ -816,9 +858,10 @@ class SelectCoordinator:
 
         spec = spec_state is not None
         with host_span("release"):
+            evals = self._eval_ids(reqs)
             seq = 0
             if self.timeline is not None:
-                seq = self.timeline.commit(**record)
+                seq = self.timeline.commit(evals=evals, **record)
             # carry note: once this dispatch's outputs land and its
             # plans commit, the next refresh may adopt the chain's
             # (used, dyn_free) carry instead of re-uploading the
@@ -832,7 +875,6 @@ class SelectCoordinator:
             hbm = default_hbm()
             hbm.track("select_batch.carry", carry[0])
             hbm.track("select_batch.carry", carry[1])
-            evals = [self.trace_ids.get(r.order) for r in reqs]
             stop_rows = set()
             for r in reqs:
                 p = r.params
@@ -861,7 +903,7 @@ class SelectCoordinator:
                 if self.timeline is not None:
                     self.timeline.released(seq, time.monotonic(),
                                            held=True)
-                return True
+                return "launched"
             self._release(holder, seq,
                           [(r, j if idxs is None else idxs[j], token)
                            for j, r in enumerate(reqs)])
@@ -869,7 +911,7 @@ class SelectCoordinator:
         # the NEXT batch a speculative launch against it, overlapping
         # this batch's plan commits with its successor's kernel
         self._offer_spec(cluster)
-        return True
+        return "launched"
 
     def _wave_lanes(self, reqs) -> List[list]:
         """Partition a cluster group's requests into wave lanes from the
@@ -908,16 +950,22 @@ class SelectCoordinator:
         batch a speculative launch against it — the successor's kernel
         then queues right behind this one on device while this batch's
         plans commit on the waiter threads. Speculation must never fail
-        the real path: any error just means no speculation."""
-        succ = self.successor
-        if succ is None or succ is self:
-            return
-        try:
-            succ.try_spec_launch(cluster)
-        except Exception:  # noqa: BLE001 — speculative only
-            pass
+        the real path: any error just means no speculation. Every offer
+        is counted under its outcome (SPEC_OFFER_OUTCOMES), with its
+        wall on this thread, the rendezvous wait included."""
+        t0 = time.monotonic()
+        outcome = "no_successor"
+        with host_span("spec_offer"):
+            succ = self.successor
+            if succ is not None and succ is not self:
+                try:
+                    outcome = succ.try_spec_launch(cluster)
+                except Exception:  # noqa: BLE001 — speculative only
+                    outcome = "error"
+        self.spec_offers[outcome] = self.spec_offers.get(outcome, 0) + 1
+        self.spec_offer_ms.append((time.monotonic() - t0) * 1e3)
 
-    def try_spec_launch(self, cluster) -> bool:
+    def try_spec_launch(self, cluster) -> str:
         """Speculatively dispatch this coordinator's round-1 batch
         against the predicted post-commit view of `cluster`. Called on
         the PREDECESSOR batch's coordinator thread (the shared worker
@@ -926,16 +974,19 @@ class SelectCoordinator:
         schedulers are compiling on the pool); aborts — leaving the
         batch parked for the normal path — unless every live thread is
         parked, every request is bound to `cluster`, the adaptive gate
-        is armed, and the chain has a carry to predict from."""
+        is armed, and the chain has a carry to predict from. Returns
+        "launched" or why not: "off" (speculation disabled, this batch
+        already ran or launched, a mesh), "gate", "rendezvous",
+        "binding", "pack" or "view" (`_dispatch_table`)."""
         if not spec_enabled() or self._ran or self._spec is not None:
-            return False
+            return "off"
         from ..parallel.mesh import get_active_mesh
 
         if get_active_mesh() is not None:
-            return False
+            return "off"
         gate = _gate_for(cluster)
         if not gate.armed():
-            return False
+            return "gate"
         deadline = time.time() + _spec_park_s()
         with self._cv:
             while True:
@@ -946,29 +997,29 @@ class SelectCoordinator:
                     # the wait was paid for nothing — consecutive
                     # misses disarm the gate (see SpecGate)
                     gate.record_miss()
-                    return False
+                    return "rendezvous"
                 self._cv.wait(min(remaining, 0.01))
             batch = list(self._parked)
             for r in batch:
                 owner = getattr(r.arrays_fn, "__self__", None)
                 if getattr(owner, "cluster", None) is not cluster:
-                    return False
+                    return "binding"
             self._parked = []
         batch.sort(key=lambda r: r.order)
-        ok = False
+        outcome = "error"
         try:
-            ok = self._dispatch_spec(batch, cluster)
+            outcome = self._dispatch_spec(batch, cluster)
         finally:
-            if not ok:
+            if outcome != "launched":
                 gate.record_miss()
                 # nothing launched: re-park untouched for run()'s
                 # normal dispatch
                 with self._cv:
                     self._parked = batch + self._parked
                     self._cv.notify_all()
-        return ok
+        return outcome
 
-    def _dispatch_spec(self, batch, cluster) -> bool:
+    def _dispatch_spec(self, batch, cluster) -> str:
         from ..lib.transfer import default_ledger
 
         led = default_ledger()
@@ -977,12 +1028,12 @@ class SelectCoordinator:
         # lease release included); only the dispatch entry differs
         _kernel_done = self._kernel_done_factory(led)
         want_ex = any(r.explain for r in batch)
-        if not self._dispatch_table(batch, cluster, want_ex, led,
-                                    _kernel_done, spec=True):
-            return False
-        self.stats["dispatches"] += 1
-        self.stats["programs"] += len(batch)
-        return True
+        outcome = self._dispatch_table(batch, cluster, want_ex, led,
+                                       _kernel_done, spec=True)
+        if outcome == "launched":
+            self.stats["dispatches"] += 1
+            self.stats["programs"] += len(batch)
+        return outcome
 
     def _certify_spec(self, spec) -> None:
         """Commit-time certification: the predecessor batch's plans have
@@ -1085,17 +1136,11 @@ class SelectCoordinator:
         n = fp.shape[0]
         return any(r >= n or bool(fp[r]) for r in stale)
 
-    def _trace(self, reqs: List[_SelectReq], phase: str,
-               start: float, end: float) -> None:
-        """Per-eval span for a fused phase: every program in the batch
-        rode the same host pack / device dispatch, so each gets the
-        batch's interval (monotonic clock)."""
-        if self.tracer is None:
-            return
-        for r in reqs:
-            tid = self.trace_ids.get(r.order)
-            if tid is not None:
-                self.tracer.record(tid, phase, start=start, end=end)
+    def _eval_ids(self, reqs: List[_SelectReq]) -> List[Optional[str]]:
+        """The ids of the evals whose programs ride a dispatch: on its
+        timeline record, which `EvalTracer.get` joins into each of their
+        traces (one record a dispatch, not one a program and phase)."""
+        return [self.trace_ids.get(r.order) for r in reqs]
 
     def _dist_traces(self, reqs: List[_SelectReq]) -> List[str]:
         """Distributed trace ids (lib/tracectx.py) of the evals riding a

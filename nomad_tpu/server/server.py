@@ -259,6 +259,8 @@ class Server:
         # /v1/scheduler/timeline + `operator timeline` + bench's
         # e2e_pipeline tail
         self.timeline = DispatchTimeline(self.metrics)
+        # an eval's trace shows the intervals of the dispatches it rode
+        self.tracer.timeline = self.timeline
         self.broker = EvalBroker(nack_timeout=self.config.nack_timeout,
                                  metrics=self.metrics, tracer=self.tracer,
                                  footprint_fn=self._eval_footprint)

@@ -72,19 +72,24 @@ class EvalContext:
             plan.trace_id = self.eval.trace_id
             plan.trace_span_id = self.eval.trace_span_id
         tracer = getattr(self.server, "tracer", None)
-        if tracer is not None:
-            tracer.host_end()  # plan_build (or prepare)
-        t0 = time.monotonic()
+        # the end of plan_build (or prepare) is where plan_apply starts
+        t0 = (tracer.host_end() if tracer is not None else None) \
+            or time.monotonic()
+        # host-local (no wire, no raft entry): the applier's
+        # `plan_apply.wait_ms` runs from here to its mutex
+        plan.submitted_at = t0
         refreshed = None
         try:
             result, refreshed = self._submit_plan(plan)
             return result, refreshed
         finally:
             if tracer is not None:
-                tracer.record(self.eval.id, "plan_apply", start=t0)
-                if refreshed is not None:
-                    # partial commit: the scheduler reconciles again
-                    tracer.host_begin("prepare")
+                t1 = time.monotonic()
+                tracer.record(self.eval.id, "plan_apply", start=t0, end=t1)
+                # a partial commit: the scheduler reconciles again;
+                # otherwise what is left is the eval's status update
+                tracer.host_begin("prepare" if refreshed is not None
+                                  else "finish", at=t1)
 
     def _submit_plan(self, plan: Plan
                      ) -> Tuple[PlanResult, Optional[object]]:
@@ -152,6 +157,10 @@ class Worker:
         self.metrics: MetricsRegistry = getattr(
             server, "metrics", None) or MetricsRegistry()
         self.tracer = getattr(server, "tracer", None)
+        from .select_batch import SpecOfferMeter
+
+        #: each batch's counted offers of a speculative launch
+        self._spec_offers = SpecOfferMeter(self.metrics)
         #: persistent scheduler-thread pool for the batch path (spawning
         #: B threads per batch measured ~0.3 ms each — a real tax at
         #: millisecond-scale evals). Guarded by _pool_lock: created by
@@ -331,14 +340,15 @@ class Worker:
                     tracer.host_arm()
             t0 = time.monotonic()
             if tracer is not None:
-                tracer.host_begin("prepare")
+                tracer.host_begin("prepare", at=t0)
             try:
                 sched.process(eval)
             finally:
+                t1 = time.monotonic()
                 if tracer is not None:
-                    tracer.host_flush(eval.id)
+                    tracer.host_flush(eval.id, end=t1)
             if tracer is not None:
-                tracer.record(eval.id, "schedule", start=t0)
+                tracer.record(eval.id, "schedule", start=t0, end=t1)
             if eval.type == "_core":
                 # Core schedulers don't drive update_eval themselves —
                 # a successful pass completes the eval here.
@@ -457,6 +467,7 @@ class Worker:
         coord.run()
         for f in futs:
             f.result()
+        self._spec_offers.count(coord)
         prefix = f"worker.{self.id}.batch."
         for k, v in coord.stats.items():
             self.metrics.inc(prefix + k, v)

@@ -371,7 +371,7 @@ class TestLeakGate:
         threads, res2 = tsp._start_parked(
             cl, [tsp._dc_job("dc1", cpu=250),
                  tsp._dc_job("dc2", cpu=250)], coord2)
-        assert coord2.try_spec_launch(cl)
+        assert coord2.try_spec_launch(cl) == "launched"
         tpt._commit_round(cl, res1, ["a", "b"])
         dc1_node = next(nid for nid in cl.row_of
                         if cl.nodes[nid].datacenter == "dc1")

@@ -485,7 +485,8 @@ class TestTableLayout:
 
 
 class _SpanLog:
-    """The tracer half `_trace` / `_dist_traces` use."""
+    """The tracer half a coordinator uses: no dispatch records a span in
+    it (the timeline's record holds the intervals, once a dispatch)."""
 
     def __init__(self):
         self.phases = []
@@ -543,10 +544,15 @@ class TestOneTableDispatchBody:
         from nomad_tpu.scheduler import stack as stack_mod
 
         ok, coord, reqs, cl, reg = _table_dispatch(wave)
-        assert ok is True
+        assert ok == "launched"
         assert coord.stats["batched"] == 2 and coord.stats["pack_bytes"] > 0
-        assert sorted(coord.tracer.phases) == sorted(
-            (e, p) for e in ("e0", "e1") for p in ("pack", "delta_apply"))
+        # the dispatch's intervals are on its record, once, with the ids
+        # of the evals that rode it — none was copied to each eval
+        assert coord.tracer.phases == []
+        for e in ("e0", "e1"):
+            assert [p for p, _s, _e in coord.timeline.eval_spans(e)] == \
+                ["pack", "delta_apply"]
+        assert coord.timeline.eval_spans("e2") == []
         holder, _i, token = reqs[0].out
         assert all(r.event.is_set() and r.out[0] is holder
                    and r.out[2] == token for r in reqs)
@@ -566,6 +572,10 @@ class TestOneTableDispatchBody:
         assert (rec["programs"], rec["batched"], rec["speculative"]) == \
             (2, True, False)
         assert rec["transfer_count"] >= 4 + len(holder.resolve())
+        # read: the kernel's interval joins the evals' traces too
+        for e in ("e0", "e1"):
+            assert [p for p, _s, _e in coord.timeline.eval_spans(e)] == \
+                ["pack", "delta_apply", "kernel"]
         for k in ("pack_ms", "upload_ms", "view_ms", "host_ms", "kernel_ms",
                   "launch_ms", "release_ms", "wake_ms", "fetch_block_ms"):
             assert rec[k] is not None and rec[k] >= 0.0, k
@@ -575,11 +585,13 @@ class TestOneTableDispatchBody:
         assert c.get("wave.dispatches", 0) == (1 if wave else 0)
         assert c.get("wave.programs", 0) == (2 if wave else 0)
 
-    @pytest.mark.parametrize("miss", ["prepare", "commit", "spec-view"])
+    @pytest.mark.parametrize("miss,why", [("prepare", "pack"),
+                                          ("commit", "pack"),
+                                          ("spec-view", "view")])
     @pytest.mark.parametrize("wave", [False, True], ids=["chain", "wave"])
-    def test_miss_returns_false_untouched(self, wave, miss, monkeypatch):
+    def test_miss_says_why_untouched(self, wave, miss, why, monkeypatch):
         ok, coord, reqs, cl, reg = _table_dispatch(wave, monkeypatch, miss)
-        assert ok is False
+        assert ok == why
         assert coord.tracer.phases == []
         assert coord.stats == {"dispatches": 0, "programs": 0,
                                "batched": 0, "pack_bytes": 0}

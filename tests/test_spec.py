@@ -519,7 +519,8 @@ class TestSpecDispatchParity:
                              1: _dc_mask(cl, "dc2")}
         if speculative:
             threads, res2 = _start_parked(cl, r2_jobs, coord2)
-            assert coord2.try_spec_launch(cl), "speculation never armed"
+            assert coord2.try_spec_launch(cl) == "launched", \
+                "speculation never armed"
             tpt._commit_round(cl, res1, r1_ids)
             if foreign_node is not None:
                 cl.upsert_alloc(_foreign_alloc(foreign_node))
@@ -575,7 +576,7 @@ class TestSpecDispatchParity:
                                        eval_ids=["a", "b"])
         coord2 = SelectCoordinator(registry=MetricsRegistry())
         threads, res2 = _start_parked(cl, [_dc_job("dc1")], coord2)
-        assert not coord2.try_spec_launch(cl)
+        assert coord2.try_spec_launch(cl) == "off"
         tpt._commit_round(cl, res1, ["a", "b"])
         coord2.run()
         for t in threads:
@@ -593,7 +594,7 @@ class TestSpecDispatchParity:
         _c1, res1 = tpt._run_round(cl, r1_jobs, eval_ids=["a", "b"])
         coord2 = SelectCoordinator(registry=MetricsRegistry())
         threads, res2 = _start_parked(cl, [_dc_job("dc1")], coord2)
-        assert not coord2.try_spec_launch(cl)
+        assert coord2.try_spec_launch(cl) == "gate"
         tpt._commit_round(cl, res1, ["a", "b"])
         coord2.run()
         for t in threads:
@@ -623,7 +624,8 @@ def _drive_chain(cl, monkeypatch, k=3, reg=None):
         coord.footprints = {0: _dc_mask(cl, "dc1"),
                             1: _dc_mask(cl, "dc2")}
         threads, res = _start_parked(cl, jobs, coord)
-        assert coord.try_spec_launch(cl), f"round {n} never speculated"
+        assert coord.try_spec_launch(cl) == "launched", \
+            f"round {n} never speculated"
         tpt._commit_round(cl, prev_res, prev_ids)
         coord.run()
         for t in threads:
@@ -1084,3 +1086,101 @@ class TestSpecServerE2E:
         assert not c_on.get("spec.rolled_back", 0)
         assert st.get("partial", 0) == 0
         assert leases == 0
+
+
+class TestOfferOutcomes:
+    """Every offer of a speculative launch (`_offer_spec`) is counted
+    under the one outcome that ended it, with its wall; the meter moves
+    them into `spec.offers` + `spec.offer.<outcome>` (which sum to it) and
+    `spec.offer_ms` (ISSUE 38). One case forces each outcome; a refused
+    batch is left parked and its normal dispatch still places it."""
+
+    @pytest.mark.parametrize("outcome", [
+        "launched", "no_successor", "off", "gate", "rendezvous", "binding",
+        "pack", "view", "error"])
+    def test_the_outcome_is_counted(self, outcome, monkeypatch):
+        from nomad_tpu.server import select_batch as sb
+        from nomad_tpu.server.program_table import table_for
+
+        cl = _dc_cluster()
+        succ = SelectCoordinator(registry=MetricsRegistry())
+        pred = SelectCoordinator()
+        pred.successor = None if outcome == "no_successor" else succ
+        ids = ["a", "b"]
+        res1 = None
+        if outcome == "launched":
+            # a dispatch before it: the chain has a carry to predict from
+            _c1, res1 = tpt._run_round(
+                cl, [_dc_job("dc1"), _dc_job("dc2")], eval_ids=["r1a",
+                                                                 "r1b"])
+            succ.footprints = {0: _dc_mask(cl, "dc1"),
+                               1: _dc_mask(cl, "dc2")}
+        succ.trace_ids = dict(enumerate(ids))
+        parked = outcome not in ("no_successor", "off", "gate",
+                                 "rendezvous")
+        threads, res = [], {}
+        if parked:
+            on = _dc_cluster() if outcome == "binding" else cl
+            threads, res = _start_parked(
+                on, [_dc_job("dc1", cpu=250), _dc_job("dc2", cpu=250)],
+                succ)
+        if outcome == "off":
+            monkeypatch.setenv("NOMAD_TPU_SPECULATE", "0")
+        elif outcome == "gate":
+            g = sb._gate_for(cl)
+            for _ in range(SpecGate.MIN_SAMPLES):
+                g.record(True)
+        elif outcome == "rendezvous":
+            monkeypatch.setenv("NOMAD_TPU_SPEC_PARK_MS", "1")
+            succ.add_thread()   # a live thread that never parks
+        elif outcome == "pack":
+            monkeypatch.setattr(table_for(cl), "prepare", lambda pl: None)
+        elif outcome == "error":
+            def boom(*a, **k):
+                raise RuntimeError("forced")
+            monkeypatch.setattr(succ, "_dispatch_table", boom)
+
+        pred._offer_spec(cl)
+
+        assert pred.spec_offers == {outcome: 1}
+        assert len(pred.spec_offer_ms) == 1 and pred.spec_offer_ms[0] >= 0.0
+        assert (succ._spec is not None) == (outcome == "launched")
+        reg = MetricsRegistry()
+        sb.SpecOfferMeter(reg).count(pred)
+        c = reg.counters()
+        assert c["spec.offers"] == 1
+        assert {o: c[f"spec.offer.{o}"] for o in sb.SPEC_OFFER_OUTCOMES} \
+            == {o: float(o == outcome) for o in sb.SPEC_OFFER_OUTCOMES}
+        assert reg.histogram("spec.offer_ms").count == 1
+        assert pred.spec_offers == {} and pred.spec_offer_ms == []
+
+        monkeypatch.undo()
+        if outcome == "rendezvous":
+            succ.thread_done()
+        if res1 is not None:
+            tpt._commit_round(cl, res1, ["r1a", "r1b"])
+        succ.run()
+        for t in threads:
+            t.join(30.0)
+        assert not any(t.is_alive() for t in threads)
+        assert all(res[i][0][0] is not None for i in res) and \
+            len(res) == len(threads)
+        stack_mod.spec_chain_reset(cl)
+
+    def test_the_outcomes_of_many_offers_sum_to_the_offers(self):
+        from nomad_tpu.server import select_batch as sb
+
+        reg = MetricsRegistry()
+        meter = sb.SpecOfferMeter(reg)
+        coords = [SelectCoordinator() for _ in range(3)]
+        for n, coord in enumerate(coords):
+            for k in range(n + 2):
+                coord._offer_spec(None)   # no successor: counted all the same
+            coord.spec_offers["rendezvous"] = n
+            coord.spec_offer_ms.extend([30.0] * n)
+            meter.count(coord)
+        c = reg.counters()
+        assert c["spec.offers"] == sum(n + 2 + n for n in range(3)) == 12
+        assert sum(c[f"spec.offer.{o}"]
+                   for o in sb.SPEC_OFFER_OUTCOMES) == c["spec.offers"]
+        assert reg.histogram("spec.offer_ms").count == 12

@@ -223,13 +223,18 @@ class TestE2ETrace:
         assert fused, "no eval carried a kernel span despite batching"
         for t in fused:
             phases = [s["phase"] for s in t["spans"]]
-            assert "pack" in phases
+            assert "pack" in phases and "delta_apply" in phases
             # fused phases happen inside the schedule window
             by = {s["phase"]: s for s in t["spans"]}
             assert by["pack"]["start_s"] >= by["schedule"]["start_s"]
         hists = snap["histograms"]
-        assert hists["eval.phase.kernel_ms"]["count"] >= len(fused)
-        assert hists["eval.phase.pack_ms"]["count"] >= len(fused)
+        # recorded once a dispatch (its timeline record), joined into
+        # each rider's trace: no per-eval copy of the interval
+        for gone in ("pack", "delta_apply", "kernel"):
+            assert f"eval.phase.{gone}_ms" not in hists
+        assert 1 <= hists["pipeline.kernel_ms"]["count"] <= len(fused)
+        assert hists["pipeline.pack_ms"]["count"] \
+            == snap["counters"]["pipeline.dispatches"]
 
 
 class TestHttpSurfaces:
@@ -270,6 +275,45 @@ class TestHttpSurfaces:
         with pytest.raises(ApiError) as ei:
             api.evaluation_trace("does-not-exist")
         assert ei.value.code == 404
+
+    def test_http_times_each_put_and_post_and_no_get(self, agent):
+        """`http.requests` / `http.handler_ms` / `http.cpu_ms` (ISSUE 38):
+        every PUT or POST that returns a response, an error too; no
+        GET, the event stream's push and long-poll pages among them."""
+        import http.client
+
+        from nomad_tpu.lib.metrics import default_registry
+        from nomad_tpu.api import ApiError
+
+        a, api = agent
+        reg = default_registry()
+        n, h, cpu = (reg.counter("http.requests"),
+                     reg.histogram("http.handler_ms"),
+                     reg.counter("http.cpu_ms"))
+        n0, h0, cpu0 = n.value, h.count, cpu.value
+        api.nodes()
+        api.metrics()
+        api.events(index=0, wait=0.1)
+        conn = http.client.HTTPConnection(*a.http_addr, timeout=10)
+        conn.request("GET", "/v1/event/stream?stream=1&index=0"
+                     "&heartbeat=0.05")
+        res = conn.getresponse()
+        assert res.status == 200 and res.fp.readline()  # one frame
+        conn.close()
+        assert (n.value, h.count) == (n0, h0)
+        self._run_job(api)                         # PUT /v1/jobs
+        with pytest.raises(ApiError):              # PUT, answered 404
+            api._request("PUT", "/v1/no-such-route", body={})
+        conn = http.client.HTTPConnection(*a.http_addr, timeout=10)
+        conn.request("POST", "/v1/no-such-route", body=b"{}",
+                     headers={"Content-Type": "application/json"})
+        assert conn.getresponse().status == 404
+        conn.close()
+        # counted after the response is written: the client may read it
+        # first
+        assert _wait(lambda: n.value - n0 == 3 and h.count - h0 == 3,
+                     timeout=10.0), (n.value - n0, h.count - h0)
+        assert h.sum > 0.0 and cpu.value >= cpu0
 
     def test_metrics_carries_phase_histograms(self, agent):
         a, api = agent
