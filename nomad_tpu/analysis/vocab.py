@@ -214,6 +214,9 @@ PROM_REQUIRED = frozenset({
     "nomad_spec_offer_error",
     "nomad_plan_apply_wait_ms", "nomad_plan_apply_verify_ms",
     "nomad_plan_apply_commit_ms",
+    # a plan's placements verified and committed in one pass, and all of
+    # them: the benchmark's `plan_array_share`
+    "nomad_plan_apply_array_allocs", "nomad_plan_apply_placed_allocs",
 })
 
 #: the raft node's promised series (ISSUE 13) — exposed from the NODE's

@@ -101,9 +101,15 @@ class InMemState:
             e.create_index = e.modify_index
         self._evals[e.id] = e
 
-    def upsert_plan_results(self, plan: Plan, result: PlanResult) -> None:
+    def upsert_plan_results(self, plan: Plan, result: PlanResult,
+                            placed=None) -> int:
         """Apply a committed plan (reference state.UpsertPlanResults,
-        state_store.go:240): stops, preemptions, then placements."""
+        state_store.go:240): stops, preemptions, then placements.
+        `placed`: the rows the plan applier's verify read
+        (`ClusterTensors.placement_rows`), host-local — the journal and
+        the raft entry carry `result` alone (`wal._encode_args`), and a
+        replay reads the rows anew. Returns how many placements were
+        committed in one pass."""
         for allocs in result.node_update.values():
             for a in allocs:
                 existing = self._allocs.get(a.id)
@@ -123,9 +129,32 @@ class InMemState:
                     merged.desired_description = a.desired_description
                     merged.preempted_by_allocation = a.preempted_by_allocation
                     self.upsert_alloc(merged)
+        # placements of allocations the store does not hold yet gather
+        # into runs committed in one pass each (`_upsert_placements`); any
+        # other placement closes the run first, so every index and every
+        # `used` sum lands in plan order
+        n_run = 0
+        run: List[Allocation] = []
+        rows: List[int] = []
+        run_ids = set()
+        placement_row = self.cluster.placement_row
         for allocs in result.node_allocation.values():
             for a in allocs:
                 existing = self._allocs.get(a.id)
+                row = (placement_row(a) if existing is None
+                       and a.id not in run_ids else None)
+                if row is not None:
+                    if a.job is None:
+                        a.job = self._placed_job(a)
+                    run.append(a)
+                    rows.append(row)
+                    run_ids.add(a.id)
+                    continue
+                if run:
+                    self._upsert_placements(run, rows, placed)
+                    n_run += len(run)
+                    run, rows, run_ids = [], [], set()
+                    existing = self._allocs.get(a.id)
                 if existing is not None:
                     # Re-upserting a live alloc (in-place update): keep the
                     # client-owned fields — the plan's copy is a stale
@@ -136,14 +165,11 @@ class InMemState:
                     a.client_description = existing.client_description
                     a.task_states = existing.task_states
                 if a.job is None:
-                    # WAL replay strips the embedded job; reattach the
-                    # VERSION the alloc was placed with, not the current
-                    # table head — the reconciler's in-place/destructive
-                    # classification compares alloc.job.version.
-                    a.job = (self._job_versions.get(
-                        (a.namespace, a.job_id, a.job_version))
-                        or self._jobs.get((a.namespace, a.job_id)))
+                    a.job = self._placed_job(a)
                 self.upsert_alloc(a)
+        if run:
+            self._upsert_placements(run, rows, placed)
+            n_run += len(run)
         if result.deployment is not None:
             self.upsert_deployment(result.deployment)
         for du in result.deployment_updates:
@@ -151,6 +177,32 @@ class InMemState:
             if d is not None:
                 d.status = du.status
                 d.status_description = du.status_description
+        return n_run
+
+    def _placed_job(self, a: Allocation) -> Optional[Job]:
+        """WAL replay strips the embedded job; reattach the VERSION the
+        alloc was placed with, not the current table head — the
+        reconciler's in-place/destructive classification compares
+        alloc.job.version."""
+        return (self._job_versions.get((a.namespace, a.job_id, a.job_version))
+                or self._jobs.get((a.namespace, a.job_id)))
+
+    def _upsert_placements(self, allocs: List[Allocation], rows: List[int],
+                           placed=None) -> None:
+        """`upsert_alloc` of new allocations (`ClusterTensors.placement_row`
+        gave each its row), in one pass: the indexes one call an allocation
+        would stamp, in order, and the tensors in one
+        `ClusterTensors.upsert_placements` (`placed`: the rows verification
+        read, `PlacementRows`)."""
+        by_job, by_node = self._allocs_by_job, self._allocs_by_node
+        for a in allocs:
+            a.modify_index = next(self.index)
+            if not a.create_index:
+                a.create_index = a.modify_index
+            self._allocs[a.id] = a
+            by_job.setdefault((a.namespace, a.job_id), {})[a.id] = a
+            by_node.setdefault(a.node_id, {})[a.id] = a
+        self.cluster.upsert_placements(allocs, rows, placed)
 
     # ---- read API (scheduler State protocol) ----
 

@@ -20,13 +20,16 @@ import heapq
 import itertools
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 from ..lib.metrics import MetricsRegistry
 from ..lib.trace import host_span
 from ..scheduler.util import proposed_allocs
 from ..structs import Allocation, Node, Plan, PlanResult, allocs_fit
 from .state import StateStore
+
+if TYPE_CHECKING:
+    from ..tensor.cluster import PlacementRows
 
 
 class _Future:
@@ -159,48 +162,58 @@ _DIM_NAMES = {0: "cpu", 1: "memory", 2: "disk", 3: "network"}
 REASON_DEVICES = "devices"
 
 
-def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
+def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str,
+                        rows: Optional[PlacementRows] = None):
     """Vectorized per-node verification against the LIVE cluster tensors
     (the reference parallelizes exactly this check, plan_apply_pool.go:18;
     here the incrementally-maintained used/capacity rows make it O(plan
     allocs) instead of rebuilding the node's whole proposed set).
+    `rows`: the plan's placements read once, under the same lock
+    (`ClusterTensors.placement_rows`) — each usage row, ports and device
+    instances are taken from there, and the commit adds the same rows.
     Returns (fit, reason) or None to fall back to the object path."""
     import numpy as np
 
     from ..tensor.cluster import R_TOTAL
 
-    freed = np.zeros(R_TOTAL, dtype=np.float32)
+    freed = None  # nothing released: no freed vector to reckon
     freed_ports: Dict[int, int] = {}
     released: List[str] = []
-
-    def release(alloc_id: str) -> None:
-        released.append(alloc_id)
-        u = cl.alloc_usage.get(alloc_id)
+    alloc_usage, alloc_ports = cl.alloc_usage, cl.alloc_ports
+    placements = plan.node_allocation.get(node_id, ())
+    # stops and preemptions free what they hold; a placement frees the
+    # live copy it replaces (an in-place update)
+    for a in itertools.chain(plan.node_update.get(node_id, ()),
+                             plan.node_preemptions.get(node_id, ()),
+                             placements):
+        released.append(a.id)
+        u = alloc_usage.get(a.id)
         if u is not None and u[0] == row:
+            if freed is None:
+                freed = np.zeros(R_TOTAL, dtype=np.float32)
             np.add(freed, u[1], out=freed)
-        ap = cl.alloc_ports.get(alloc_id)
+        ap = alloc_ports.get(a.id)
         if ap is not None and ap[0] == row:
             for p in ap[1]:
                 freed_ports[p] = freed_ports.get(p, 0) + 1
 
-    for a in plan.node_update.get(node_id, ()):
-        release(a.id)
-    for a in plan.node_preemptions.get(node_id, ()):
-        release(a.id)
-
+    pos = rows.pos if rows is not None else {}
     placed = None
     placed_ports: List[int] = []
     placed_devs: List[Tuple[str, str]] = []
-    for a in plan.node_allocation.get(node_id, ()):
-        release(a.id)  # in-place update: the plan's copy replaces it
+    for a in placements:
         if a.terminal_status():
             continue
-        try:
-            v = cl.usage_row(a)
-            ports = cl._alloc_port_list(a)
-            devs = cl._alloc_device_list(a)
-        except Exception:  # noqa: BLE001 — odd shape: object path decides
-            return None
+        i = pos.get(id(a))
+        if i is not None:
+            v, ports, devs = rows.usage[i], rows.ports[i], rows.devices[i]
+        else:
+            try:
+                v = cl.usage_row(a)
+                ports = cl._alloc_port_list(a)
+                devs = cl._alloc_device_list(a)
+            except Exception:  # noqa: BLE001 — odd shape: object path decides
+                return None
         placed = v if placed is None else placed + v
         placed_ports.extend(ports)
         if devs:
@@ -208,7 +221,8 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
 
     if placed is None:
         return True, ""
-    total = cl.used[row] - freed + placed
+    total = (cl.used[row] + placed if freed is None
+             else cl.used[row] - freed + placed)
     # float32 incremental accounting: tolerate epsilon at the boundary
     over = total > cl.capacity[row] + 1e-3
     if over.any():
@@ -239,8 +253,11 @@ def _tensor_node_verify(cl, row: int, plan: Plan, node_id: str):
     return True, ""
 
 
-def evaluate_node_plan(state, plan: Plan, node_id: str) -> Tuple[bool, str]:
-    """Can this node accommodate the plan? (reference plan_apply.go:629)."""
+def evaluate_node_plan(state, plan: Plan, node_id: str,
+                       rows: Optional[PlacementRows] = None
+                       ) -> Tuple[bool, str]:
+    """Can this node accommodate the plan? (reference plan_apply.go:629).
+    `rows`: the plan's placements read once (`_tensor_node_verify`)."""
     has_update = bool(plan.node_update.get(node_id)) or bool(
         plan.node_preemptions.get(node_id)
     )
@@ -257,7 +274,7 @@ def evaluate_node_plan(state, plan: Plan, node_id: str) -> Tuple[bool, str]:
     cl = getattr(state, "cluster", None)
     row = cl.row_of.get(node_id) if cl is not None else None
     if row is not None:
-        verdict = _tensor_node_verify(cl, row, plan, node_id)
+        verdict = _tensor_node_verify(cl, row, plan, node_id, rows)
         if verdict is not None:
             return verdict
 
@@ -301,6 +318,10 @@ class PlanApplier:
         self._wait_ms = self.metrics.histogram("plan_apply.wait_ms")
         self._verify_ms = self.metrics.histogram("plan_apply.verify_ms")
         self._commit_ms = self.metrics.histogram("plan_apply.commit_ms")
+        # placements committed in one pass, and all of a plan's
+        # placements: added once a plan (`plan_array_share`)
+        self._array_allocs = self.metrics.counter("plan_apply.array_allocs")
+        self._placed_allocs = self.metrics.counter("plan_apply.placed_allocs")
         #: partial / applied — the server-side twin of the bench tail's
         #: `e2e_plan_partial_rate` (optimistic-concurrency cost), always
         #: exposed (ISSUE 13)
@@ -407,14 +428,26 @@ class PlanApplier:
         lock = (self.state.mutation_lock()
                 if hasattr(self.state, "mutation_lock")
                 else contextlib.nullcontext())
+        cl = getattr(self.state, "cluster", None)
+        placed = None
         t_verify = time.perf_counter()
         with host_span("plan_verify"), lock:
             # verify against the LIVE store (not a snapshot): the mutation
             # lock already guarantees internal consistency, and a full
             # StateSnapshot copy per plan (~0.6 ms at 10K allocs) was the
             # single biggest apply cost
+            if cl is not None:
+                # each placement's usage row, ports and devices, read once
+                # for the verify below and the commit after it
+                try:
+                    placed = cl.placement_rows(
+                        [a for allocs in plan.node_allocation.values()
+                         for a in allocs if not a.terminal_status()])
+                except Exception:  # noqa: BLE001 — odd shape: per node
+                    placed = None
             for node_id in touched:
-                fit, reason = evaluate_node_plan(self.state, plan, node_id)
+                fit, reason = evaluate_node_plan(self.state, plan, node_id,
+                                                 placed)
                 if fit:
                     if node_id in plan.node_allocation:
                         result.node_allocation[node_id] = list(
@@ -475,7 +508,6 @@ class PlanApplier:
                 if plan_span_id:
                     a.trace_id = plan.trace_id
                     a.trace_span_id = plan_span_id
-        cl = getattr(self.state, "cluster", None)
         if (cl is not None and getattr(self.state, "raft", None) is None
                 and hasattr(self.state, "mutation_lock")):
             # rejected node ids → rows: the certification observer
@@ -486,7 +518,8 @@ class PlanApplier:
             t_commit = time.perf_counter()
             with host_span("plan_commit"), self.state.mutation_lock():
                 v_lo = cl.version
-                self.state.upsert_plan_results(plan, result)
+                n_array = self.state.upsert_plan_results(plan, result,
+                                                         placed)
                 cl.mark_plan_window(
                     plan.eval_id, v_lo, cl.version, clean=not partial,
                     exact=bool(getattr(plan, "carry_exact", False)),
@@ -495,8 +528,14 @@ class PlanApplier:
         else:
             t_commit = time.perf_counter()
             with host_span("plan_commit"):
-                self.state.upsert_plan_results(plan, result)
+                n_array = self.state.upsert_plan_results(plan, result,
+                                                         placed)
         self._commit_ms.add_sample((time.perf_counter() - t_commit) * 1e3)
+        # committed in one pass (None from a raft-routed store, whose FSM
+        # commits), beside every placement the plan was handed
+        self._array_allocs.inc(n_array or 0)
+        self._placed_allocs.inc(
+            sum(len(v) for v in plan.node_allocation.values()))
         result.alloc_index = self.state.index.value
         if partial:
             result.refresh_index = self.state.index.value

@@ -175,6 +175,19 @@ class StateStore(InMemState):
                 self._emit_entry("upsert_alloc", (alloc,), before)
             self._cv.notify_all()
 
+    def _upsert_placements(self, allocs: List[Allocation], rows: List[int],
+                           placed=None) -> None:
+        # copy-on-write as `upsert_alloc`, once a plan: each per-job and
+        # per-node map the placements land in is copied once and replaced
+        # (first touch first), so a snapshot taken before keeps its maps.
+        # Runs under `upsert_plan_results`' lock.
+        by_job, by_node = self._allocs_by_job, self._allocs_by_node
+        for jk in dict.fromkeys((a.namespace, a.job_id) for a in allocs):
+            by_job[jk] = dict(by_job.get(jk, {}))
+        for node_id in dict.fromkeys(a.node_id for a in allocs):
+            by_node[node_id] = dict(by_node.get(node_id, {}))
+        InMemState._upsert_placements(self, allocs, rows, placed)
+
     # -- locked mutators --
 
     def _locked(name):  # noqa: N805 — decorator factory over parent methods

@@ -94,6 +94,50 @@ class ClusterSnapshot:
     row_to_node_id: List[Optional[str]]
 
 
+class PlacementRows:
+    """A plan's placements as `upsert_alloc` reads them
+    (`ClusterTensors.placement_rows`): each one's `usage_row`, ports and
+    device instances, read once under the store's lock. The plan
+    applier's verify sums the rows per node (`_tensor_node_verify`) and
+    its commit adds them to `used` (`ClusterTensors.upsert_placements`),
+    so no row is computed twice.
+
+    NumPy touches one row at a time, as `upsert_alloc` does: `np.add.at`
+    and operations on a whole plan's matrix let go of the GIL, and in a
+    server of a hundred threads the applier then waits to get it back
+    while it holds the commit-point mutex and the store's lock — slower
+    end to end than the row-at-a-time loop they replace. `pos` maps an
+    allocation OBJECT (its `id()`) to its index: a copy of an allocation
+    is not the allocation these rows were read from."""
+
+    __slots__ = ("allocs", "usage", "ports", "devices", "device_cols", "pos")
+
+    def __init__(self, allocs: List[Allocation], usage: List[np.ndarray],
+                 ports: List[List[int]], devices: List[List[Tuple[str, str]]],
+                 device_cols: int) -> None:
+        self.allocs = allocs
+        self.usage = usage
+        self.ports = ports
+        self.devices = devices
+        #: device columns when the rows were read: a column added since
+        #: would be missing from them
+        self.device_cols = device_cols
+        self.pos = {id(a): i for i, a in enumerate(allocs)}
+
+    def index(self, allocs: List[Allocation],
+              device_cols: int) -> Optional[List[int]]:
+        """Where each of `allocs` (these very objects) is, or None when one
+        is not here or a device column was added since (`device_cols`: the
+        table's count now)."""
+        if device_cols != self.device_cols:
+            return None
+        pos = self.pos
+        try:
+            return [pos[id(a)] for a in allocs]
+        except KeyError:
+            return None
+
+
 class ClusterTensors:
     """Incremental tensorization of nodes + allocations."""
 
@@ -761,6 +805,67 @@ class ClusterTensors:
         japs[alloc.id] = (row, alloc.task_group)
         touched.append(row)
         self._log_hot(*touched)
+        self.version += 1
+
+    def placement_row(self, alloc: Allocation) -> Optional[int]:
+        """The row `upsert_placements` takes `alloc` on: a live allocation
+        this table holds no usage, port or device entry of yet, on a node
+        it has a row for. None sends it through `upsert_alloc`."""
+        aid = alloc.id
+        if (aid in self.alloc_usage or aid in self.alloc_ports
+                or aid in self.alloc_devices or alloc.terminal_status()):
+            return None
+        return self.row_of.get(alloc.node_id)
+
+    def placement_rows(self, allocs: List[Allocation]) -> "PlacementRows":
+        """What `upsert_alloc` reads of each allocation — its usage row, its
+        ports, its device instances — read once for a plan's placements,
+        in their order. The commit takes the lock anew after the verify
+        that read them: a device column added between makes the rows
+        stale (`PlacementRows.index`), never wrong."""
+        device_cols = len(self.device_cols)
+        return PlacementRows(
+            allocs, [self.usage_row(a) for a in allocs],
+            [self._alloc_port_list(a) for a in allocs],
+            [self._alloc_device_list(a) for a in allocs], device_cols)
+
+    def upsert_placements(self, allocs: List[Allocation], rows: List[int],
+                          placed: Optional["PlacementRows"] = None) -> None:
+        """`upsert_alloc` of allocations `placement_row` gave `rows`, in one
+        pass: each usage row (from `placed` when it holds these very
+        allocations and no device column was added since) added to `used`
+        with the same `+=` in the same order, ports and device instances
+        booked for those that hold any, and ONE hot-log entry and version
+        for all of them."""
+        idx = (placed.index(allocs, len(self.device_cols))
+               if placed is not None else None)
+        if idx is None:
+            placed = self.placement_rows(allocs)
+            idx = range(len(allocs))
+        used, usage_of = self.used, placed.usage
+        alloc_usage, alloc_ports = self.alloc_usage, self.alloc_ports
+        ports_of, devices_of = placed.ports, placed.devices
+        job_allocs = self.job_allocs
+        job_id, japs = None, None
+        for a, j, row in zip(allocs, idx, rows):
+            aid = a.id
+            u = usage_of[j]
+            used[row] += u
+            alloc_usage[aid] = (row, u)
+            ports = ports_of[j]
+            if ports:
+                self._add_alloc_ports(aid, row, ports)
+            else:
+                alloc_ports[aid] = (row, ports)
+            devs = devices_of[j]
+            if devs:
+                self._add_alloc_devices(aid, row, devs)
+            if a.job_id != job_id:
+                job_id = a.job_id
+                japs = job_allocs.setdefault(job_id, {})
+            japs.pop(aid, None)
+            japs[aid] = (row, a.task_group)
+        self._log_hot(*rows)
         self.version += 1
 
     def remove_alloc(self, alloc_id: str, job_id: str = "") -> None:
