@@ -7,14 +7,21 @@ program. The shapes are `nomad_tpu/synth.py`'s (`synth_node`,
 from __future__ import annotations
 
 import json
+import os
 
 from nomad_tpu.mock import alloc_resources
-from nomad_tpu.structs import (JOB_TYPE_SERVICE, Allocation, EphemeralDisk,
-                               Job, NetworkResource, Node,
-                               NodeReservedResources, NodeResources,
-                               RequestedDevice, Resources, Task, TaskGroup)
+from nomad_tpu.structs import (Allocation, EphemeralDisk, Job,
+                               NetworkResource, Node, NodeReservedResources,
+                               NodeResources, RequestedDevice, Resources,
+                               Task, TaskGroup)
 from nomad_tpu.structs.codec import to_json_tree, to_wire
 from nomad_tpu.structs.job import Affinity, Constraint, Spread, SpreadTarget
+
+# read by path, as `cluster.JOB_DEFAULTS` is: this module is also loaded
+# alone, where `cluster` is no module to import
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "job_defaults.json")) as _f:
+    JOB_DEFAULTS = json.load(_f)["defaults"]
 
 
 def to_node(rec: dict) -> Node:
@@ -65,7 +72,10 @@ def to_job(spec: dict) -> Job:
                           spread_target=[SpreadTarget(value=v, percent=p)
                                          for v, p in sp["targets"]])]
     return Job(
-        id=spec["id"], name=spec["id"], type=JOB_TYPE_SERVICE, priority=50,
+        # a spec holds `type` and `priority` only where its kind names them
+        id=spec["id"], name=spec["id"],
+        type=spec.get("type", JOB_DEFAULTS["type"]),
+        priority=spec.get("priority", JOB_DEFAULTS["priority"]),
         datacenters=list(spec["datacenters"]),
         constraints=constraints,
         affinities=[Affinity(ltarget=lt, rtarget=rv, operand=op, weight=w)

@@ -14,6 +14,8 @@ the GPUs there are. Sizes, choices, the mix and the shape of each job kind
 from __future__ import annotations
 
 import copy
+import json
+import os
 import random
 import uuid
 from typing import Dict, List
@@ -21,6 +23,27 @@ from typing import Dict, List
 import numpy as np
 
 DIMS = ("cpu", "memory", "disk")
+#: shape keys that a job's spec holds only where its kind's record names
+#: them: the default job's bytes are those it had before the keys existed.
+#: `job_type` and `job_priority` read them with these defaults.
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "job_defaults.json")) as _f:
+    JOB_DEFAULTS = json.load(_f)["defaults"]
+#: the schedulers the reference models: the generic one's `Select`
+#: (service) and the system one's node set with preemption
+JOB_TYPES = ("service", "system")
+#: what a system job may not carry: the system scheduler ranks no node, and
+#: under `distinct_property` its outcome depends on the order of the nodes
+SYSTEM_REFUSES = ("spread", "affinities", "distinct_hosts",
+                  "distinct_property")
+
+
+def job_type(spec: dict) -> str:
+    return spec.get("type", JOB_DEFAULTS["type"])
+
+
+def job_priority(spec: dict) -> int:
+    return spec.get("priority", JOB_DEFAULTS["priority"])
 
 
 def datacenters(cfg: dict) -> List[str]:
@@ -118,7 +141,10 @@ def make_job(cfg: dict, seed: int, k: int, kind: str, count: int) -> dict:
     A kind without a record, or a record with a key that is no key of the
     shape — one that `reference.py` and `adapter.to_job` would not read —
     is an error: no cell is judged by a reference that ignores part of its
-    job."""
+    job. `type` (`JOB_TYPES`) and `priority` (1..100) are keys of the shape
+    too (`JOB_DEFAULTS`); a `system` job has a count of 1, as upstream's
+    `Job.Validate` demands, whatever the traffic's count, and refuses the
+    keys of `SYSTEM_REFUSES`."""
     j = cfg["job"]
     pairs = [(c, m) for c in j["cpu"] for m in j["memory"]]
     _rng(seed, f"asks/{k // len(pairs)}").shuffle(pairs)
@@ -142,11 +168,26 @@ def make_job(cfg: dict, seed: int, k: int, kind: str, count: int) -> dict:
         raise ValueError(f"job kind {kind!r} has no record in the "
                          f"configuration's `kinds`")
     else:
-        unknown = sorted(set(record) - set(shape))
+        keys = set(shape) | set(JOB_DEFAULTS)
+        unknown = sorted(set(record) - keys)
         if unknown:
             raise ValueError(f"job kind {kind!r}: {unknown} are no keys of "
-                             f"a job's shape {sorted(shape)}")
+                             f"a job's shape {sorted(keys)}")
         shape.update(copy.deepcopy(record))
+        if job_type(shape) not in JOB_TYPES:
+            raise ValueError(f"job kind {kind!r}: type {job_type(shape)!r} "
+                             f"is none of {JOB_TYPES}")
+        prio = job_priority(shape)
+        if isinstance(prio, bool) or not isinstance(prio, int) \
+                or not 1 <= prio <= 100:
+            raise ValueError(f"job kind {kind!r}: priority {prio!r} is no "
+                             f"integer in 1..100")
+        if job_type(shape) == "system":
+            set_keys = [key for key in SYSTEM_REFUSES if shape[key]]
+            if set_keys:
+                raise ValueError(f"job kind {kind!r}: a system job takes "
+                                 f"none of {set_keys}")
+            count = 1
     return {
         "k": k,
         "id": f"svc-{rng.getrandbits(48):012x}",

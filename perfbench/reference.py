@@ -12,16 +12,37 @@ It reads the benchmark's own plain records (`cluster.py`), nothing the
 program has made. `rnd` rounds after every arithmetic step: the identity for
 the reference, a round trip through bfloat16 for the control (the nearest
 precision below the kernels' float32 at `Precision.HIGHEST`).
+
+A system job (`scheduler/system_sched.go`, with `SchedulerConfiguration`'s
+system preemption on, upstream's default) ranks nothing: every node of its
+datacenters that passes its constraints gets one allocation, where the ask
+fits as the state stands (`system_nodes`: `must`) or fits once the live
+allocations that it may evict are gone (`may_preempt`). It may evict an
+allocation whose job's priority is at least `PRIORITY_DELTA` below its own
+(`preemption.go`: candidates within a delta of 10 are skipped).
+`judge_preemption` says why a victim set on one node is wrong: a victim not
+live there, one of too high a priority, an eviction where the job fit
+without one, a set after which it still does not fit, and a set that is not
+minimal as upstream's `filterSuperset` makes it minimal: it adds the
+victims back by their distance to the ask (`basic_distance`), far to near,
+until the ask fits, so the victim nearest the ask is one the job needs.
+That is all it guarantees: a farther victim may be one the job could do
+without (ask 290 MHz on a full node, victims of 110 and 290 MHz: the 290
+alone would do, and upstream evicts both). It decides legality,
+sufficiency and that minimality; it does not decide which set upstream's
+greedy order picks.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from cluster import Cluster
+from cluster import JOB_DEFAULTS, Cluster, job_priority
 
 BINPACK_MAX_FIT_SCORE = 18.0
+#: a victim's job priority is at most the preempting job's less this
+PRIORITY_DELTA = 10
 
 
 def exact(a):
@@ -55,7 +76,8 @@ def _compare(op: str, col: np.ndarray, rval: str) -> np.ndarray:
 
 
 class Reference:
-    """Cluster state as the serial schedule leaves it, and one Select."""
+    """Cluster state as the serial schedule leaves it, one Select, and a
+    system job's nodes and victims."""
 
     def __init__(self, cluster: Cluster) -> None:
         self.c = cluster
@@ -64,14 +86,46 @@ class Reference:
         #: job id -> {node index: allocations of that job there}
         self.own: Dict[str, Dict[int, int]] = {}
         self._static: Dict[str, np.ndarray] = {}
+        #: the live allocations that have an id, for the system job and its
+        #: victims: id -> (node, (cpu, memory, disk, GPUs), priority). The
+        #: fillers are the default job's (`launcher.load_cluster`), at its
+        #: priority, entered when a system job or an eviction first asks.
+        self._live: Dict[str, Tuple[int, tuple, int]] = {}
+        self._fillers_entered = False
 
     # ---- state ----
 
-    def place(self, job: dict, node: int) -> None:
+    def place(self, job: dict, node: int, alloc_id: Optional[str] = None
+              ) -> None:
         self.used[node] += (job["cpu"], job["memory"], job["disk"])
         self.gpu_free[node] -= job["gpus"]
         own = self.own.setdefault(job["id"], {})
         own[node] = own.get(node, 0) + 1
+        if alloc_id is not None:
+            self._live[alloc_id] = (node, (job["cpu"], job["memory"],
+                                           job["disk"], job["gpus"]),
+                                    job_priority(job))
+
+    def evict(self, alloc_id: str) -> bool:
+        """Free a live allocation; False where there is none of that id."""
+        rec = self.live().pop(alloc_id, None)
+        if rec is None:
+            return False
+        node, ask, _prio = rec
+        self.used[node] -= ask[:3]
+        self.gpu_free[node] += ask[3]
+        return True
+
+    def live(self) -> Dict[str, Tuple[int, tuple, int]]:
+        """id -> (node, ask, priority) of every live allocation with an
+        id, the fillers entered on the first call."""
+        if not self._fillers_entered:
+            self._fillers_entered = True
+            prio = JOB_DEFAULTS["priority"]
+            for f in self.c.fillers:
+                self._live[f["id"]] = (
+                    f["node"], (f["cpu"], f["memory"], f["disk"], 0), prio)
+        return self._live
 
     def forget(self, job_id: str) -> None:
         """Drop a finished job's own-allocation map (its allocations stay
@@ -81,7 +135,7 @@ class Reference:
 
     # ---- one Select ----
 
-    def _static_mask(self, job: dict) -> np.ndarray:
+    def static_mask(self, job: dict) -> np.ndarray:
         m = self._static.get(job["id"])
         if m is None:
             cols = self.c.columns
@@ -100,7 +154,7 @@ class Reference:
         own = np.zeros(n)
         for i, k in self.own.get(job["id"], {}).items():
             own[i] = k
-        feas = self._static_mask(job).copy()
+        feas = self.static_mask(job).copy()
         if job["distinct_hosts"]:
             feas &= own == 0
         if job["distinct_property"]:
@@ -170,6 +224,81 @@ class Reference:
             parts = parts + hit
 
         return feas, rnd(score / parts)
+
+    # ---- a system job ----
+
+    def _fits(self, job: dict, freed: np.ndarray, rows=slice(None),
+              rnd: Callable = exact) -> np.ndarray:
+        """Where, among `rows`, the job's ask fits once `freed` (f64[rows,
+        4]: cpu, memory, disk, GPUs) is given back; used, freed, ask and
+        capacity rounded by `rnd` as `select` rounds them (GPUs are
+        counts)."""
+        ask = np.array([job["cpu"], job["memory"], job["disk"]],
+                       dtype=np.float64)
+        util = rnd(rnd(rnd(self.used[rows]) - rnd(freed[:, :3])) + rnd(ask))
+        return ((util <= rnd(self.c.cap[rows])).all(axis=1)
+                & (self.gpu_free[rows] + freed[:, 3] >= job["gpus"]))
+
+    def system_nodes(self, job: dict, rnd: Callable = exact
+                     ) -> Tuple[np.ndarray, np.ndarray]:
+        """(must bool[N], may_preempt bool[N]) for a system job against the
+        state as it stands: the nodes that pass its datacenters and
+        constraints where the ask fits, and those where it fits only once
+        every live allocation it may evict there is gone; computed with
+        `rnd` (`bf16`: the control)."""
+        n = len(self.c.nodes)
+        static = self.static_mask(job)
+        top = job_priority(job) - PRIORITY_DELTA
+        evictable = [(node, ask) for node, ask, prio in self.live().values()
+                     if prio <= top]
+        freed = np.zeros((n, 4))
+        if evictable:
+            np.add.at(freed, [node for node, _a in evictable],
+                      np.array([ask for _n, ask in evictable],
+                               dtype=np.float64))
+        must = static & self._fits(job, np.zeros((n, 4)), rnd=rnd)
+        return must, static & ~must & self._fits(job, freed, rnd=rnd)
+
+    def judge_preemption(self, job: dict, node: int, victims
+                         ) -> List[str]:
+        """The reasons why evicting `victims` (allocation ids) on `node`
+        for one allocation of `job` is wrong; none where it is right."""
+        why = []
+        zero = np.zeros(4)
+
+        def fits(freed: np.ndarray) -> bool:
+            return bool(self._fits(job, freed[None, :], [node])[0])
+
+        if fits(zero):
+            why.append("fit without evicting")
+        asks = []
+        live = self.live()
+        for v in victims:
+            rec = live.get(v)
+            if rec is None or rec[0] != node:
+                why.append(f"{v} is not live on the node")
+                continue
+            if rec[2] > job_priority(job) - PRIORITY_DELTA:
+                why.append(f"{v} has priority {rec[2]}")
+            asks.append(np.array(rec[1], dtype=np.float64))
+        total = sum(asks, zero)
+        if not fits(total):
+            why.append("does not fit after the eviction")
+        elif asks:
+            far = [basic_distance(job, a) for a in asks]
+            near = [a for a, d in zip(asks, far) if d == min(far)]
+            if all(fits(total - a) for a in near):
+                why.append("the victim nearest the ask could be given back")
+        return why
+
+
+def basic_distance(job: dict, used: np.ndarray) -> float:
+    """Upstream's `basicResourceDistance` (`preemption.go`): how far an
+    allocation's cpu, memory and disk lie from the job's ask, each as a
+    share of the ask."""
+    coords = [(job[d] - u) / job[d] for d, u in
+              zip(("cpu", "memory", "disk"), used[:3]) if job[d] > 0]
+    return float(np.sqrt(sum(c * c for c in coords)))
 
 
 def gap_of(feas: np.ndarray, final: np.ndarray, node: int

@@ -57,7 +57,6 @@ RECORDED_COUNTERS = (
     "plan_apply.rejected_devices", "sched.device_offers",
     "sched.device_offer_retries", "sched.offers", "sched.offers_skipped",
     "drain.footprint_estimates", "drain.footprint_hits")
-ALLOC_INDEX = re.compile(r"\[(\d+)\]$")
 
 
 def log(msg: str) -> None:
@@ -83,7 +82,7 @@ def plugin(kind: str, name: str):
 class Req:
     __slots__ = ("k", "spec", "payload", "burst", "due", "released", "sent",
                  "acked", "done", "status", "eval_id", "order", "job_index",
-                 "error")
+                 "finished", "error")
 
     def __init__(self, k, spec, payload, burst, due=None, released=None):
         self.k = k
@@ -98,7 +97,8 @@ class Req:
         # (written, then enqueued, by one handler thread). The job's own
         # index is no order: two handlers can write job A, job B and then
         # enqueue eval B before eval A. It stands in where events were lost.
-        self.order = self.job_index = None
+        # `finished`: the index of its terminal event (`check.serial_order`)
+        self.order = self.job_index = self.finished = None
 
 
 class Payloads:
@@ -278,6 +278,7 @@ class Generator:
                         # was written, just before it was enqueued
                         req.order = p.get("modify_index") or e.get("index")
                     if p.get("status") in TERMINAL and req.done is None:
+                        req.finished = p.get("modify_index") or e.get("index")
                         self._finish(req, p["status"], now)
         except Exception as e:  # noqa: BLE001 — reported by the main thread
             self.stream_error = f"{type(e).__name__}: {e}"
@@ -411,7 +412,9 @@ def counters_between(a: dict, b: dict) -> dict:
 
 
 def read_back(host, port, reqs, threads: int = 4) -> None:
-    """Every eval's allocations over HTTP, outside any timing."""
+    """Every eval's allocations over HTTP, outside any timing: those the
+    store runs, and those a later allocation evicted (`evicted`), each
+    with its id, the victims it evicted and its commit index."""
     todo: "queue.SimpleQueue" = queue.SimpleQueue()
     for r in reqs:
         todo.put(r)
@@ -437,24 +440,9 @@ def read_back(host, port, reqs, threads: int = 4) -> None:
                 if res.status >= 400:
                     continue
                 for a in data or ():
-                    if a.get("desired_status") != "run":
-                        continue
-                    m = ALLOC_INDEX.search(a.get("name", ""))
-                    score = None
-                    for sm in (a.get("metrics") or {}).get(
-                            "score_meta") or ():
-                        if sm.get("node_id") == a.get("node_id"):
-                            score = sm.get("norm_score")
-                    gpus = []
-                    tasks = (a.get("allocated_resources") or {}).get(
-                        "tasks") or {}
-                    for t in tasks.values():
-                        for d in t.get("devices") or ():
-                            gpus.extend(d.get("device_ids") or ())
-                    allocs.append({"index": int(m.group(1)) if m else 0,
-                                   "node": a.get("node_id"),
-                                   "norm_score": score,
-                                   "device_ids": gpus})
+                    rec = check.alloc_record(a)
+                    if rec is not None:
+                        allocs.append(rec)
         finally:
             conn.close()
 
@@ -587,18 +575,18 @@ def judge(plain, reqs, asked, workload: str, seed: int, control: bool):
     """The reference's replay of the whole run, a sample of the window's
     answered evals compared (`check.py`)."""
     spec = load_json(HERE, "limits", f"{workload}.json")
-    ordered = sorted((r for r in reqs if (r.order or r.job_index)),
-                     key=lambda r: r.order or r.job_index)
+    ordered = check.serial_order(
+        [{"spec": r.spec, "allocs": r.spec["_allocs"],
+          "enqueued": r.order or r.job_index, "finished": r.finished}
+         for r in reqs if (r.order or r.job_index)])
     answered = [r for r in asked if r.status in TERMINAL]
     longest = max(answered, key=lambda r: (len(r.spec["_allocs"]), r.k),
                   default=None)
     sample = check.draw_sample([r.spec["id"] for r in answered],
                                longest.spec["id"] if longest else None,
                                int(spec["sample_evals"]), seed)
-    numbers = check.replay(
-        plain, [{"spec": r.spec, "allocs": r.spec["_allocs"]}
-                for r in ordered], sample,
-        rnd_control=bf16 if control else None)
+    numbers = check.replay(plain, ordered, sample,
+                           rnd_control=bf16 if control else None)
     numbers["unanswered"] = sum(1 for r in asked if r.done is None)
     if control:
         # the control's numbers through the same comparison, with the
@@ -700,8 +688,10 @@ def main(argv=None) -> int:
         read_back(ready["host"], ready["port"], reqs)
         readback_s = time.monotonic() - t_rb
         for r in reqs:
-            r.spec["_ok"] = (r.status == "complete"
-                             and len(r.spec["_allocs"]) == r.spec["count"])
+            # a system job's count is its node set, which the replay judges
+            r.spec["_ok"] = r.status == "complete" and (
+                cl.job_type(r.spec) == "system"
+                or len(r.spec["_allocs"]) == r.spec["count"])
         failed = [r for r in asked if not r.spec["_ok"]]
         t_check = time.monotonic()
         numbers, checks = judge(plain, reqs, asked, args.workload,
@@ -811,6 +801,9 @@ def main(argv=None) -> int:
                 for c in RECORDED_COUNTERS},
             "fill_at_end": numbers["fill"],
             "gpus_in_use": numbers["gpus_in_use"],
+            "system": {k: numbers[k] for k in (
+                "system_placed", "system_refused", "evictions",
+                "system_interleaved")},
             "setup": dict(setup, total_s=setup_s, child=ready["timings"]),
             "after_window": {"drain_s": w["drain_s"],
                              "answered_all": w["answered_all"],
