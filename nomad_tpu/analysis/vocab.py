@@ -206,6 +206,11 @@ PROM_REQUIRED = frozenset({
     # sum to the offers); a plan's wait for the commit-point mutex, its
     # verify loop and its commit
     "nomad_http_handler_ms", "nomad_http_requests", "nomad_http_cpu_ms",
+    # a job register's body decoded in one pass at the edge: the bodies,
+    # their thread CPU (the benchmark's `http_decode_ms`), and those left
+    # to the generic decode
+    "nomad_http_decodes", "nomad_http_decode_ms",
+    "nomad_http_decode_fallbacks",
     "nomad_spec_offers", "nomad_spec_offer_ms",
     "nomad_spec_offer_launched", "nomad_spec_offer_no_successor",
     "nomad_spec_offer_off", "nomad_spec_offer_gate",

@@ -9,18 +9,22 @@ tagged with `__t` type markers so nested structs (Job inside Allocation,
 DrainStrategy inside Node, ...) round-trip without per-type code.
 
 Consumers: the WAL/FSM (server/fsm.py), the Raft transport, and the
-msgpack-RPC fabric.
+msgpack-RPC fabric; the HTTP API (agent/http.py) through the JSON bridge,
+`from_json` for a job register's body.
 """
 from __future__ import annotations
 
+import base64
 import dataclasses
 import importlib
+import json
 import pkgutil
 import threading
-from typing import Any, Dict, Type
+from typing import Any, Dict, FrozenSet, Tuple, Type
 
 _TYPE_TAG = "__t"
 _REGISTRY: Dict[str, Type] = {}
+_FIELDS: Dict[str, Tuple[Type, FrozenSet[str]]] = {}
 _REGISTRY_LOCK = threading.Lock()
 
 
@@ -59,8 +63,22 @@ def registry() -> Dict[str, Type]:
     if not _REGISTRY:
         with _REGISTRY_LOCK:
             if not _REGISTRY:
-                _REGISTRY.update(_build_registry())
+                reg = _build_registry()
+                _FIELDS.update(
+                    (name, (cls, frozenset(
+                        f.name for f in dataclasses.fields(cls))))
+                    for name, cls in reg.items())
+                _REGISTRY.update(reg)
     return _REGISTRY
+
+
+def _struct_table() -> Dict[str, Tuple[Type, FrozenSet[str]]]:
+    """Type tag → (class, its field names), built once with the registry:
+    a decode looks a node's fields up here instead of calling
+    `dataclasses.fields` for every struct it builds."""
+    if not _REGISTRY:
+        registry()
+    return _FIELDS
 
 
 def to_wire(obj: Any) -> Any:
@@ -84,8 +102,6 @@ def to_json_tree(tree: Any) -> Any:
     msgpack transports carry bytes natively; HTTP/JSON needs this bridge.
     Injective: user dicts that collide with the markers are wrapped in
     {"__bmap": ...} so decoding never misreads them."""
-    import base64
-
     if isinstance(tree, bytes):
         return {"__b": base64.b64encode(tree).decode()}
     if isinstance(tree, dict):
@@ -99,8 +115,6 @@ def to_json_tree(tree: Any) -> Any:
 
 
 def from_json_tree(tree: Any) -> Any:
-    import base64
-
     if isinstance(tree, dict):
         if set(tree) == {"__b"}:
             return base64.b64decode(tree["__b"])
@@ -117,14 +131,64 @@ def from_wire(tree: Any) -> Any:
     if isinstance(tree, dict):
         tag = tree.get(_TYPE_TAG)
         if tag is not None:
-            cls = registry().get(tag)
-            if cls is None:
+            entry = _struct_table().get(tag)
+            if entry is None:
                 raise KeyError(f"unknown struct type {tag!r}")
-            names = {f.name for f in dataclasses.fields(cls)}
-            kwargs = {k: from_wire(v) for k, v in tree.items()
-                      if k != _TYPE_TAG and k in names}
-            return cls(**kwargs)
+            cls, names = entry
+            return cls(**{k: from_wire(v) for k, v in tree.items()
+                          if k in names})
         return {k: from_wire(v) for k, v in tree.items()}
     if isinstance(tree, list):
         return [from_wire(v) for v in tree]
     return tree
+
+
+_NO_MARKER = object()
+
+
+def from_json(raw: Any) -> Any:
+    """`from_wire(from_json_tree(json.loads(raw)))` in one pass: the
+    parser's object hook builds each struct, bytes value and map as its
+    closing brace is read. Equal to the three steps wherever it returns.
+    It raises wherever they raise, and also where they may not, since it
+    decodes each node before it knows what holds it: a node under a field
+    `from_wire` drops, and a wrapped map shaped as a marker that is none
+    (`{"__b": "z"}`, `{"__bmap": 1}`). A caller runs the three steps
+    where it raises.
+
+    The hook runs bottom-up, so a `{"__bmap": X}` wrapper reaches it after
+    X. An X whose own shape is a marker (`{"__b": s}` or `{"__bmap": Y}`)
+    was decoded as one; the wrapper takes the node back, as the plain map
+    it stands for. That X is the newest marker decoded, since its closing
+    brace is the last one before the wrapper's."""
+    table = _struct_table()
+    last = [_NO_MARKER, None]  # the newest marker: what it gave, itself
+
+    def hook(node: dict) -> Any:
+        tag = node.get(_TYPE_TAG)
+        if tag is not None:
+            entry = table.get(tag)
+            if entry is None:
+                raise KeyError(f"unknown struct type {tag!r}")
+            cls, names = entry
+            del node[_TYPE_TAG]
+            if names.issuperset(node):
+                return cls(**node)
+            return cls(**{k: v for k, v in node.items() if k in names})
+        if len(node) != 1:
+            return node
+        if "__b" in node:
+            out = base64.b64decode(node["__b"])
+        elif "__bmap" in node:
+            out = node["__bmap"]
+            if out is last[0]:
+                out = last[1]
+            elif not (isinstance(out, dict)
+                      or dataclasses.is_dataclass(out)):
+                raise TypeError(f"__bmap of {type(out).__name__}")
+        else:
+            return node
+        last[0], last[1] = out, node
+        return out
+
+    return json.loads(raw, object_hook=hook)
