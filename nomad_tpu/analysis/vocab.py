@@ -287,6 +287,8 @@ ALLOWED_PREFIXES = (
                               # (ISSUE 26, lib/backend.py)
     "nomad_http_",            # the PUT/POST handlers' wall, count and
                               # thread CPU (ISSUE 38, agent/http.py)
+    "nomad_system_",          # the system scheduler's launch, plan build
+                              # and evictions (scheduler/system.py)
 )
 
 #: the only label names any exposed series may carry
@@ -301,7 +303,7 @@ TRANSFER_SITES = frozenset({
     "stack.ports_full", "stack.ports_delta", "stack.ports_word_delta",
     "select_batch.pack_buffers", "select_batch.fetch",
     "select_batch.table_insert", "select_batch.dyn_rows",
-    "mesh.shard_cluster",
+    "mesh.shard_cluster", "system.upload", "system.fetch",
 })
 
 #: HBM residency sites (lib/hbm.py; README residency-site table) — the

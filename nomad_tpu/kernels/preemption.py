@@ -14,6 +14,11 @@ The winning node's exact victim set is then refined host-side by the faithful
 greedy `scheduler/preemption.py` Preemptor (distance scoring + superset
 filter) — only the O(N·A) node scan belongs on the VPU.
 
+`system_place` is the system scheduler's program: every node of a system
+task group decided at once — fits, needs an eviction, and for each such
+node the victim set upstream's greedy (`PreemptForTaskGroup` +
+`filterSuperset`, preemption.go:198/:702) chooses there, the node fixed.
+
 Shapes: N nodes × A candidate-alloc slots (bucketed). Ineligible slots
 (padding, priority delta < 10, same job) carry priority +INF so the sort
 pushes them past every real candidate and the cumulative-eligibility mask
@@ -27,7 +32,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..structs.funcs import PREEMPTION_SCORE_ORIGIN, PREEMPTION_SCORE_RATE
+from ..structs.funcs import (MAX_PARALLEL_PENALTY, PREEMPTION_SCORE_ORIGIN,
+                             PREEMPTION_SCORE_RATE)
 from .placement import (
     ClusterArrays,
     TGParams,
@@ -38,6 +44,7 @@ from .placement import (
     _scatter_counts,
     _select_tokens,
     fit_scores,
+    system_feasibility,
 )
 
 NEG_INF = -1e30
@@ -154,3 +161,176 @@ def preempt_rank(cluster: ClusterArrays, p: TGParams,
 
 
 preempt_rank_jit = jax.jit(preempt_rank)
+
+
+# ---------------------------------------------------------------------------
+# System task groups: every node's fit, eviction and victims in one launch
+# ---------------------------------------------------------------------------
+
+#: `system_place`'s row status codes
+SYS_INFEASIBLE, SYS_FITS, SYS_FREED, SYS_REFUSED, SYS_UNPACKED = range(5)
+
+
+class SystemCandidates(NamedTuple):
+    """One system task group's victim candidates, host-packed per node row
+    (the row bucket × a power-of-two candidate bucket C): the proposed
+    allocations of the row (live less the plan's stops and preemptions,
+    plus its placements; never the job's own) that the job may evict —
+    priority at least 10 below its own — in the order the host lists
+    them. `remaining` is the node's usable cpu / memory / disk less EVERY
+    such proposed allocation, evictable or not (the Preemptor's
+    `remaining`)."""
+
+    packed: jax.Array     # bool[N] — the row's candidates are in the table
+    remaining: jax.Array  # f32[N, 3]
+    res: jax.Array        # f32[N, C, 3] — candidate cpu / memory / disk
+    prio: jax.Array       # f32[N, C] — candidate job priority; INF_PRIO pad
+    # the max_parallel penalty (coupled variant; [1, 1] / [1] otherwise)
+    max_par: jax.Array    # f32[N, C] — candidate group's migrate max_parallel
+    slot: jax.Array       # i32[N, C] — its (job, task group) count slot, −1 pad
+    counts: jax.Array     # f32[S] — the plan's preemptions per slot at launch
+    order: jax.Array      # i32[K] — packed rows in the host's node order, −1 pad
+
+
+def _distance(need: jax.Array, used: jax.Array) -> jax.Array:
+    """`basic_resource_distance` (preemption.go:608) over the last axis
+    (cpu, memory, disk), summed in the host's order: memory, cpu, disk."""
+    def coord(k):
+        n = need[..., k]
+        return jnp.where(n > 0, (n - used[..., k]) / jnp.where(n > 0, n, 1.0),
+                         0.0)
+
+    cpu, mem, disk = coord(0), coord(1), coord(2)
+    return jnp.sqrt(mem * mem + cpu * cpu + disk * disk)
+
+
+def _greedy(remaining, res, prio, penalty, ask):
+    """Upstream's victim greedy on B nodes at once, each node alone.
+
+    Per node: the lowest priority among the candidates not yet taken is
+    the group; in it take the least `score_for_task_group` against the
+    SHRINKING need (first in table order on a tie), until remaining plus
+    what was taken holds the ask on every dimension; a group used up
+    without that passes to the next priority. Then `filterSuperset`: the
+    taken ones re-added far to near by distance to the ask (a tie in the
+    order taken) and cut at the first prefix that fits.
+    -> (victims i32[B], order i32[B, C]): the first `victims` of `order`
+    are the victims' candidate indices in upstream's order."""
+    b, c = prio.shape
+    live0 = prio < INF_PRIO
+    cols = jnp.arange(c)[None, :]
+
+    def cond(st):
+        i, _chosen, _step, _avail, _need, done = st
+        return (i < c) & ~jnp.all(done)
+
+    def body(st):
+        i, chosen, step, avail, need, done = st
+        live = live0 & ~chosen
+        low = jnp.min(jnp.where(live, prio, INF_PRIO), axis=1)
+        grp = live & (prio == low[:, None]) & ~done[:, None]
+        score = jnp.where(grp, _distance(need[:, None, :], res) + penalty,
+                          jnp.inf)
+        pick = jnp.argmin(score, axis=1)
+        took = jnp.any(grp, axis=1)
+        hit = (cols == pick[:, None]) & took[:, None]
+        freed = jnp.sum(jnp.where(hit[..., None], res, 0.0), axis=1)
+        avail = avail + freed
+        met = took & jnp.all(avail >= ask, axis=1)
+        return (i + 1, chosen | hit, jnp.where(hit, i, step), avail,
+                need - freed, done | met | ~took)
+
+    st0 = (jnp.int32(0), jnp.zeros((b, c), bool), jnp.full((b, c), c),
+           remaining, jnp.broadcast_to(ask, (b, 3)), ~jnp.any(live0, axis=1))
+    _, chosen, step, avail, _, _ = jax.lax.while_loop(cond, body, st0)
+    met = jnp.all(avail >= ask, axis=1) & jnp.any(chosen, axis=1)
+
+    far = jnp.where(chosen, -_distance(ask, res), jnp.inf)
+    _, _, order = jax.lax.sort(
+        (far, step, jnp.broadcast_to(cols, (b, c)).astype(jnp.int32)),
+        dimension=1, num_keys=2)
+    back = jnp.cumsum(jnp.take_along_axis(res, order[..., None], axis=1),
+                      axis=1)
+    fits = jnp.all(remaining[:, None, :] + back >= ask, axis=2) \
+        & (cols < jnp.sum(chosen, axis=1)[:, None])
+    victims = jnp.where(met, jnp.argmax(fits, axis=1) + 1, 0)
+    return victims.astype(jnp.int32), order
+
+
+def _onehot_take(x, idx):
+    """`x[idx]` for one row of candidates, as a one-hot select."""
+    return jnp.sum(jnp.where(idx[:, None] == jnp.arange(x.shape[0])[None, :],
+                             x[None, :], 0), axis=1)
+
+
+def _penalty(max_par, slot, counts):
+    """`score_for_task_group`'s max_parallel term at the given counts."""
+    n = jnp.where(slot >= 0, counts[jnp.maximum(slot, 0)], 0.0)
+    return jnp.where((max_par > 0) & (n >= max_par),
+                     (n + 1.0 - max_par) * MAX_PARALLEL_PENALTY, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("coupled",))
+def system_place(cluster: ClusterArrays, p: TGParams, cand: SystemCandidates,
+                 coupled: bool = False):
+    """Every node of one system task group in one launch (reference
+    `scheduler/system_sched.go:268` computePlacements with preemption on,
+    the node fixed: `stack.go:256` + `PreemptForTaskGroup`).
+
+    -> (status i8[N], victims i16[N, C]). `status` is `SYS_FITS` where
+    the ask fits as the view stands, `SYS_FREED` where it fits once the
+    row's victims go — the first of `victims` up to the first −1, in
+    upstream's order, as indices into the row's candidates —,
+    `SYS_REFUSED` where even every candidate would not free enough,
+    `SYS_UNPACKED` where it needs an eviction and the host packed no
+    candidates for the row, `SYS_INFEASIBLE` where a constraint rules
+    it out.
+
+    Nodes are independent unless a candidate's group has a migrate
+    `max_parallel`: its penalty counts the plan's preemptions of that
+    group, node after node. `coupled` (static) walks the packed rows in
+    the host's node order and carries those counts, each row's victims
+    added as it is decided; otherwise every row is decided at once with
+    no penalty."""
+    feas, fits = system_feasibility(cluster, p)
+    need = feas & ~fits & cand.packed
+    ask = p.ask[:3]
+    n, c = cand.prio.shape
+    if not coupled:
+        victims, order = _greedy(cand.remaining, cand.res, cand.prio,
+                                 jnp.zeros((n, c), jnp.float32), ask)
+    else:
+        s_ax = jnp.arange(cand.counts.shape[0])
+
+        def row_step(counts, r):
+            rr = jnp.maximum(r, 0)
+            slot = cand.slot[rr]
+            v, o = _greedy(cand.remaining[rr][None], cand.res[rr][None],
+                           cand.prio[rr][None],
+                           _penalty(cand.max_par[rr], slot, counts)[None],
+                           ask)
+            v = jnp.where((r >= 0) & need[rr], v[0], 0)
+            # the row's victims, as the host loop adds them to the plan
+            taken = jnp.sum(jnp.where(jnp.arange(c) < v,
+                                      _onehot_take(slot, o[0]), -1)
+                            [:, None] == s_ax[None, :], axis=0)
+            return counts + taken, (v, o[0])
+
+        _, (v_k, o_k) = jax.lax.scan(row_step, cand.counts, cand.order)
+        # each row's decision back from its place in the walk (a gather)
+        at = jnp.argmax(cand.order[None, :] == jnp.arange(n)[:, None],
+                        axis=1)
+        walked = jnp.any(cand.order[None, :] == jnp.arange(n)[:, None],
+                         axis=1)
+        victims = jnp.where(walked, v_k[at], 0)
+        order = o_k[at]
+    freed = need & (victims > 0)
+    status = jnp.where(
+        ~feas, SYS_INFEASIBLE, jnp.where(
+            fits, SYS_FITS, jnp.where(
+                ~cand.packed, SYS_UNPACKED,
+                jnp.where(freed, SYS_FREED, SYS_REFUSED))))
+    out = jnp.where(jnp.arange(c)[None, :] < jnp.where(freed, victims,
+                                                       0)[:, None],
+                    order, -1)
+    return status.astype(jnp.int8), out.astype(jnp.int16)

@@ -11,7 +11,10 @@ good would that be) runs full-width on device (`kernels/preemption.py` —
 sort + prefix-scan over the per-node alloc axis). This module is the host
 half: the exact greedy victim-set selection on the ONE chosen node — a
 sequential, order-dependent loop over ≤ dozens of allocs that the reference
-also runs scalar; putting it on the MXU would be shape-hostile for zero win.
+also runs scalar. A system task group needs that greedy on every full node
+at once; there it runs on device for all of them in one launch
+(`kernels/preemption.py system_place`), and `Preemptor` is the reference
+its tests compare against.
 """
 from __future__ import annotations
 
@@ -20,15 +23,12 @@ from typing import Dict, List, Optional, Tuple
 
 from ..structs import Allocation, NetworkResource
 from ..structs.funcs import (  # noqa: F401 — re-exported parity anchors
+    MAX_PARALLEL_PENALTY,
     PREEMPTION_SCORE_ORIGIN,
     PREEMPTION_SCORE_RATE,
     preemption_score,
 )
 from ..structs.resources import ComparableResources
-
-# Score penalty applied per already-preempted alloc of the same job/tg beyond
-# its migrate max_parallel (reference preemption.go:13).
-MAX_PARALLEL_PENALTY = 50.0
 
 # Minimum priority delta between the preempting job and a victim
 # (reference preemption.go:677 "within a delta of 10").
@@ -473,21 +473,12 @@ def find_preemption_placement(state, cluster, job, tg, params, plan
 
 
 def preempt_on_node(state, job, tg, node_id: str, plan) -> List[Allocation]:
-    """System-scheduler preemption: victims on ONE fixed node
-    (reference system_sched.go preemption path — no cross-node ranking)."""
-    from .util import proposed_allocs
-
-    node = state.node_by_id(node_id)
-    if node is None:
-        return []
-    preemptor = Preemptor(job.priority, job.namespace, job.id)
-    preemptor.set_node(node)
-    preemptor.set_candidates(proposed_allocs(state, plan, node_id))
-    preemptor.set_preemptions(
-        [a for lst in plan.node_preemptions.values() for a in lst]
-    )
-    res = job.combined_task_resources(tg)
-    ask = ComparableResources(
-        cpu=res.cpu, memory_mb=res.memory_mb, disk_mb=res.disk_mb
-    )
-    return preemptor.preempt_for_task_group(ask)
+    """System-scheduler preemption: the victims a system allocation on
+    ONE fixed node evicts (reference system_sched.go preemption path — no
+    cross-node ranking). The system scheduler decides every node of a
+    task group in one device launch (`kernels/preemption.py
+    system_place`) and hands the decisions to the plan it builds as
+    `plan.system_victims`; every victim set of the system path is taken
+    here, the one place a test substitutes a wrong one
+    (`perfbench/faults.py`, `preempt-too-high`)."""
+    return plan.system_victims(node_id)

@@ -5,12 +5,16 @@ Behavioral reference: `scheduler/system_sched.go` (:45 NewSystemScheduler,
 `scheduler/util.go` diffSystemAllocsForNode (:70) / diffSystemAllocs (:201).
 
 TPU-first restructuring: the reference runs the feasibility stack once per
-node (SystemStack with a single-node source). Here ONE kernel call computes
-the [N]-wide feasibility+fit mask per task group; the per-node diff is host
-set arithmetic.
+node (SystemStack with a single-node source) and, where a node is full,
+the Preemptor on it. Here ONE launch of `kernels/preemption.py
+system_place` per task group decides every node — fits, needs an
+eviction, and the victims upstream's greedy takes there — from a
+candidate table the host packs once; the per-node diff and the plan are
+host set arithmetic over its arrays.
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -31,9 +35,11 @@ from ..structs import (
     TaskGroup,
     filter_terminal_allocs,
 )
+from ..lib.trace import host_span
 from ..tensor.cluster import ClusterTensors
+from . import preemption
 from .generic import (allocated_resources, count_offers,
-                      offer_needs_node)
+                      offer_needs_node, planner_registry)
 from .reconcile import ALLOC_LOST, ALLOC_NOT_NEEDED, ALLOC_UPDATING
 from .stack import PlanContext, TPUStack
 from .util import (
@@ -50,6 +56,17 @@ from .util import (
 
 MAX_SYSTEM_ATTEMPTS = 5  # reference system_sched.go:17
 ALLOC_NODE_TAINTED = "alloc not needed as node is tainted"
+
+#: `system.<name>` counters an eval adds when it ends: allocations
+#: placed, allocations evicted, nodes that needed an eviction, those of
+#: them that no eviction could free, and the victim candidates packed
+SYSTEM_COUNTS = ("placements", "evictions", "preempt_nodes", "refused",
+                 "candidates")
+
+#: the floor of `system_place`'s candidate bucket C: one shape for every
+#: table a loaded 10,000-node cluster packs, so that no launch compiles
+#: anew as its nodes fill up
+CANDIDATE_BUCKET = 128
 
 
 def materialize_system_groups(job: Job) -> Dict[str, TaskGroup]:
@@ -77,6 +94,8 @@ class SystemScheduler:
         #: offers of this eval and those that built no per-node index
         #: (`scheduler/generic.py count_offers`)
         self._offers = self._offers_skipped = 0
+        #: this eval's `system.*` counts, added once when it ends
+        self._counts = dict.fromkeys(SYSTEM_COUNTS, 0)
 
     def process(self, eval: Evaluation) -> None:
         self.eval = eval
@@ -88,6 +107,12 @@ class SystemScheduler:
         finally:
             count_offers(self.planner, self._offers, self._offers_skipped)
             self._offers = self._offers_skipped = 0
+            reg = planner_registry(self.planner)
+            reg.inc("system.evals")
+            for name, n in self._counts.items():
+                if n:
+                    reg.inc("system." + name, n)
+            self._counts = dict.fromkeys(SYSTEM_COUNTS, 0)
         if err is not None:
             if isinstance(err, SetStatusError):
                 self._set_status(EVAL_STATUS_FAILED, str(err))
@@ -105,6 +130,7 @@ class SystemScheduler:
 
     def _process(self) -> Tuple[bool, Optional[Exception]]:
         ev = self.eval
+        t0 = time.monotonic()
         self.job = self.state.job_by_id(ev.namespace, ev.job_id)
         self.queued_allocs = {}
         self.failed_tg_allocs = {}
@@ -120,6 +146,8 @@ class SystemScheduler:
         self.preemption_enabled = config.preemption_system_enabled
 
         err = self._compute_job_allocs()
+        planner_registry(self.planner).add_sample(
+            "system.schedule_ms", 1e3 * (time.monotonic() - t0))
         if err is not None:
             return False, err
 
@@ -222,17 +250,20 @@ class SystemScheduler:
     def _compute_placements(
         self, place: List[Tuple[str, TaskGroup, Optional[Allocation]]]
     ) -> Optional[Exception]:
-        """One mask-kernel dispatch per task group; per-node decode
-        (replaces the reference's per-node SystemStack.Select loop,
-        system_sched.go:268)."""
-        from ..kernels.placement import system_feasibility
-        from .stack import _to_device
+        """One `system_place` launch per task group, then the plan built
+        node by node from its arrays (replaces the reference's per-node
+        SystemStack.Select loop, system_sched.go:268, and its Preemptor
+        on each full node)."""
+        from ..kernels.preemption import SYS_FITS, SYS_FREED, SYS_UNPACKED
+        from ..tensor.vocab import MISSING
 
         by_tg: Dict[str, List[Tuple[str, Optional[Allocation]]]] = {}
         tg_map: Dict[str, TaskGroup] = {}
         for node_id, tg, prev in place:
             by_tg.setdefault(tg.name, []).append((node_id, prev))
             tg_map[tg.name] = tg
+        reg = planner_registry(self.planner)
+        counts = self._counts
 
         for tg_name, entries in by_tg.items():
             tg = tg_map[tg_name]
@@ -240,16 +271,18 @@ class SystemScheduler:
             for stops in self.plan.node_update.values():
                 plan_ctx.stopped_allocs.extend(stops)
             params, _m = self.stack.compile_tg(self.job, tg, len(entries), plan_ctx)
-            arrays = self.stack.device_arrays()
-            feas_mask, mask = system_feasibility(arrays, _to_device(params))
-            feas_mask, mask = np.asarray(feas_mask), np.asarray(mask)
+            rows = [self.cluster.row_of.get(nid) for nid, _p in entries]
+            t0 = time.monotonic()
+            table = SystemTable(self)
+            if self.preemption_enabled:
+                table.pack(table.may_not_fit(params, rows))
+            table.launch(params, rows)
+            place_s = time.monotonic() - t0
 
             # distinct_property tracking (SystemStack includes the
             # DistinctPropertyIterator too, stack.go:248): counts update
             # as this loop places, host-side since placement here is
             # per-node scalar
-            from ..tensor.vocab import MISSING
-
             dp_active = np.asarray(params.dp_active)
             dp_keys = np.asarray(params.dp_key_idx)
             dp_allowed = np.asarray(params.dp_allowed)
@@ -259,95 +292,314 @@ class SystemScheduler:
             #                               LTarget dp caps total placements
             plain = not offer_needs_node(tg)  # no index is built per node
 
-            for node_id, prev in entries:
-                row = self.cluster.row_of.get(node_id)
-                ok = row is not None and bool(mask[row])
-                # distinct_property gates BOTH normal and preemption
-                # placements; check before deciding to preempt, so a
-                # dp-infeasible node never evicts victims
-                dp_ok = True
-                dp_toks: List[Tuple[int, int]] = []
-                if row is not None and has_dp:
-                    for i in range(len(dp_keys)):
-                        if not dp_active[i]:
-                            continue
-                        tok = int(self.cluster.attrs[row, dp_keys[i]])
-                        if tok == MISSING or tok >= dcounts.shape[1] \
-                                or dcounts[i, tok] >= dp_allowed[i]:
-                            dp_ok = False
-                            break
-                        dp_toks.append((i, tok))
-                dp_ok = dp_ok and budget > 0
-                ok = ok and dp_ok
-                victims: List[Allocation] = []
-                if (
-                    not ok
-                    and dp_ok
-                    and row is not None
-                    and bool(feas_mask[row])
-                    and self.preemption_enabled
-                ):
-                    # Feasible but exhausted → evict lower-priority allocs
-                    # (system jobs preempt by default, stack.go:256-263)
-                    from .preemption import preempt_on_node
-
-                    victims = preempt_on_node(
-                        self.state, self.job, tg, node_id, self.plan
-                    )
-                    ok = bool(victims)
-                metrics = AllocMetric()
-                metrics.nodes_evaluated = 1
-                metrics.nodes_available = dict(self.nodes_by_dc)
-                if not ok:
-                    existing = self.failed_tg_allocs.get(tg.name)
-                    if existing is not None:
-                        existing.coalesced_failures += 1
-                    else:
-                        metrics.nodes_filtered = 1
-                        self.failed_tg_allocs[tg.name] = metrics
-                    continue
-                node = self.state.node_by_id(node_id)
-                alloc_id = fast_uuid()
-                if victims:
-                    # Same ordering contract as the generic scheduler: plan
-                    # preemptions precede the NetworkIndex build.
-                    for v in victims:
-                        self.plan.append_preempted_alloc(v, alloc_id)
-                self._offers += 1
-                if plain or node is None:
-                    self._offers_skipped += 1
-                alloc_res, net_err = allocated_resources(
-                    self.state, self.plan, tg, node
-                )
-                if net_err is not None:
-                    # Port-exhausted node: fail the per-node placement
-                    # rather than placing without ports (rank.go:256-267)
-                    fail_network_exhausted(
-                        self.plan, node_id, node, victims, metrics,
-                        self.failed_tg_allocs, tg.name, net_err)
-                    continue
-                alloc = Allocation(
-                    id=alloc_id,
-                    namespace=self.job.namespace,
-                    eval_id=self.eval.id,
-                    name=f"{self.job.id}.{tg.name}[0]",
-                    job_id=self.job.id,
-                    job=self.job,
-                    task_group=tg.name,
-                    metrics=metrics,
-                    node_id=node_id,
-                    node_name=node.name if node else "",
-                    allocated_resources=alloc_res,
-                    desired_status=ALLOC_DESIRED_RUN,
-                    client_status=ALLOC_CLIENT_PENDING,
-                    job_version=self.job.version,
-                )
-                if victims:
-                    alloc.preempted_allocations = [v.id for v in victims]
-                if prev is not None:
-                    alloc.previous_allocation = prev.id
-                self.plan.append_alloc(alloc)
-                budget -= 1
-                for i, tok in dp_toks:
-                    dcounts[i, tok] += 1
+            self.plan.system_victims = table.victims_of
+            try:
+                for i, (node_id, prev) in enumerate(entries):
+                    row = rows[i]
+                    st = int(table.status[row]) if row is not None else 0
+                    ok = st == SYS_FITS
+                    # distinct_property gates BOTH normal and preemption
+                    # placements; check before deciding to preempt, so a
+                    # dp-infeasible node never evicts victims
+                    dp_ok = True
+                    dp_toks: List[Tuple[int, int]] = []
+                    if row is not None and has_dp:
+                        for k in range(len(dp_keys)):
+                            if not dp_active[k]:
+                                continue
+                            tok = int(self.cluster.attrs[row, dp_keys[k]])
+                            if tok == MISSING or tok >= dcounts.shape[1] \
+                                    or dcounts[k, tok] >= dp_allowed[k]:
+                                dp_ok = False
+                                break
+                            dp_toks.append((k, tok))
+                    dp_ok = dp_ok and budget > 0
+                    ok = ok and dp_ok
+                    victims: List[Allocation] = []
+                    if not ok and dp_ok and st > SYS_FITS \
+                            and self.preemption_enabled:
+                        # Feasible but exhausted → evict lower-priority
+                        # allocs (system jobs preempt by default,
+                        # stack.go:256-263): the launch's victims
+                        if st == SYS_UNPACKED:
+                            t1 = time.monotonic()
+                            table.pack([r for r in rows[i:] if r is not None
+                                        and table.status[r] == SYS_UNPACKED])
+                            table.launch(params, rows[i:])
+                            place_s += time.monotonic() - t1
+                        counts["preempt_nodes"] += 1
+                        victims = preemption.preempt_on_node(
+                            self.state, self.job, tg, node_id, self.plan)
+                        ok = bool(victims)
+                        counts["refused"] += not ok
+                    committed = self._place_on_node(
+                        tg, node_id, prev, ok, victims, plain)
+                    if committed:
+                        budget -= 1
+                        for k, tok in dp_toks:
+                            dcounts[k, tok] += 1
+                        counts["evictions"] += len(victims)
+                    if row is not None and table.coupled \
+                            and table.status[row] == SYS_FREED \
+                            and not (committed and victims) \
+                            and table.penalized(row):
+                        # the launch counted this node's victims toward
+                        # the max_parallel penalty of the nodes after it,
+                        # and the plan holds none of them: decide those
+                        # nodes again from the plan as it stands
+                        t1 = time.monotonic()
+                        table.launch(params, rows[i + 1:])
+                        place_s += time.monotonic() - t1
+            finally:
+                del self.plan.system_victims
+            reg.add_sample("system.place_ms", 1e3 * place_s)
+            reg.add_sample("system.build_ms", 1e3 * (
+                time.monotonic() - t0 - place_s))
         return None
+
+    def _place_on_node(self, tg: TaskGroup, node_id: str,
+                       prev: Optional[Allocation], ok: bool,
+                       victims: List[Allocation], plain: bool) -> bool:
+        """One node's allocation and its preemptions into the plan, or its
+        failure into `failed_tg_allocs`. -> whether it was placed."""
+        metrics = AllocMetric()
+        metrics.nodes_evaluated = 1
+        metrics.nodes_available = dict(self.nodes_by_dc)
+        if not ok:
+            existing = self.failed_tg_allocs.get(tg.name)
+            if existing is not None:
+                existing.coalesced_failures += 1
+            else:
+                metrics.nodes_filtered = 1
+                self.failed_tg_allocs[tg.name] = metrics
+            return False
+        node = self.state.node_by_id(node_id)
+        alloc_id = fast_uuid()
+        # Same ordering contract as the generic scheduler: plan
+        # preemptions precede the NetworkIndex build.
+        for v in victims:
+            self.plan.append_preempted_alloc(v, alloc_id)
+        self._offers += 1
+        if plain or node is None:
+            self._offers_skipped += 1
+        alloc_res, net_err = allocated_resources(
+            self.state, self.plan, tg, node
+        )
+        if net_err is not None:
+            # Port-exhausted node: fail the per-node placement rather
+            # than placing without ports (rank.go:256-267)
+            fail_network_exhausted(
+                self.plan, node_id, node, victims, metrics,
+                self.failed_tg_allocs, tg.name, net_err)
+            return False
+        alloc = Allocation(
+            id=alloc_id,
+            namespace=self.job.namespace,
+            eval_id=self.eval.id,
+            name=f"{self.job.id}.{tg.name}[0]",
+            job_id=self.job.id,
+            job=self.job,
+            task_group=tg.name,
+            metrics=metrics,
+            node_id=node_id,
+            node_name=node.name if node else "",
+            allocated_resources=alloc_res,
+            desired_status=ALLOC_DESIRED_RUN,
+            client_status=ALLOC_CLIENT_PENDING,
+            job_version=self.job.version,
+        )
+        if victims:
+            alloc.preempted_allocations = [v.id for v in victims]
+        if prev is not None:
+            alloc.previous_allocation = prev.id
+        self.plan.append_alloc(alloc)
+        self._counts["placements"] += 1
+        return True
+
+
+class SystemTable:
+    """One system task group's `SystemCandidates`, packed on the host row
+    by row, and the `system_place` launch that decides it.
+
+    A row's candidates are its proposed allocations (`proposed_allocs`:
+    live less the plan's stops and preemptions, plus its placements),
+    never the job's own; those the job may evict — a job, and a priority
+    at least `PRIORITY_DELTA` below its own — go into the table in that
+    order, and every one of them comes off the node's usable resources
+    (the Preemptor's `set_candidates` + `preempt_for_task_group`)."""
+
+    def __init__(self, sched: SystemScheduler) -> None:
+        self.sched = sched
+        self.cluster = sched.cluster
+        #: row -> the row's candidates, and their (cpu, memory, disk,
+        #: priority, max_parallel, slot) rows
+        self.cands: Dict[int, List[Allocation]] = {}
+        self.rows: Dict[int, np.ndarray] = {}
+        self.remaining: Dict[int, np.ndarray] = {}
+        #: (namespace, job, task group) -> slot of the penalty counts
+        self.slots: Dict[Tuple[str, str, str], int] = {}
+        self._max_par: Dict[Tuple[str, int, str], int] = {}
+        #: a candidate's group has a migrate max_parallel: the nodes are
+        #: decided in the host's order, the counts carried
+        self.coupled = False
+        self.status = np.zeros(self.cluster.n_cap, np.int8)
+        self.victims = np.zeros((self.cluster.n_cap, 1), np.int16)
+
+    def may_not_fit(self, params, rows: List[Optional[int]]) -> List[int]:
+        """The rows where the ask may not fit as the launch's view will
+        stand — `system_feasibility`'s fit on the host's copy of the
+        same tensors, and every row where the group asks for a port:
+        only those get candidates. A row the launch finds full with no
+        candidates packed comes back `SYS_UNPACKED` and is packed then."""
+        cl = self.cluster
+        got = [r for r in rows if r is not None]
+        if not got:
+            return []
+        if (np.asarray(params.res_ports) >= 0).any() \
+                or float(params.n_dyn) > 0:
+            return got
+        idx = np.asarray(got)
+        used = cl.used[idx].copy()
+        didx = np.asarray(params.delta_idx)
+        live = didx >= 0
+        if live.any():
+            pos = {r: k for k, r in enumerate(got)}
+            for r, res in zip(didx[live], np.asarray(params.delta_res)[live]):
+                k = pos.get(int(r))
+                if k is not None:
+                    used[k] -= res
+        over = np.any(used + np.asarray(params.ask, np.float64)
+                      > cl.capacity[idx], axis=1)
+        return [r for r, o in zip(got, over) if o]
+
+    def pack(self, rows: List[int]) -> None:
+        from .util import proposed_allocs
+
+        s, cl = self.sched, self.cluster
+        job = s.job
+        usage = cl.alloc_usage
+        for row in rows:
+            if row in self.cands:
+                continue
+            node_id = cl.node_of_row[row]
+            planned = {a.id for a in s.plan.node_allocation.get(node_id, ())}
+            rem = cl.capacity[row, :3].astype(np.float64)
+            cands: List[Allocation] = []
+            out: List[Tuple[float, ...]] = []
+            for a in proposed_allocs(s.state, s.plan, node_id):
+                if a.job_id == job.id and a.namespace == job.namespace:
+                    continue  # never preempt the job being placed
+                ent = usage.get(a.id)
+                if ent is not None and ent[0] == row and a.id not in planned:
+                    u = ent[1][:3]
+                else:
+                    cr = a.comparable_resources()
+                    u = (cr.cpu, cr.memory_mb, cr.disk_mb)
+                rem -= u
+                if a.job is None or job.priority - a.job.priority \
+                        < preemption.PRIORITY_DELTA:
+                    continue
+                mp = self._max_parallel(a)
+                key = (a.namespace, a.job_id, a.task_group)
+                slot = self.slots.setdefault(key, len(self.slots))
+                self.coupled |= mp > 0
+                cands.append(a)
+                out.append((u[0], u[1], u[2], a.job.priority, mp, slot))
+            self.cands[row] = cands
+            self.rows[row] = np.asarray(out, np.float64).reshape(-1, 6)
+            self.remaining[row] = rem
+            s._counts["candidates"] += len(cands)
+
+    def _max_parallel(self, a: Allocation) -> int:
+        key = (a.job_id, a.job.version, a.task_group)
+        mp = self._max_par.get(key)
+        if mp is None:
+            tg = a.job.lookup_task_group(a.task_group)
+            ms = tg.migrate_strategy if tg is not None else None
+            mp = self._max_par[key] = ms.max_parallel if ms else 0
+        return mp
+
+    def candidates(self, n: int, rows: List[Optional[int]]):
+        """The table at `n` rows as `SystemCandidates` (NumPy), the
+        coupled variant's order being the packed rows among `rows`, and
+        its counts the plan's preemptions as they stand."""
+        from ..kernels.preemption import INF_PRIO, SystemCandidates
+        from ..utils import bucket
+
+        c = bucket(max((len(v) for v in self.cands.values()), default=1),
+                   CANDIDATE_BUCKET)
+        packed = np.zeros(n, bool)
+        remaining = np.zeros((n, 3), np.float32)
+        res = np.zeros((n, c, 3), np.float32)
+        prio = np.full((n, c), INF_PRIO, np.float32)
+        for row, t in self.rows.items():
+            k = len(t)
+            packed[row] = True
+            remaining[row] = self.remaining[row]
+            res[row, :k] = t[:, :3]
+            prio[row, :k] = t[:, 3]
+        if not self.coupled:
+            return SystemCandidates(
+                packed, remaining, res, prio,
+                max_par=np.zeros((1, 1), np.float32),
+                slot=np.full((1, 1), -1, np.int32),
+                counts=np.zeros(1, np.float32),
+                order=np.full(1, -1, np.int32))
+        max_par = np.zeros((n, c), np.float32)
+        slot = np.full((n, c), -1, np.int32)
+        for row, t in self.rows.items():
+            k = len(t)
+            max_par[row, :k] = t[:, 4]
+            slot[row, :k] = t[:, 5]
+        counts = np.zeros(bucket(max(len(self.slots), 1)), np.float32)
+        for lst in self.sched.plan.node_preemptions.values():
+            for a in lst:
+                k = self.slots.get((a.namespace, a.job_id, a.task_group))
+                if k is not None:
+                    counts[k] += 1
+        seen, order = set(), []
+        for r in rows:
+            if r is not None and r in self.cands and r not in seen:
+                seen.add(r)
+                order.append(r)
+        walk = np.full(bucket(max(len(order), 1)), -1, np.int32)
+        walk[:len(order)] = order
+        return SystemCandidates(packed, remaining, res, prio, max_par, slot,
+                                counts, walk)
+
+    def launch(self, params, rows: List[Optional[int]]) -> None:
+        """`system_place` over the table: uploads and fetch on the
+        transfer ledger's `system.*` sites, under the transfer guard."""
+        import jax
+
+        from ..kernels.preemption import system_place
+        from ..lib.transfer import default_ledger, guard_scope
+
+        led = default_ledger()
+        with guard_scope():
+            arrays = self.sched.stack.device_arrays()
+            host = (params, self.candidates(arrays.capacity.shape[0], rows))
+            nbytes = sum(int(getattr(x, "nbytes", 0))
+                         for x in jax.tree_util.tree_leaves(host))
+            with led.timed("system.upload", nbytes):
+                dev_params, dev_cand = jax.device_put(host)
+            with host_span("system_place"):
+                out = system_place(arrays, dev_params, dev_cand,
+                                   coupled=self.coupled)
+                with led.timed("system.fetch", sum(x.nbytes for x in out)):
+                    self.status, self.victims = (np.asarray(x) for x in out)
+
+    def victims_of(self, node_id: str) -> List[Allocation]:
+        """The node's victims as the launch decided them, in upstream's
+        order (`preemption.preempt_on_node` reads them off the plan)."""
+        row = self.cluster.row_of.get(node_id)
+        if row is None or row not in self.cands:
+            return []
+        cands = self.cands[row]
+        return [cands[k] for k in self.victims[row] if k >= 0]
+
+    def penalized(self, row: int) -> bool:
+        """Whether a victim the launch chose on `row` counts toward a
+        max_parallel penalty."""
+        t = self.rows[row]
+        return any(t[k, 4] > 0 for k in self.victims[row] if k >= 0)

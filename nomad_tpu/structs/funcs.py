@@ -132,6 +132,11 @@ def score_fit(algorithm: str, node: Node, util: ComparableResources) -> float:
 PREEMPTION_SCORE_RATE = 0.0048
 PREEMPTION_SCORE_ORIGIN = 2048.0
 
+# Score penalty applied per already-preempted alloc of the same job/tg beyond
+# its migrate max_parallel (reference preemption.go:13); the host Preemptor
+# and the system scheduler's device greedy share it.
+MAX_PARALLEL_PENALTY = 50.0
+
 
 def preemption_score(net_prio: float) -> float:
     """Score in [0, 1]; inflection at net priority 2048 (rank.go:773)."""
