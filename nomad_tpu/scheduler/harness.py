@@ -247,6 +247,11 @@ class InMemState:
     def alloc_by_id(self, alloc_id: str) -> Optional[Allocation]:
         return self._allocs.get(alloc_id)
 
+    def tensor_version(self) -> int:
+        """The `cluster.version` this state's tables stand at: the
+        tensors' own, since a live state's writes reach both at once."""
+        return self.cluster.version
+
     def deployments(self) -> List[Deployment]:
         return list(self._deployments.values())
 

@@ -15,6 +15,7 @@ host set arithmetic over its arrays.
 from __future__ import annotations
 
 import time
+from itertools import chain, compress
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -353,6 +354,10 @@ class SystemScheduler:
             finally:
                 del self.plan.system_victims
             reg.add_sample("system.place_ms", 1e3 * place_s)
+            if self.preemption_enabled:
+                reg.add_sample("system.pack_ms", 1e3 * table.pack_s)
+                reg.inc("system.pack_rows", table.n_rows)
+                reg.inc("system.pack_rows_array", table.n_array)
             reg.add_sample("system.build_ms", 1e3 * (
                 time.monotonic() - t0 - place_s))
         return None
@@ -418,32 +423,52 @@ class SystemScheduler:
 
 
 class SystemTable:
-    """One system task group's `SystemCandidates`, packed on the host row
-    by row, and the `system_place` launch that decides it.
+    """One system task group's `SystemCandidates`, packed on the host,
+    and the `system_place` launch that decides it.
 
     A row's candidates are its proposed allocations (`proposed_allocs`:
     live less the plan's stops and preemptions, plus its placements),
     never the job's own; those the job may evict — a job, and a priority
     at least `PRIORITY_DELTA` below its own — go into the table in that
     order, and every one of them comes off the node's usable resources
-    (the Preemptor's `set_candidates` + `preempt_for_task_group`)."""
+    (the Preemptor's `set_candidates` + `preempt_for_task_group`).
+
+    The rows are packed from the cluster tensors' allocation index
+    (`tensor/alloc_index.py`) at once, as arrays; a row the index cannot
+    stand for as the eval's snapshot and plan see it walks its
+    Allocation objects (`_walk`): the plan touches its node, the tensors
+    changed it since the snapshot (or their hot-row log cannot say), the
+    index holds it partial, or a live allocation on it has no job."""
+
+    #: reads of the index a writer may spoil before the pack walks
+    GATHER_TRIES = 4
 
     def __init__(self, sched: SystemScheduler) -> None:
         self.sched = sched
         self.cluster = sched.cluster
-        #: row -> the row's candidates, and their (cpu, memory, disk,
-        #: priority, max_parallel, slot) rows
-        self.cands: Dict[int, List[Allocation]] = {}
-        self.rows: Dict[int, np.ndarray] = {}
-        self.remaining: Dict[int, np.ndarray] = {}
+        n = self.cluster.n_cap
+        self.packed = np.zeros(n, bool)
+        self.remaining = np.zeros((n, 3), np.float64)
+        #: a packed row's candidates: `ncand` of them from `first` on in
+        #: the table's order, each with its (cpu, memory, disk, priority,
+        #: max_parallel, slot) in `_parts` and its Allocation — or its
+        #: id, packed from the index — in `refs`
+        self.first = np.zeros(n, np.int64)
+        self.ncand = np.zeros(n, np.int64)
+        self._parts: List[np.ndarray] = []
+        self.refs: list = []
         #: (namespace, job, task group) -> slot of the penalty counts
         self.slots: Dict[Tuple[str, str, str], int] = {}
         self._max_par: Dict[Tuple[str, int, str], int] = {}
         #: a candidate's group has a migrate max_parallel: the nodes are
         #: decided in the host's order, the counts carried
         self.coupled = False
-        self.status = np.zeros(self.cluster.n_cap, np.int8)
-        self.victims = np.zeros((self.cluster.n_cap, 1), np.int16)
+        self.status = np.zeros(n, np.int8)
+        self.victims = np.zeros((n, 1), np.int16)
+        #: rows packed, those of them packed from the index, and the
+        #: packs' seconds (`system.pack_*`)
+        self.n_rows = self.n_array = 0
+        self.pack_s = 0.0
 
     def may_not_fit(self, params, rows: List[Optional[int]]) -> List[int]:
         """The rows where the ask may not fit as the launch's view will
@@ -473,42 +498,184 @@ class SystemTable:
         return [r for r, o in zip(got, over) if o]
 
     def pack(self, rows: List[int]) -> None:
+        """Each of `rows` not packed yet into the table, in that order:
+        those the index stands for from its arrays at once, the others
+        by `_walk`."""
+        t0 = time.monotonic()
+        new = [r for r in dict.fromkeys(rows) if not self.packed[r]]
+        if new:
+            self._pack(new, self._from_index(new))
+            self.n_rows += len(new)
+        self.pack_s += time.monotonic() - t0
+
+    def _from_index(self, new: List[int]):
+        """The index's read of `new`: `(ok, nodes, cand)` — per row whether
+        the index stands for it, its node id, and for the rows it stands
+        for their `remaining` and their candidates in table order
+        (`_candidates`) — or None where no row can be read from it."""
+        s, cl = self.sched, self.cluster
+        state = s.state
+        if getattr(state, "cluster", None) is not cl \
+                or not hasattr(state, "tensor_version"):
+            return None
+        v0 = state.tensor_version()
+        idx = cl.alloc_index
+        lists, node_of_row = idx.row_slots, cl.node_of_row
+        for _ in range(self.GATHER_TRIES):
+            seq = idx.seq
+            if seq & 1:
+                time.sleep(0)   # a writer is inside: let it finish
+                continue
+            per_row = list(map(lists.__getitem__, new))
+            lens = np.fromiter(map(len, per_row), np.int64, len(new))
+            flat = list(chain.from_iterable(per_row))
+            ids = list(map(idx.ids.__getitem__, flat))
+            vals = idx.vals[np.fromiter(flat, np.int64, len(flat))]
+            cap = cl.capacity[new, :3].astype(np.float64)
+            nodes = list(map(node_of_row.__getitem__, new))
+            own = idx.jobs.get((s.job.namespace, s.job.id), -1)
+            if idx.seq == seq:
+                break
+        else:
+            return None
+        cand = self._candidates(len(new), lens, vals, ids, cap, own)
+        # every write the read saw is in the log by now
+        moved = cl.hot_rows_since(v0, cl.n_cap)
+        if moved is None:
+            return None
+        plan = s.plan
+        touched = {nid for t in (plan.node_update, plan.node_preemptions,
+                                 plan.node_allocation)
+                   for nid, lst in t.items() if lst}
+        ok = cand.pop("ok")
+        ok &= ~np.isin(new, list(moved | idx.partial))
+        ok &= ~np.fromiter(map(touched.__contains__, nodes), bool, len(new))
+        return ok, nodes, cand
+
+    def _candidates(self, n, lens, vals, ids, cap, own) -> dict:
+        """From the index's slots of `n` rows (`lens` a row, their `vals`
+        and allocation `ids`, the rows' `cap`acity, `own` the job's
+        interned id): `ok` (no live allocation without a job),
+        `remaining` (capacity less every live allocation but the job's
+        own, in order), and the candidates in table order with their
+        row's position (`pos`), (cpu, memory, disk, priority,
+        max_parallel) `vals`, group ids and allocation ids."""
+        from ..tensor import alloc_index as ai
+
+        pos = np.repeat(np.arange(n), lens)
+        live = vals[:, ai.LIVE] > 0
+        ok = np.ones(n, bool)
+        ok[pos[live & (vals[:, ai.HAS_JOB] == 0)]] = False
+        keep = live & (vals[:, ai.JOB] != own)
+        # each row's capacity less its allocations one after another, in
+        # order, as the walk subtracts them: `bincount` adds a bin's
+        # weights in the order they come, the capacity first
+        at = np.concatenate([np.arange(n), pos[keep]])
+        rem = np.stack([np.bincount(at, np.concatenate(
+            [cap[:, d], -vals[keep, ai.CPU + d]]), n) for d in range(3)],
+            axis=1)
+        cm = keep & (vals[:, ai.HAS_JOB] > 0) & (
+            self.sched.job.priority - vals[:, ai.PRIO]
+            >= preemption.PRIORITY_DELTA)
+        cm &= ok[pos]
+        got = vals[cm]
+        return {"ok": ok, "remaining": rem, "pos": pos[cm],
+                "vals": got[:, [ai.CPU, ai.MEM, ai.DISK, ai.PRIO,
+                                ai.MAX_PAR]],
+                "group": got[:, ai.GROUP].astype(np.int64),
+                "ids": list(compress(ids, cm.tolist()))}
+
+    def _pack(self, new: List[int], read) -> None:
+        """`new` into the table in order: each run of rows the index
+        stands for as one slice of its read, each other row walked; then
+        the candidates' penalty slots, in order of first appearance."""
+        idx = self.cluster.alloc_index
+        if read is None:
+            ok, nodes, cand = np.zeros(len(new), bool), None, None
+        else:
+            ok, nodes, cand = read
+            starts = np.searchsorted(cand["pos"], np.arange(len(new) + 1))
+        counts = np.zeros(len(new), np.int64)
+        vals: List[np.ndarray] = []
+        groups: List[np.ndarray] = []
+        local: Dict[Tuple[str, str, str], int] = {}
+        run = 0
+        for i in [int(k) for k in np.flatnonzero(~ok)] + [len(new)]:
+            if i > run:     # rows new[run:i] from the index
+                lo, hi = starts[run], starts[i]
+                vals.append(cand["vals"][lo:hi])
+                groups.append(cand["group"][lo:hi])
+                self.refs.extend(cand["ids"][lo:hi])
+                counts[run:i] = np.diff(starts[run:i + 1])
+                sel = np.asarray(new[run:i])
+                self.remaining[sel] = cand["remaining"][run:i]
+            if i == len(new):
+                break
+            row = new[i]
+            rem, out, keys, allocs = self._walk(
+                row, nodes[i] if nodes else self.cluster.node_of_row[row])
+            self.remaining[row] = rem
+            counts[i] = len(allocs)
+            vals.append(np.asarray(out, np.float64).reshape(-1, 5))
+            groups.append(np.asarray(
+                [g if g is not None else -1 - local.setdefault(k, len(local))
+                 for k, g in zip(keys, map(idx.groups.get, keys))],
+                np.int64))
+            self.refs.extend(allocs)
+            run = i + 1
+        self.n_array += int(ok.sum())
+        base = len(self.refs) - int(counts.sum())
+        sel = np.asarray(new)
+        self.packed[sel] = True
+        self.ncand[sel] = counts
+        self.first[sel] = base + np.cumsum(counts) - counts
+        t = np.concatenate(vals) if vals else np.zeros((0, 5))
+        g = np.concatenate(groups) if groups else np.zeros(0, np.int64)
+        slot = np.zeros(len(g))
+        if len(g):
+            keys = {v: k for k, v in local.items()}
+            u, at, inv = np.unique(g, return_index=True, return_inverse=True)
+            lut = np.zeros(len(u))
+            for j in np.argsort(at, kind="stable"):
+                gid = int(u[j])
+                key = idx.group_keys[gid] if gid >= 0 else keys[-1 - gid]
+                lut[j] = self.slots.setdefault(key, len(self.slots))
+            slot = lut[inv]
+        self.coupled |= bool((t[:, 4] > 0).any())
+        self._parts.append(np.column_stack([t, slot]))
+        self.sched._counts["candidates"] += len(t)
+
+    def _walk(self, row: int, node_id: str):
+        """One row's (remaining, candidate rows, group keys, candidates)
+        from its proposed Allocation objects."""
         from .util import proposed_allocs
 
         s, cl = self.sched, self.cluster
         job = s.job
         usage = cl.alloc_usage
-        for row in rows:
-            if row in self.cands:
+        planned = {a.id for a in s.plan.node_allocation.get(node_id, ())}
+        rem = cl.capacity[row, :3].astype(np.float64)
+        allocs: List[Allocation] = []
+        out: List[Tuple[float, ...]] = []
+        keys: List[Tuple[str, str, str]] = []
+        for a in proposed_allocs(s.state, s.plan, node_id):
+            if a.job_id == job.id and a.namespace == job.namespace:
+                continue  # never preempt the job being placed
+            ent = usage.get(a.id)
+            if ent is not None and ent[0] == row and a.id not in planned:
+                u = ent[1][:3]
+            else:
+                cr = a.comparable_resources()
+                u = (cr.cpu, cr.memory_mb, cr.disk_mb)
+            rem -= u
+            if a.job is None or job.priority - a.job.priority \
+                    < preemption.PRIORITY_DELTA:
                 continue
-            node_id = cl.node_of_row[row]
-            planned = {a.id for a in s.plan.node_allocation.get(node_id, ())}
-            rem = cl.capacity[row, :3].astype(np.float64)
-            cands: List[Allocation] = []
-            out: List[Tuple[float, ...]] = []
-            for a in proposed_allocs(s.state, s.plan, node_id):
-                if a.job_id == job.id and a.namespace == job.namespace:
-                    continue  # never preempt the job being placed
-                ent = usage.get(a.id)
-                if ent is not None and ent[0] == row and a.id not in planned:
-                    u = ent[1][:3]
-                else:
-                    cr = a.comparable_resources()
-                    u = (cr.cpu, cr.memory_mb, cr.disk_mb)
-                rem -= u
-                if a.job is None or job.priority - a.job.priority \
-                        < preemption.PRIORITY_DELTA:
-                    continue
-                mp = self._max_parallel(a)
-                key = (a.namespace, a.job_id, a.task_group)
-                slot = self.slots.setdefault(key, len(self.slots))
-                self.coupled |= mp > 0
-                cands.append(a)
-                out.append((u[0], u[1], u[2], a.job.priority, mp, slot))
-            self.cands[row] = cands
-            self.rows[row] = np.asarray(out, np.float64).reshape(-1, 6)
-            self.remaining[row] = rem
-            s._counts["candidates"] += len(cands)
+            allocs.append(a)
+            out.append((u[0], u[1], u[2], a.job.priority,
+                        self._max_parallel(a)))
+            keys.append((a.namespace, a.job_id, a.task_group))
+        return rem, out, keys, allocs
 
     def _max_parallel(self, a: Allocation) -> int:
         key = (a.job_id, a.job.version, a.task_group)
@@ -519,6 +686,14 @@ class SystemTable:
             mp = self._max_par[key] = ms.max_parallel if ms else 0
         return mp
 
+    def _table(self) -> np.ndarray:
+        """The candidates' (cpu, memory, disk, priority, max_parallel,
+        slot) rows in table order."""
+        if len(self._parts) != 1:
+            self._parts = [np.concatenate(self._parts) if self._parts
+                           else np.zeros((0, 6))]
+        return self._parts[0]
+
     def candidates(self, n: int, rows: List[Optional[int]]):
         """The table at `n` rows as `SystemCandidates` (NumPy), the
         coupled variant's order being the packed rows among `rows`, and
@@ -526,18 +701,21 @@ class SystemTable:
         from ..kernels.preemption import INF_PRIO, SystemCandidates
         from ..utils import bucket
 
-        c = bucket(max((len(v) for v in self.cands.values()), default=1),
-                   CANDIDATE_BUCKET)
+        m = min(n, len(self.packed))
+        c = bucket(int(self.ncand.max()), CANDIDATE_BUCKET)
+        t = self._table()
+        full = np.flatnonzero(self.ncand)
+        full = full[np.argsort(self.first[full])]
+        crow = np.repeat(full, self.ncand[full])  # each candidate's row
+        ck = np.arange(len(t)) - self.first[crow]
         packed = np.zeros(n, bool)
+        packed[:m] = self.packed[:m]
         remaining = np.zeros((n, 3), np.float32)
+        remaining[:m] = self.remaining[:m]
         res = np.zeros((n, c, 3), np.float32)
         prio = np.full((n, c), INF_PRIO, np.float32)
-        for row, t in self.rows.items():
-            k = len(t)
-            packed[row] = True
-            remaining[row] = self.remaining[row]
-            res[row, :k] = t[:, :3]
-            prio[row, :k] = t[:, 3]
+        res[crow, ck] = t[:, :3]
+        prio[crow, ck] = t[:, 3]
         if not self.coupled:
             return SystemCandidates(
                 packed, remaining, res, prio,
@@ -547,21 +725,19 @@ class SystemTable:
                 order=np.full(1, -1, np.int32))
         max_par = np.zeros((n, c), np.float32)
         slot = np.full((n, c), -1, np.int32)
-        for row, t in self.rows.items():
-            k = len(t)
-            max_par[row, :k] = t[:, 4]
-            slot[row, :k] = t[:, 5]
+        max_par[crow, ck] = t[:, 4]
+        slot[crow, ck] = t[:, 5]
         counts = np.zeros(bucket(max(len(self.slots), 1)), np.float32)
         for lst in self.sched.plan.node_preemptions.values():
             for a in lst:
                 k = self.slots.get((a.namespace, a.job_id, a.task_group))
                 if k is not None:
                     counts[k] += 1
-        seen, order = set(), []
-        for r in rows:
-            if r is not None and r in self.cands and r not in seen:
-                seen.add(r)
-                order.append(r)
+        seq = np.asarray([-1 if r is None else r for r in rows], np.int64)
+        seq = seq[seq >= 0]
+        seq = seq[self.packed[seq]]
+        _u, at = np.unique(seq, return_index=True)
+        order = seq[np.sort(at)]
         walk = np.full(bucket(max(len(order), 1)), -1, np.int32)
         walk[:len(order)] = order
         return SystemCandidates(packed, remaining, res, prio, max_par, slot,
@@ -591,15 +767,21 @@ class SystemTable:
 
     def victims_of(self, node_id: str) -> List[Allocation]:
         """The node's victims as the launch decided them, in upstream's
-        order (`preemption.preempt_on_node` reads them off the plan)."""
+        order (`preemption.preempt_on_node` reads them off the plan); a
+        row packed from the index names them by id, and the eval's
+        snapshot holds them."""
         row = self.cluster.row_of.get(node_id)
-        if row is None or row not in self.cands:
+        if row is None or not self.packed[row]:
             return []
-        cands = self.cands[row]
-        return [cands[k] for k in self.victims[row] if k >= 0]
+        base, refs = int(self.first[row]), self.refs
+        state = self.sched.state
+        return [state.alloc_by_id(x) if isinstance(x, str) else x
+                for x in (refs[base + int(k)] for k in self.victims[row]
+                          if k >= 0)]
 
     def penalized(self, row: int) -> bool:
         """Whether a victim the launch chose on `row` counts toward a
         max_parallel penalty."""
-        t = self.rows[row]
-        return any(t[k, 4] > 0 for k in self.victims[row] if k >= 0)
+        t, base = self._table(), int(self.first[row])
+        return any(t[base + int(k), 4] > 0 for k in self.victims[row]
+                   if k >= 0)

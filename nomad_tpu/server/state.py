@@ -55,6 +55,13 @@ class StateSnapshot(InMemState):
         self.index = store.index
         self.cluster = store.cluster
         self.index_at = store.index.value
+        self._tensor_version = store.cluster.version
+
+    def tensor_version(self) -> int:
+        """`cluster.version` when this snapshot was taken: the tensors
+        are shared live, and their hot-row log says which rows moved
+        since (`scheduler/system.py SystemTable`)."""
+        return self._tensor_version
 
     def detach_for_writes(self) -> "StateSnapshot":
         """Make this snapshot safe to MUTATE (dry-run scheduling): the

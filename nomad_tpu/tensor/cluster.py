@@ -17,6 +17,7 @@ scatters in the kernel instead).
 """
 from __future__ import annotations
 
+import functools
 import os
 from collections import deque
 from dataclasses import dataclass
@@ -26,6 +27,7 @@ import numpy as np
 
 from ..structs.alloc import Allocation
 from ..structs.node import Node
+from .alloc_index import AllocIndex
 from .vocab import MISSING, AttrVocab
 
 R_CPU, R_MEM, R_DISK, R_BW = 0, 1, 2, 3
@@ -71,6 +73,21 @@ def device_keys(offers) -> List[Tuple[str, str]]:
     AllocatedDeviceResource: the key of the device instance ledger."""
     return [(f"{ad.vendor}/{ad.type}/{ad.name}", inst)
             for ad in offers for inst in ad.device_ids]
+
+
+def _writes_index(method):
+    """A `ClusterTensors` method that writes the allocation index or
+    `capacity`: `AllocIndex.seq` is odd while it runs, so a reader
+    without the store's lock can tell it read across a write."""
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        idx = self.alloc_index
+        idx.seq += 1
+        try:
+            return method(self, *args, **kwargs)
+        finally:
+            idx.seq += 1
+    return wrapper
 
 
 def _bucket(n: int, lo: int = 64) -> int:
@@ -189,6 +206,9 @@ class ClusterTensors:
         self.alloc_usage: Dict[str, Tuple[int, np.ndarray]] = {}
         # job -> {alloc_id: (row, task_group)} for per-eval count vectors
         self.job_allocs: Dict[str, Dict[str, Tuple[int, str]]] = {}
+        #: every row's allocations as arrays, in the state's order on the
+        #: node (`alloc_index.py`): kept beside `alloc_usage`
+        self.alloc_index = AllocIndex(n_cap)
         self.version = 0
         #: bumps ONLY on port-bitmap mutations — ports_used is by far
         #: the largest tensor (u32[N, 2048] ≈ 128 MB at 16K rows), so
@@ -498,6 +518,7 @@ class ClusterTensors:
         self.attrs = at
         self.free_rows = list(range(new_cap - 1, self.n_cap - 1, -1)) + self.free_rows
         self.node_of_row.extend([None] * (new_cap - self.n_cap))
+        self.alloc_index.grow_rows(new_cap)
         self.n_cap = new_cap
 
     def _grow_keys(self) -> None:
@@ -620,6 +641,7 @@ class ClusterTensors:
             self.device_cols[pool] = col
         return col
 
+    @_writes_index
     def upsert_node(self, node: Node) -> int:
         row = self.row_of.get(node.id)
         if row is None:
@@ -628,6 +650,7 @@ class ClusterTensors:
             row = self.free_rows.pop()
             self.row_of[node.id] = row
             self.node_of_row[row] = node.id
+            self.alloc_index.node_row(row, node.id)
         self.nodes[node.id] = node
         old = self._ready_contrib.get(node.id)
         if old is not None and old[1]:
@@ -707,6 +730,7 @@ class ClusterTensors:
         self.node_version += 1
         return row
 
+    @_writes_index
     def remove_node(self, node_id: str) -> None:
         row = self.row_of.pop(node_id, None)
         if row is None:
@@ -740,6 +764,7 @@ class ClusterTensors:
         for japs in self.job_allocs.values():
             for aid in [a for a, (r, _tg) in japs.items() if r == row]:
                 del japs[aid]
+        self.alloc_index.drop_row(row, node_id)
         self.free_rows.append(row)
         self._log_hot(row)
         self.version += 1
@@ -764,10 +789,13 @@ class ClusterTensors:
                         u[col] += len(dev.device_ids)
         return u
 
+    @_writes_index
     def upsert_alloc(self, alloc: Allocation) -> None:
-        """Maintain `used` and the job index. Terminal allocs release usage
-        (mirrors the reference's non-terminal filter in AllocsByNodeTerminal,
-        state_store usage via context.go:122)."""
+        """Maintain `used`, the job index and the allocation index.
+        Terminal allocs release usage (mirrors the reference's non-terminal
+        filter in AllocsByNodeTerminal, state_store usage via
+        context.go:122)."""
+        idx = self.alloc_index
         touched = []
         prev = self.alloc_usage.pop(alloc.id, None)
         if prev is not None:
@@ -783,14 +811,18 @@ class ClusterTensors:
         japs = self.job_allocs.setdefault(alloc.job_id, {})
         japs.pop(alloc.id, None)
 
+        row = self.row_of.get(alloc.node_id)
+        if row is None:
+            idx.drop(alloc.id)
+            idx.unrowed.add(alloc.node_id)
         if alloc.terminal_status():
             if not japs:
                 self.job_allocs.pop(alloc.job_id, None)
+            if row is not None:
+                idx.put(alloc.id, row, alloc, None)
             self._log_hot(*touched)
             self.version += 1
             return
-
-        row = self.row_of.get(alloc.node_id)
         if row is None:
             self._log_hot(*touched)
             self.version += 1
@@ -798,6 +830,7 @@ class ClusterTensors:
         usage = self.usage_row(alloc)
         self.used[row] += usage
         self.alloc_usage[alloc.id] = (row, usage)
+        idx.put(alloc.id, row, alloc, usage)
         self._add_alloc_ports(alloc.id, row, self._alloc_port_list(alloc))
         devs = self._alloc_device_list(alloc)
         if devs:
@@ -829,6 +862,7 @@ class ClusterTensors:
             [self._alloc_port_list(a) for a in allocs],
             [self._alloc_device_list(a) for a in allocs], device_cols)
 
+    @_writes_index
     def upsert_placements(self, allocs: List[Allocation], rows: List[int],
                           placed: Optional["PlacementRows"] = None) -> None:
         """`upsert_alloc` of allocations `placement_row` gave `rows`, in one
@@ -847,11 +881,13 @@ class ClusterTensors:
         ports_of, devices_of = placed.ports, placed.devices
         job_allocs = self.job_allocs
         job_id, japs = None, None
+        usages = []
         for a, j, row in zip(allocs, idx, rows):
             aid = a.id
             u = usage_of[j]
             used[row] += u
             alloc_usage[aid] = (row, u)
+            usages.append(u)
             ports = ports_of[j]
             if ports:
                 self._add_alloc_ports(aid, row, ports)
@@ -865,9 +901,11 @@ class ClusterTensors:
                 japs = job_allocs.setdefault(job_id, {})
             japs.pop(aid, None)
             japs[aid] = (row, a.task_group)
+        self.alloc_index.place(allocs, rows, usages)
         self._log_hot(*rows)
         self.version += 1
 
+    @_writes_index
     def remove_alloc(self, alloc_id: str, job_id: str = "") -> None:
         touched = []
         prev = self.alloc_usage.pop(alloc_id, None)
@@ -888,6 +926,7 @@ class ClusterTensors:
                 if alloc_id in japs:
                     del japs[alloc_id]
                     break
+        self.alloc_index.drop(alloc_id)
         self._log_hot(*touched)
         self.version += 1
 
